@@ -76,9 +76,13 @@ def _resize(crops: np.ndarray, res: int) -> np.ndarray:
     return crops[:, idx][:, :, idx]
 
 
-def _cache_path(stream: str, model_id: str, duration_s: int) -> str:
+def _cache_path(stream: str, model_id: str, duration_s: int, steps: int,
+                Ls: int) -> str:
+    """Keyed by everything the training run takes, so a run with other
+    ``steps`` or ``Ls`` never loads these weights."""
     os.makedirs(CACHE_DIR, exist_ok=True)
-    return os.path.join(CACHE_DIR, f"{stream}_{model_id}_{duration_s}.pkl")
+    return os.path.join(
+        CACHE_DIR, f"{stream}_{model_id}_{duration_s}_s{steps}_L{Ls}.pkl")
 
 
 def get_model(stream_name: str, model_id: str,
@@ -86,7 +90,7 @@ def get_model(stream_name: str, model_id: str,
               duration_s: int = BENCH_DURATION_S, steps: int = 200,
               Ls: int = DEFAULT_LS) -> Tuple[Callable, float, object]:
     """Returns (apply_fn, accounted_flops_per_image, class_map or None)."""
-    path = _cache_path(stream_name, model_id, duration_s)
+    path = _cache_path(stream_name, model_id, duration_s, steps, Ls)
     specialized = model_id in SPECIALIZED_FAMILY
     cfg, divisor = (SPECIALIZED_FAMILY if specialized
                     else GENERIC_FAMILY)[model_id]

@@ -23,6 +23,8 @@ MODULES = [
 
 
 def main() -> None:
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     only = sys.argv[1:] or None
     failures = 0

@@ -73,6 +73,12 @@ def _pad_rows(arr: np.ndarray, bucket: int) -> np.ndarray:
         [arr, np.zeros((bucket - n,) + arr.shape[1:], arr.dtype)])
 
 
+def _jit_entries(fn: Optional[Callable]) -> int:
+    """Trace-cache entries of a jitted function (0 before its first
+    dispatch)."""
+    return 0 if fn is None else int(fn._cache_size())
+
+
 def _donate_argnums() -> tuple:
     """Donate the ClusterState buffers (centroids, counts, n) so the fold
     updates them in place. CPU XLA cannot alias donated buffers (it would
@@ -317,22 +323,14 @@ class IngestPipeline:
 
     def jit_cache_entries(self) -> dict:
         """REAL trace-cache entry counts of the shared megastep / tail
-        jits (``-1`` if this jax version lacks introspection). This is
-        what the CI retrace gate checks: the per-pipeline
+        jits. This is what the CI retrace gate checks: the per-pipeline
         ``compile_hits/misses`` counters track (bucket, res) key novelty
         only and cannot see an XLA retrace caused by dtype or weak-type
         drift."""
-        def size(fn):
-            if fn is None:
-                return 0
-            try:
-                return int(fn._cache_size())
-            except Exception:
-                return -1
         # the exact jit this pipeline dispatched — no key reconstruction
         # that could drift from _dispatch and leave the gate measuring 0
-        return {"megastep": size(self._megastep_fn),
-                "tail": size(_SCAN_TAIL_JIT)}
+        return {"megastep": _jit_entries(self._megastep_fn),
+                "tail": _jit_entries(_SCAN_TAIL_JIT)}
 
     # -- internals -------------------------------------------------------------
 
@@ -500,7 +498,6 @@ def _sharded_megastep_jit(cheap_fn: Callable, k_top: int, with_topk: bool,
         _MEGASTEP_JITS.move_to_end(key)
         return fn
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import sharding as shd
@@ -532,9 +529,9 @@ def _sharded_megastep_jit(cheap_fn: Callable, k_top: int, with_topk: bool,
     out_specs = (s(2), s(1), s(0), s(2), s(2), s(1), s(1))
     if with_topk:
         out_specs = out_specs + (s(2), s(2))
-    # check_rep=False: Pallas calls have no replication rule
-    fn = jax.jit(shard_map(block, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False),
+    # check_vma=False: Pallas calls have no replication rule
+    fn = jax.jit(jax.shard_map(block, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False),
                  donate_argnums=_donate_argnums())
     _MEGASTEP_JITS[key] = fn
     if len(_MEGASTEP_JITS) > _MEGASTEP_JITS_MAX:
@@ -552,7 +549,6 @@ def _sharded_tail_jit(mesh, width: int) -> Callable:
         _SHARDED_TAIL_JITS.move_to_end(key)
         return fn
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed import sharding as shd
@@ -567,11 +563,11 @@ def _sharded_tail_jit(mesh, width: int) -> Callable:
         return tuple(jnp.stack([o[i] for o in outs]) for i in range(4))
 
     s = lambda r: shd.stream_spec(mesh, r)          # noqa: E731
-    fn = jax.jit(shard_map(block, mesh=mesh,
-                           in_specs=(s(2), s(1), s(0), s(2), s(1), s(1),
-                                     P()),
-                           out_specs=(s(2), s(1), s(0), s(1)),
-                           check_rep=False),
+    fn = jax.jit(jax.shard_map(block, mesh=mesh,
+                               in_specs=(s(2), s(1), s(0), s(2), s(1),
+                                         s(1), P()),
+                               out_specs=(s(2), s(1), s(0), s(1)),
+                               check_vma=False),
                  donate_argnums=_donate_argnums())
     _SHARDED_TAIL_JITS[key] = fn
     if len(_SHARDED_TAIL_JITS) > _SHARDED_TAIL_JITS_MAX:
@@ -732,15 +728,8 @@ class ShardedIngestPipeline:
     def jit_cache_entries(self) -> dict:
         """Trace-cache entry counts of the sharded megastep / tail jits
         (same contract as ``IngestPipeline.jit_cache_entries``)."""
-        def size(fn):
-            if fn is None:
-                return 0
-            try:
-                return int(fn._cache_size())
-            except Exception:
-                return -1
-        return {"megastep": size(self._megastep_fn),
-                "tail": size(self._tail_fn)}
+        return {"megastep": _jit_entries(self._megastep_fn),
+                "tail": _jit_entries(self._tail_fn)}
 
     # -- the stacked step ------------------------------------------------------
 

@@ -998,10 +998,15 @@ class MultiStreamRunner:
         # old per-step hot-path bug (ISSUE 9 satellite)
         self._stack_sharding = None
         if mesh is not None and cheap_apply is not None:
-            import jax
             from jax.sharding import NamedSharding
 
             from repro.distributed.sharding import batch_spec
+            if batch_pad % mesh.size:
+                # every stacked batch is padded to a batch_pad multiple, so
+                # this makes each one divisible over the data axis
+                raise ValueError(
+                    f"batch_pad={batch_pad} must be a multiple of the mesh "
+                    f"size {mesh.size} to shard stacked batches over it")
             self._stack_sharding = NamedSharding(mesh, batch_spec(mesh, 3))
 
     def feed(self, feeds: Mapping[str, Tuple[np.ndarray, np.ndarray]]):
@@ -1041,11 +1046,8 @@ class MultiStreamRunner:
         n = len(stacked)
         padded = pad_to_bucket(stacked, self.batch_pad)
         if self._stack_sharding is not None:
-            try:
-                import jax
-                padded = jax.device_put(padded, self._stack_sharding)
-            except (ValueError, RuntimeError):
-                pass                     # indivisible batch / CPU fallback
+            import jax
+            padded = jax.device_put(padded, self._stack_sharding)
         probs, feats = self.cheap_apply(padded)
         probs = np.asarray(probs)[:n]
         feats = np.asarray(feats)[:n]

@@ -29,11 +29,13 @@ _BLOCK_ELEMS = 1 << 24
 def _kernel_backend() -> bool:
     """True when JAX is backed by a real accelerator (kernels compile
     natively). Interpret-mode Pallas on CPU loses to blocked numpy."""
-    try:
-        import jax
-        return jax.default_backend() != "cpu"
-    except Exception:                                # jax unavailable
-        return False
+    import jax
+    return jax.default_backend() != "cpu"
+
+
+def _pad_rows(x: np.ndarray, n: int, value: float) -> np.ndarray:
+    x = np.asarray(x, np.float32)
+    return np.pad(x, ((0, n - len(x)), (0, 0)), constant_values=value)
 
 
 def _resolve_backend(backend: str) -> str:
@@ -57,11 +59,20 @@ def match_flat(a: np.ndarray, b: np.ndarray, threshold: float,
     if Na == 0 or Nb == 0:
         return np.full((Na,), -1, np.int64)
     if _resolve_backend(backend) == "kernel":
+        from repro.core.clustering import _pad_bucket
         from repro.kernels import ops
-        m, _ = ops.pixel_match(a, b, threshold)
+        from repro.kernels.pixel_diff import PAD
+        # calls come per frame (a few crops against a ring that grows to
+        # its capacity): rows are padded to power-of-two buckets, crops
+        # with zeros trimmed below and references with the kernel's own
+        # never-matching sentinel, so the kernel compiles O(log) shapes
+        # instead of one per (Na, Nb) pair
+        m, _ = ops.pixel_match(_pad_rows(a, _pad_bucket(Na), 0.0),
+                               _pad_rows(b, _pad_bucket(Nb), PAD),
+                               threshold)
         # focuslint: disable=host-sync -- gate decision is consumed by
         # host control flow; match_flat returns numpy by contract
-        return np.asarray(m).astype(np.int64)
+        return np.asarray(m)[:Na].astype(np.int64)
     a = np.ascontiguousarray(a, np.float32)
     b = np.ascontiguousarray(b, np.float32)
     D = a.shape[1]

@@ -33,6 +33,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _kernel(t2_ref, f_ref, c_ref, min_ref, arg_ref, match_ref, fnorm_ref, *,
             bm: int, n_m: int):
+    # every per-row quantity is a (BB, 1) column: Mosaic lays a 1-D (BB,)
+    # block out differently from XLA, so outputs and carries stay 2-D
     mi = pl.program_id(1)
 
     @pl.when(mi == 0)
@@ -40,7 +42,7 @@ def _kernel(t2_ref, f_ref, c_ref, min_ref, arg_ref, match_ref, fnorm_ref, *,
         min_ref[...] = jnp.full_like(min_ref, jnp.inf)
         arg_ref[...] = jnp.zeros_like(arg_ref)
         f0 = f_ref[...].astype(jnp.float32)
-        fnorm_ref[...] = jnp.sum(f0 * f0, axis=1)
+        fnorm_ref[...] = jnp.sum(f0 * f0, axis=1, keepdims=True)
 
     f = f_ref[...].astype(jnp.float32)          # (BB, D)
     c = c_ref[...].astype(jnp.float32)          # (BM, D)
@@ -52,8 +54,8 @@ def _kernel(t2_ref, f_ref, c_ref, min_ref, arg_ref, match_ref, fnorm_ref, *,
         preferred_element_type=jnp.float32)     # (BB, BM) on the MXU
     part = jnp.sum(c * c, axis=1)[None, :] - 2.0 * cross
 
-    local_arg = jnp.argmin(part, axis=1).astype(jnp.int32)
-    local_min = jnp.min(part, axis=1)
+    local_arg = jnp.argmin(part, axis=1, keepdims=True).astype(jnp.int32)
+    local_min = jnp.min(part, axis=1, keepdims=True)
     better = local_min < min_ref[...]
     min_ref[...] = jnp.where(better, local_min, min_ref[...])
     arg_ref[...] = jnp.where(better, local_arg + mi * bm, arg_ref[...])
@@ -111,18 +113,18 @@ def _assign_impl(t2, feats, centroids, *, bb: int, bm: int,
             pl.BlockSpec((bm, D), lambda bi, mi: (mi, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bb,), lambda bi, mi: (bi,)),
-            pl.BlockSpec((bb,), lambda bi, mi: (bi,)),
-            pl.BlockSpec((bb,), lambda bi, mi: (bi,)),
+            pl.BlockSpec((bb, 1), lambda bi, mi: (bi, 0)),
+            pl.BlockSpec((bb, 1), lambda bi, mi: (bi, 0)),
+            pl.BlockSpec((bb, 1), lambda bi, mi: (bi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bb,), jnp.float32),     # per-row |f|^2, computed once
+            pltpu.VMEM((bb, 1), jnp.float32),   # per-row |f|^2, computed once
         ],
         interpret=interpret,
     )(t2, f, c)
-    return min_d2[:B], arg[:B], match[:B] != 0
+    return min_d2[:B, 0], arg[:B, 0], match[:B, 0] != 0

@@ -28,28 +28,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_NEG = -3e38
+from repro.kernels.topk_mask import extract_topk
 
 
 def _kernel(sg_ref, q_ref, s_ref, v_ref, i_ref, *, k: int):
     scale = sg_ref[0] * s_ref[...]                    # (BM, 1) f32
-    x = q_ref[...].astype(jnp.float32) * scale        # dequant, VMEM only
-    C = x.shape[1]
-    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-
-    def body(t, carry):
-        x, = carry
-        m = jnp.max(x, axis=1)                        # (BM,)
-        # smallest column index attaining the max (ties -> lowest index,
-        # matching jax.lax.top_k and the eager stable-argsort ranks)
-        is_max = x == m[:, None]
-        idx = jnp.min(jnp.where(is_max, cols, C), axis=1).astype(jnp.int32)
-        v_ref[:, t] = m
-        i_ref[:, t] = idx
-        x = jnp.where(cols == idx[:, None], _NEG, x)
-        return (x,)
-
-    jax.lax.fori_loop(0, k, body, (x,))
+    # via int32: Mosaic has no direct uint8 -> f32 cast, and every 8-bit
+    # code is exact in both int32 and f32, so the dequantized bits are
+    # those of the eager numpy path (DESIGN.md §14)
+    x = q_ref[...].astype(jnp.int32).astype(jnp.float32) * scale
+    # same K max-extract+mask passes as topk_mask: ties to the lowest
+    # column index, matching jax.lax.top_k and the eager stable-argsort
+    v_ref[...], i_ref[...] = extract_topk(x, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bm", "interpret"))

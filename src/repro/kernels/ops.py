@@ -31,7 +31,16 @@ def centroid_assign(feats, centroids, *, bb: int | None = None,
 
     Default tiles: 128x128 on TPU (sized for VMEM); in interpret mode the
     tiles cover the whole problem (the per-grid-step interpreter dispatch
-    dominates there, and "VMEM" blocks are ordinary host arrays)."""
+    dominates there, and "VMEM" blocks are ordinary host arrays).
+    ``B == 0`` short-circuits to empty outputs; ``M == 0`` raises — there
+    is no centroid to assign to."""
+    B, M = feats.shape[0], centroids.shape[0]
+    if M == 0:
+        raise ValueError("centroid_assign needs at least one centroid")
+    if B == 0:
+        out = (jnp.zeros((0,), jnp.float32), jnp.zeros((0,), jnp.int32),
+               jnp.zeros((0,), bool))
+        return out if threshold is not None else out[:2]
     interp = _interpret()
     if bb is None:
         bb = 4096 if interp else 128

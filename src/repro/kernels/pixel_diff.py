@@ -37,11 +37,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 # b-row pad sentinel: |a - 3e18| averages to ~3e18, so a padded reference
 # row can never win the online argmin against any real crop
-_PAD = 3e18
+PAD = 3e18
 
 
 def _kernel(t_ref, a_ref, b_ref, min_ref, arg_ref, match_ref, *,
             bn: int, n_n: int):
+    # per-crop reductions are (BA, 1) columns: 1-D (BA,) carries and
+    # blocks do not lower on Mosaic
     ni = pl.program_id(1)
 
     @pl.when(ni == 0)
@@ -50,12 +52,13 @@ def _kernel(t_ref, a_ref, b_ref, min_ref, arg_ref, match_ref, *,
         arg_ref[...] = jnp.zeros_like(arg_ref)
 
     a = a_ref[...].astype(jnp.float32)          # (BA, D)
-    b = b_ref[...].astype(jnp.float32)          # (BN, D)
 
     def body(j, carry):
         mn, ag = carry
-        row = jax.lax.dynamic_slice_in_dim(b, j, 1, axis=0)     # (1, D)
-        d = jnp.mean(jnp.abs(a - row), axis=1)                  # (BA,)
+        # one reference row, read from the ref (Mosaic cannot slice a
+        # loaded value at a traced offset)
+        row = b_ref[pl.ds(j, 1), :].astype(jnp.float32)        # (1, D)
+        d = jnp.mean(jnp.abs(a - row), axis=1, keepdims=True)  # (BA, 1)
         better = d < mn                  # strict: ties keep the lowest j
         return (jnp.where(better, d, mn),
                 jnp.where(better, j + ni * bn, ag))
@@ -91,7 +94,7 @@ def pixel_match(thr, a, b, *, ba: int = 128, bn: int = 128,
     Nbp = (Nb + bn - 1) // bn * bn
     af = jnp.pad(a.astype(jnp.float32), ((0, Nap - Na), (0, 0)))
     bf = jnp.pad(b.astype(jnp.float32), ((0, Nbp - Nb), (0, 0)),
-                 constant_values=_PAD)
+                 constant_values=PAD)
     n_n = Nbp // bn
 
     grid = (Nap // ba, n_n)
@@ -105,15 +108,15 @@ def pixel_match(thr, a, b, *, ba: int = 128, bn: int = 128,
             pl.BlockSpec((bn, D), lambda ai, ni: (ni, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((ba,), lambda ai, ni: (ai,)),
-            pl.BlockSpec((ba,), lambda ai, ni: (ai,)),
-            pl.BlockSpec((ba,), lambda ai, ni: (ai,)),
+            pl.BlockSpec((ba, 1), lambda ai, ni: (ai, 0)),
+            pl.BlockSpec((ba, 1), lambda ai, ni: (ai, 0)),
+            pl.BlockSpec((ba, 1), lambda ai, ni: (ai, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Nap,), jnp.float32),
-            jax.ShapeDtypeStruct((Nap,), jnp.int32),
-            jax.ShapeDtypeStruct((Nap,), jnp.int32),
+            jax.ShapeDtypeStruct((Nap, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Nap, 1), jnp.int32),
+            jax.ShapeDtypeStruct((Nap, 1), jnp.int32),
         ],
         interpret=interpret,
     )(thr, af, bf)
-    return match[:Na], min_d[:Na]
+    return match[:Na, 0], min_d[:Na, 0]

@@ -21,21 +21,37 @@ _NEG = -3e38
 
 def _kernel(x_ref, v_ref, i_ref, *, k: int):
     x = x_ref[...].astype(jnp.float32)              # (BB, C)
-    C = x.shape[1]
+    v_ref[...], i_ref[...] = extract_topk(x, k)
+
+
+def extract_topk(x, k: int):
+    """K online max-extract+mask passes over a (BB, C) f32 tile ->
+    (values (BB, k) f32, indices (BB, k) i32), ties to the lowest column.
+
+    The K extracted columns accumulate in values and the caller stores the
+    block once: Mosaic cannot store to a lane offset ``t`` that is traced
+    (``ref[:, t] = m``), and a select into a carried (BB, k) value moves
+    the same bits."""
+    bb, C = x.shape
     cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    kcols = jax.lax.broadcasted_iota(jnp.int32, (bb, k), 1)
 
     def body(t, carry):
-        x, = carry
-        m = jnp.max(x, axis=1)                      # (BB,)
+        x, vals, idxs = carry
+        m = jnp.max(x, axis=1, keepdims=True)       # (BB, 1)
         # smallest column index attaining the max (ties -> lowest index)
-        is_max = x == m[:, None]
-        idx = jnp.min(jnp.where(is_max, cols, C), axis=1).astype(jnp.int32)
-        v_ref[:, t] = m
-        i_ref[:, t] = idx
-        x = jnp.where(cols == idx[:, None], _NEG, x)
-        return (x,)
+        is_max = x == m
+        idx = jnp.min(jnp.where(is_max, cols, C), axis=1,
+                      keepdims=True).astype(jnp.int32)
+        vals = jnp.where(kcols == t, m, vals)
+        idxs = jnp.where(kcols == t, idx, idxs)
+        x = jnp.where(cols == idx, _NEG, x)
+        return x, vals, idxs
 
-    jax.lax.fori_loop(0, k, body, (x,))
+    _, vals, idxs = jax.lax.fori_loop(
+        0, k, body, (x, jnp.zeros((bb, k), jnp.float32),
+                     jnp.zeros((bb, k), jnp.int32)))
+    return vals, idxs
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bb", "interpret"))

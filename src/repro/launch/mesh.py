@@ -11,14 +11,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit Auto axis types where the installed jax
-    supports them (jax.sharding.AxisType landed after 0.4.x; older releases
-    are Auto-only, so omitting the kwarg is equivalent)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types (its default is Explicit, which
+    ``with_sharding_constraint`` refuses)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -37,9 +33,9 @@ def make_ingest_mesh(n_devices: int):
     takes any ``n_devices`` and validates it against the runtime device
     count up front, so a bad count fails with an actionable error instead
     of an opaque XLA one deep inside the first sharded dispatch. The mesh
-    is built over the *first* ``n_devices`` devices, so CPU CI can build
-    1/2/4-device meshes inside one 8-device
-    ``--xla_force_host_platform_device_count`` process.
+    is built over the *first* ``n_devices`` devices: on a TPU host these
+    are real chips, and CPU CI builds 1/2/4-device meshes inside one
+    8-device ``--xla_force_host_platform_device_count`` process.
 
     Module contract preserved: device state is only touched when this is
     *called*, never at import.
@@ -50,7 +46,9 @@ def make_ingest_mesh(n_devices: int):
     if n_devices > avail:
         raise ValueError(
             f"make_ingest_mesh(n_devices={n_devices}) but only {avail} "
-            f"jax device(s) are visible; on CPU, set XLA_FLAGS="
+            f"jax device(s) are visible; on an accelerator host the mesh "
+            f"takes real devices, so run on one with {n_devices}; on CPU "
+            f"only, set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n_devices} in the "
             f"environment BEFORE the first jax import (see the "
             f"sharded-ingest CI step)")

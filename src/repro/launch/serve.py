@@ -39,6 +39,7 @@ import time
 
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core.archive import ArchiveQueryEngine, ShardCatalog
 from repro.core.engine import QueryEngine
 from repro.core.ingest import IngestConfig, ingest
@@ -195,7 +196,7 @@ def _archive_ingest(crops, frames, apply_fn, acc_flops, cfg, class_map,
     return catalog, ing.stats, engine, service
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--stream", default="lausanne")
     ap.add_argument("--policy", default="balance",
@@ -246,10 +247,12 @@ def main():
                     help="shard streaming/archive ingest over a 1-D "
                          "('data',) mesh of N devices via the fused "
                          "sharded megastep (0 = host-staged ingest); on "
-                         "CPU export XLA_FLAGS=--xla_force_host_platform_"
-                         "device_count=N before launch")
+                         "an accelerator host these are real devices; on "
+                         "CPU only, export XLA_FLAGS=--xla_force_host_"
+                         "platform_device_count=N before launch")
     ap.add_argument("--index-out", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.mesh_devices > 0 and not (args.archive
                                       or args.stream_chunks > 0):
         raise SystemExit("--mesh-devices needs a streaming ingest path: "

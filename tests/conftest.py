@@ -1,10 +1,5 @@
 """Test config. NOTE: no XLA_FLAGS here — tests must see 1 CPU device;
 only the dry-run creates 512 placeholder devices (in its own process).
-
-Also installs a minimal ``hypothesis`` fallback when the real package is
-absent (this container has no network): ``@given`` runs each test over a
-small deterministic sample of the strategy space instead of a search. The
-real hypothesis is used automatically whenever it is importable.
 """
 import os
 
@@ -12,85 +7,6 @@ import numpy as np
 import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import functools
-    import inspect
-    import random
-    import sys
-    import types
-
-    class _Strategy:
-        def __init__(self, draw_fn):
-            self._draw = draw_fn
-
-        def example(self, rng):
-            return self._draw(rng)
-
-    class _DataObject:
-        """Stand-in for hypothesis's ``data()`` interactive draw object."""
-
-        def __init__(self, rng):
-            self._rng = rng
-
-        def draw(self, strategy, label=None):
-            return strategy.example(self._rng)
-
-    def _integers(min_value, max_value):
-        return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-    def _floats(min_value, max_value, **kw):
-        return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-    def _booleans():
-        return _Strategy(lambda rng: rng.random() < 0.5)
-
-    def _sampled_from(seq):
-        seq = list(seq)
-        return _Strategy(lambda rng: seq[rng.randint(0, len(seq) - 1)])
-
-    def _data():
-        s = _Strategy(lambda rng: _DataObject(rng))
-        s._is_data = True
-        return s
-
-    def _given(*strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_shim_max_examples",
-                            getattr(fn, "_shim_max_examples", 10))
-                for i in range(n):
-                    rng = random.Random(0xF0C05 + i * 7919)
-                    drawn = [s.example(rng) for s in strategies]
-                    fn(*args, *drawn, **kwargs)
-            # hide the drawn params from pytest's fixture resolution
-            # (real hypothesis does the same via its own wrapper signature)
-            wrapper.__signature__ = inspect.Signature()
-            del wrapper.__wrapped__
-            return wrapper
-        return deco
-
-    def _settings(max_examples=10, **kw):
-        def deco(fn):
-            fn._shim_max_examples = max_examples
-            return fn
-        return deco
-
-    hyp = types.ModuleType("hypothesis")
-    hyp.given = _given
-    hyp.settings = _settings
-    st_mod = types.ModuleType("hypothesis.strategies")
-    st_mod.integers = _integers
-    st_mod.floats = _floats
-    st_mod.booleans = _booleans
-    st_mod.sampled_from = _sampled_from
-    st_mod.data = _data
-    hyp.strategies = st_mod
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = st_mod
 
 
 @pytest.fixture

@@ -164,6 +164,26 @@ def test_pixel_difference_kernel_backend_matches_numpy():
     assert mk[9] == 4
 
 
+@pytest.mark.parametrize("Na,Nb", [(1, 1), (3, 9), (8, 8), (9, 130)])
+def test_pixel_difference_kernel_buckets_match_numpy(Na, Nb):
+    """The kernel backend pads both sides to power-of-two row buckets;
+    the pad rows never match and never shift an index."""
+    rng = np.random.default_rng(Na * 1000 + Nb)
+    a = rng.random((Na, 4, 4, 3)).astype(np.float32)
+    b = rng.random((Nb, 4, 4, 3)).astype(np.float32)
+    b[Nb - 1] = a[Na - 1] + 1e-4      # the match sits next to the pad
+    mk = pixel_difference(a, b, 0.02, backend="kernel")
+    np.testing.assert_array_equal(
+        mk, pixel_difference(a, b, 0.02, backend="numpy"))
+    assert mk[Na - 1] == Nb - 1
+
+
+def test_auto_backend_is_numpy_on_cpu():
+    from repro.data import bgsub
+    assert bgsub._kernel_backend() is False
+    assert bgsub._resolve_backend("auto") == "numpy"
+
+
 def test_pixel_difference_rejects_unknown_backend():
     a = np.zeros((1, 2, 2, 3), np.float32)
     with pytest.raises(ValueError):

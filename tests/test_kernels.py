@@ -489,3 +489,88 @@ def test_dequant_topk_property(M, C, data):
     vr, ir = ref.dequant_topk_ref(q, scales, k, global_scale=gs)
     np.testing.assert_array_equal(np.asarray(v), np.asarray(vr))
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ir))
+
+
+# ---------------------------------------------------------------------------
+# TPU tiles in interpret mode: the 128-row blocks the chip compiles, on
+# block multiples, ragged shapes and empty inputs — exact against ref.py
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,M", [(128, 128), (256, 4096), (300, 130),
+                                 (5, 77)])
+def test_centroid_assign_tpu_tiles_exact(B, M):
+    r = np.random.default_rng(B * 7 + M)
+    f = r.normal(size=(B, 128)).astype(np.float32)
+    c = r.normal(size=(M, 128)).astype(np.float32)
+    c[M // 2] = f[B - 1]                    # an exact hit in a later block
+    d2, j, m = ops.centroid_assign(f, c, bb=128, bm=128, threshold=14.0)
+    d2r, jr, mr = ref.centroid_assign_ref(f, c, threshold=14.0)
+    np.testing.assert_array_equal(np.asarray(j), np.asarray(jr))
+    np.testing.assert_array_equal(np.asarray(m), np.asarray(mr))
+    np.testing.assert_allclose(np.asarray(d2), np.asarray(d2r), atol=1e-3)
+    assert int(np.asarray(j)[B - 1]) == M // 2
+
+
+def test_centroid_assign_empty_inputs():
+    c = np.ones((5, 128), np.float32)
+    d2, j, m = ops.centroid_assign(np.zeros((0, 128), np.float32), c,
+                                   bb=128, bm=128, threshold=1.0)
+    assert d2.shape == j.shape == m.shape == (0,)
+    assert len(ops.centroid_assign(np.zeros((0, 128), np.float32), c)) == 2
+    with pytest.raises(ValueError):
+        ops.centroid_assign(c, np.zeros((0, 128), np.float32))
+
+
+@pytest.mark.parametrize("B,C,k", [(128, 1000, 10), (512, 1000, 10),
+                                   (300, 7, 7), (5, 1000, 4)])
+def test_topk_tpu_tiles_exact(B, C, k):
+    lg = np.array(jax.random.normal(jax.random.PRNGKey(B + C), (B, C)))
+    lg[:, 1] = lg[:, C - 1]                 # ties resolve to the low index
+    v, i = ops.topk(lg, k, bb=128)
+    vr, ir = ref.topk_ref(lg, k)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(vr))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ir))
+
+
+@pytest.mark.parametrize("M,C,k,dtype", [
+    (2048, 1000, 10, np.uint8), (300, 7, 4, np.int8),
+    (5, 1000, 60, np.uint8), (128, 9, 9, np.int8),
+])
+def test_dequant_topk_tpu_tiles_exact(M, C, k, dtype):
+    q, scales = _quant_rows(M, C, dtype, M + C + k)
+    v, i = ops.dequant_topk(q, scales, k, global_scale=1.0 / 255.0,
+                            bm=128)
+    vr, ir = ref.dequant_topk_ref(q, scales, k, global_scale=1.0 / 255.0)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(vr))
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ir))
+
+
+@pytest.mark.parametrize("Na,Nb", [(256, 512), (300, 130), (5, 7)])
+def test_pixel_match_tpu_tiles_exact(Na, Nb):
+    """32 px crops (D = 3072) against a gate ring, 128-row blocks."""
+    r = np.random.default_rng(Na + Nb)
+    a = r.random((Na, 3072)).astype(np.float32)
+    b = r.random((Nb, 3072)).astype(np.float32)
+    b[Nb - 1] = a[0]
+    b[Nb // 2] = a[0]                       # tie: the lower index wins
+    m, d = ops.pixel_match(a, b, 0.3, ba=128, bn=128)
+    mr, dr = ref.pixel_match_ref(a, b, 0.3)
+    np.testing.assert_array_equal(np.asarray(m), np.asarray(mr))
+    np.testing.assert_array_equal(np.asarray(d), np.asarray(dr))
+    assert int(np.asarray(m)[0]) == Nb // 2
+
+
+def test_tpu_tiles_empty_inputs():
+    v, i = ops.topk(np.zeros((0, 1000), np.float32), 10, bb=128)
+    assert v.shape == i.shape == (0, 10)
+    v, i = ops.dequant_topk(np.zeros((0, 1000), np.uint8),
+                            np.zeros(0, np.float32), 10, bm=128)
+    assert v.shape == i.shape == (0, 10)
+    m, d = ops.pixel_match(np.zeros((0, 3072), np.float32),
+                           np.ones((4, 3072), np.float32), 0.1,
+                           ba=128, bn=128)
+    assert m.shape == d.shape == (0,)
+    m, _ = ops.pixel_match(np.ones((4, 3072), np.float32),
+                           np.zeros((0, 3072), np.float32), 0.1,
+                           ba=128, bn=128)
+    assert (np.asarray(m) == -1).all()

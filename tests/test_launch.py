@@ -117,3 +117,37 @@ def test_input_specs_are_abstract():
     spec = st.build("vit-s16", "serve_b1", mesh)
     for leaf in jax.tree.leaves(spec.args):
         assert isinstance(leaf, jax.ShapeDtypeStruct)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; without it the
+    cache sits at one fixed, gitignored path inside the checkout."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.common import compile_cache
+    names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs")
+    saved = {n: getattr(jax.config, n) for n in names}
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = compile_cache.enable_compile_cache()
+        if from_env:
+            assert path == str(tmp_path)
+            # set by nobody in code: still what it was before the call
+            assert jax.config.jax_compilation_cache_dir == \
+                saved["jax_compilation_cache_dir"]
+        else:
+            root = os.path.abspath(os.path.join(SRC, ".."))
+            assert path == os.path.join(root, "experiments", "jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            with open(os.path.join(root, ".gitignore")) as f:
+                assert "experiments/" in f.read().split()
+        assert jax.config.jax_enable_compilation_cache
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
+        cc.reset_cache()

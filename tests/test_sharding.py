@@ -71,3 +71,17 @@ def test_act_specs(mesh):
     assert act_spec(mesh, "kv_cache")[1] == "model"
     with pytest.raises(ValueError):
         act_spec(mesh, "nope")
+
+
+def test_constrain_applies_and_raises_on_a_spec_that_does_not_fit(mesh):
+    """constrain places a fitting activation and raises on a rank
+    mismatch instead of returning the value unconstrained."""
+    import jax.numpy as jnp
+
+    from repro.distributed.sharding import constrain
+    x = jnp.ones((2, 3, 4))
+    out = jax.jit(lambda v: constrain(v, mesh, "hidden"))(x)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
+    assert constrain(x, None, "hidden") is x
+    with pytest.raises(ValueError):
+        jax.jit(lambda v: constrain(v, mesh, "hidden"))(jnp.ones((2, 3)))

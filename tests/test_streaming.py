@@ -452,6 +452,28 @@ def test_runner_rejects_self_driven_ingestors():
         MultiStreamRunner({}, _cheap)
 
 
+def test_runner_mesh_requires_divisible_batch_pad():
+    """Staged stacking over a mesh pads every stacked batch to batch_pad
+    rows; a batch_pad the data axis cannot divide is refused up front
+    (nothing is silently left unsharded)."""
+    from repro.launch.mesh import make_ingest_mesh
+    mesh = make_ingest_mesh(1)
+    cfg = IngestConfig(K=2, threshold=1.5, max_clusters=32, batch_size=48)
+    crops, frames = _stream(3, 120)
+    runner = MultiStreamRunner({"a": StreamingIngestor(None, 1e9, cfg)},
+                               _cheap, batch_pad=32, mesh=mesh)
+    runner.feed({"a": (crops, frames)})
+    index, _ = runner.finish()["a"]
+    want, _ = ingest(crops, frames, _cheap, 1e9, cfg)
+    assert _save_bytes(index) == _save_bytes(want)
+
+    class _Mesh3:
+        size = 3
+    with pytest.raises(ValueError, match="multiple of the mesh size"):
+        MultiStreamRunner({"a": StreamingIngestor(None, 1e9, cfg)},
+                          _cheap, batch_pad=32, mesh=_Mesh3())
+
+
 def test_runner_driven_finish_requires_runner():
     ing = StreamingIngestor(None, 1e9, IngestConfig(batch_size=64))
     crops, frames = _stream(2, 30)
