@@ -113,8 +113,11 @@ def _nbytes(saved) -> int:
 
 
 def identity_phase(checks):
-    """Megastep vs staged over the same stream and chunks, gate on."""
+    """Megastep vs staged over the same stream and chunks, gate on.
+    Returns a catalog holding the staged index sealed as one K = 4 v4
+    shard, for the lazy == eager check."""
     from benchmarks.common import get_model, load_stream
+    from repro.core.archive import ShardCatalog
     from repro.core.ingest import IngestConfig
     from repro.core.pipeline import (IngestPipeline, ShardedIngestPipeline,
                                      staged_cheap_apply)
@@ -138,7 +141,7 @@ def identity_phase(checks):
             None, flops, cfg, class_map=cmap,
             pipeline=sharded.handle(STREAM)),
     }
-    saved = {}
+    saved, indexes = {}, {}
     for name, ing in runs.items():
         t0 = time.perf_counter()
         for lo, hi in _chunks(len(crops)):
@@ -146,6 +149,7 @@ def identity_phase(checks):
             ing.flush()
         index, stats = ing.finish()
         saved[name] = index.save_bytes()
+        indexes[name] = index
         print(f"[smoke] {name}: {stats.n_objects} objects, "
               f"{stats.n_cnn_invocations} CNN rows, {stats.n_gate_skipped} "
               f"gate hits, {index.n_clusters} clusters in "
@@ -155,10 +159,16 @@ def identity_phase(checks):
               saved[name] == saved["staged"],
               f"({_nbytes(saved[name])} vs {_nbytes(saved['staged'])} "
               f"bytes saved)")
+    # serve's §4.4 selection may pick K = 1, which would leave the later
+    # passes of dequant_topk's extraction loop unchecked on the chip
+    k4 = ShardCatalog(os.path.join(WORK, "k4_archive"))
+    k4.seal(indexes["staged"], int(frames[0]), int(frames[-1]), 0)
+    return k4
 
 
-def archive_phase(checks, archive_dir):
-    """Archive answers == per-shard union; lazy v4 ranks == eager."""
+def archive_phase(checks, archive_dir, k4_catalog):
+    """Archive answers == per-shard union; lazy v4 ranks == eager, over
+    the served archive and the K = 4 shard."""
     import numpy as np
 
     from benchmarks.common import GT_FLOPS, gt_oracle, load_stream
@@ -186,17 +196,21 @@ def archive_phase(checks, archive_dir):
           f"{sum(len(r.frames) for r in results)} frames)")
 
     loader = ShardLoader(catalog)
-    n_cmp = 0
+    n_cmp, ks = 0, set()
     lossless = True
-    for m in catalog:
-        lazy, eager = loader.get(m.shard_id), catalog.load_shard(m.shard_id)
-        for c in workload:
-            for kx in range(1, lazy.K + 1):
-                a, b = lazy.lookup(c, Kx=kx), eager.lookup(c, Kx=kx)
-                lossless &= (a == b and np.array_equal(lazy.frames_of(a),
-                                                       eager.frames_of(b)))
-                n_cmp += 1
-    check(checks, "lazy_equals_eager", lossless, f"({n_cmp} lookups)")
+    for cat, ldr in ((catalog, loader),
+                     (k4_catalog, ShardLoader(k4_catalog))):
+        for m in cat:
+            lazy, eager = ldr.get(m.shard_id), cat.load_shard(m.shard_id)
+            ks.add(lazy.K)
+            for c in workload:
+                for kx in range(1, lazy.K + 1):
+                    a, b = lazy.lookup(c, Kx=kx), eager.lookup(c, Kx=kx)
+                    lossless &= (a == b and np.array_equal(
+                        lazy.frames_of(a), eager.frames_of(b)))
+                    n_cmp += 1
+    check(checks, "lazy_equals_eager", lossless,
+          f"({n_cmp} lookups, shard K in {sorted(ks)})")
     return catalog, loader, workload
 
 
@@ -252,9 +266,9 @@ def one_chip(compiles):
     checks = {}
     archive_dir = os.path.join(WORK, "archive")
     run_phase("ingest+serve", lambda: serve_phase(archive_dir), compiles)
-    run_phase("identity", lambda: identity_phase(checks), compiles)
+    k4 = run_phase("identity", lambda: identity_phase(checks), compiles)
     catalog, loader, workload = run_phase(
-        "archive", lambda: archive_phase(checks, archive_dir), compiles)
+        "archive", lambda: archive_phase(checks, archive_dir, k4), compiles)
     run_phase("gt", lambda: gt_phase(checks, loader, catalog, workload),
               compiles)
     return checks
