@@ -38,7 +38,6 @@ agree bit-for-bit.
 """
 from __future__ import annotations
 
-import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -47,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import spans
 from repro.core import clustering as C
 from repro.kernels import ops as kops
 
@@ -303,13 +303,12 @@ class IngestPipeline:
         if ing is None:
             raise RuntimeError("pipeline is not bound to an ingestor; "
                                "pass it to StreamingIngestor(pipeline=...)")
-        t0 = time.perf_counter()
-        if ing._state is None:
-            self._init_state(crops)
-        rec = self._dispatch(crops, objs, frames)
+        with spans.span("ingest.megastep", ing.stats):
+            if ing._state is None:
+                self._init_state(crops)
+            rec = self._dispatch(crops, objs, frames)
         # double buffer: fold batch N-1 on the host while the device runs N
         prev, self._pending = self._pending, None
-        ing.stats.wall_s += time.perf_counter() - t0
         if prev is not None:
             self._fold(prev)
         self._resolve(rec)
@@ -378,88 +377,88 @@ class IngestPipeline:
     def _resolve(self, rec: _InFlight):
         """Sync the tiny assignment outputs, run the unmatched tail, and
         decide eviction — everything batch N+1's megastep depends on.
-        Times itself into ``stats.wall_s``, pausing around ``_fold`` (it
-        keeps its own clock) so eviction batches are not double-counted."""
+        An eviction folds this batch at once, then evicts (``ingest.fold``);
+        otherwise the batch waits for the next submit's overlapped fold."""
         ing = self._ing
-        t0 = time.perf_counter()
-        # focuslint: disable=host-sync -- single tiny (j, matched) fetch
-        # per resolved batch; the double-buffered dispatch has already
-        # overlapped this batch's compute
-        j, matched = jax.device_get((rec.j, rec.matched))
-        rec.j = np.asarray(j)[:rec.n]
-        rec.matched = np.asarray(matched)[:rec.n]
-        rec.unmatched_idx = np.nonzero(~rec.matched)[0]
-        U = len(rec.unmatched_idx)
-        if U:
-            # identical tail construction to cluster_fused: gather indices
-            # padded to a power-of-two bucket, invalid rows are no-ops.
-            # Tail executables are keyed by (P, feats bucket) — a bounded
-            # set (P is a power of two <= bucket), tracked so a retrace
-            # regression in the tail path also trips the CI compile gate
-            P = C._pad_bucket(U)
-            tail_key = ("tail", P, rec.feats.shape[0])
-            if tail_key in self._seen_keys:
-                self.stats.tail_compile_hits += 1
-            else:
-                self._seen_keys.add(tail_key)
-                self.stats.tail_compile_misses += 1
-            gather = np.zeros((P,), np.int64)
-            gather[:U] = rec.unmatched_idx
-            st = ing._state
-            cen, cnt, nn, sub_ids = _scan_tail_jit()(
-                st.centroids, st.counts, st.n, rec.feats,
-                jnp.asarray(gather), jnp.asarray(np.arange(P) < U),
-                jnp.asarray(self.cfg.threshold, jnp.float32))
-            ing._state = C.ClusterState(cen, cnt, nn)
-            rec.sub_ids = sub_ids
-            self.stats.n_dispatches += 1
-            self.stats.n_tail_scans += 1
-            self._n_hi += U
-        # eviction uses the same trigger as the staged path (state.n at
-        # high water), but only syncs when the bound says it could fire:
-        # n_hi >= actual n always, so no staged eviction point is missed
-        hw = int(self.cfg.high_water * self.cfg.max_clusters)
-        if self._n_hi >= hw:
-            self.stats.n_eviction_syncs += 1
-            # focuslint: disable=host-sync -- bound-gated: fires only
-            # when _n_hi crosses the ceiling, not per batch (counted in
-            # stats.n_eviction_syncs)
-            n_live = int(jax.device_get(ing._state.n))
-            self._n_hi = n_live
-            if n_live >= hw:
-                # the remap must not run before this batch's slots are
-                # translated: fold now (no overlap for this rare batch)
-                ing.stats.wall_s += time.perf_counter() - t0
-                self._fold(rec)
-                t0 = time.perf_counter()
-                ing._evict_live()
-                # focuslint: disable=host-sync -- rare eviction path;
-                # the remap must land before the next dispatch
+        with spans.span("ingest.megastep", ing.stats):
+            # focuslint: disable=host-sync -- single tiny (j, matched)
+            # fetch per resolved batch; the double-buffered dispatch has
+            # already overlapped this batch's compute
+            j, matched = jax.device_get((rec.j, rec.matched))
+            rec.j = np.asarray(j)[:rec.n]
+            rec.matched = np.asarray(matched)[:rec.n]
+            rec.unmatched_idx = np.nonzero(~rec.matched)[0]
+            U = len(rec.unmatched_idx)
+            if U:
+                # identical tail construction to cluster_fused: gather
+                # indices padded to a power-of-two bucket, invalid rows are
+                # no-ops. Tail executables are keyed by (P, feats bucket) —
+                # a bounded set (P is a power of two <= bucket), tracked so
+                # a retrace regression in the tail path also trips the CI
+                # compile gate
+                P = C._pad_bucket(U)
+                tail_key = ("tail", P, rec.feats.shape[0])
+                if tail_key in self._seen_keys:
+                    self.stats.tail_compile_hits += 1
+                else:
+                    self._seen_keys.add(tail_key)
+                    self.stats.tail_compile_misses += 1
+                gather = np.zeros((P,), np.int64)
+                gather[:U] = rec.unmatched_idx
+                st = ing._state
+                cen, cnt, nn, sub_ids = _scan_tail_jit()(
+                    st.centroids, st.counts, st.n, rec.feats,
+                    jnp.asarray(gather), jnp.asarray(np.arange(P) < U),
+                    jnp.asarray(self.cfg.threshold, jnp.float32))
+                ing._state = C.ClusterState(cen, cnt, nn)
+                rec.sub_ids = sub_ids
+                self.stats.n_dispatches += 1
+                self.stats.n_tail_scans += 1
+                self._n_hi += U
+            # eviction uses the same trigger as the staged path (state.n at
+            # high water), but only syncs when the bound says it could
+            # fire: n_hi >= actual n always, so no staged eviction point is
+            # missed
+            hw = int(self.cfg.high_water * self.cfg.max_clusters)
+            evict = False
+            if self._n_hi >= hw:
+                self.stats.n_eviction_syncs += 1
+                # focuslint: disable=host-sync -- bound-gated: fires only
+                # when _n_hi crosses the ceiling, not per batch (counted in
+                # stats.n_eviction_syncs)
                 self._n_hi = int(jax.device_get(ing._state.n))
-                ing.stats.wall_s += time.perf_counter() - t0
-                return
-        self._pending = rec
-        ing.stats.wall_s += time.perf_counter() - t0
+                evict = self._n_hi >= hw
+        if not evict:
+            self._pending = rec
+            return
+        # the remap must not run before this batch's slots are translated:
+        # fold now (no overlap for this rare batch)
+        self._fold(rec)
+        with spans.span("ingest.fold", ing.stats):
+            ing._evict_live()
+            # focuslint: disable=host-sync -- rare eviction path; the
+            # remap must land before the next dispatch
+            self._n_hi = int(jax.device_get(ing._state.n))
 
     def _fold(self, rec: _InFlight):
         """Host side of the fold: scatter tail ids, slot → cid, SoA index
         update — mirrors the staged ``fold_batch`` exactly."""
         ing = self._ing
-        t0 = time.perf_counter()
-        slots = rec.j.astype(np.int32)
-        if len(rec.unmatched_idx):
-            slots[rec.unmatched_idx] = \
-                np.asarray(rec.sub_ids)[:len(rec.unmatched_idx)]
-        probs = np.asarray(rec.probs, np.float32)[:rec.n]
-        feats = np.asarray(rec.feats, np.float32)[:rec.n]
-        ing.stats.n_cnn_invocations += rec.n
-        ing.stats.cheap_flops += rec.n * ing.cheap_flops_per_image
-        ing._fold_rows(rec.crops, rec.objs, rec.frames, probs, feats, slots)
-        self.stats.n_objects += rec.n
-        if self.topk_sink is not None:
-            self.topk_sink(rec.objs, np.asarray(rec.vals)[:rec.n],
-                           np.asarray(rec.idxs)[:rec.n])
-        ing.stats.wall_s += time.perf_counter() - t0
+        with spans.span("ingest.fold", ing.stats):
+            slots = rec.j.astype(np.int32)
+            if len(rec.unmatched_idx):
+                slots[rec.unmatched_idx] = \
+                    np.asarray(rec.sub_ids)[:len(rec.unmatched_idx)]
+            probs = np.asarray(rec.probs, np.float32)[:rec.n]
+            feats = np.asarray(rec.feats, np.float32)[:rec.n]
+            ing.stats.n_cnn_invocations += rec.n
+            ing.stats.cheap_flops += rec.n * ing.cheap_flops_per_image
+            ing._fold_rows(rec.crops, rec.objs, rec.frames, probs, feats,
+                           slots)
+            self.stats.n_objects += rec.n
+            if self.topk_sink is not None:
+                self.topk_sink(rec.objs, np.asarray(rec.vals)[:rec.n],
+                               np.asarray(rec.idxs)[:rec.n])
 
 
 # ---------------------------------------------------------------------------
@@ -502,7 +501,7 @@ def _sharded_megastep_jit(cheap_fn: Callable, k_top: int, with_topk: bool,
 
     from repro.distributed import sharding as shd
 
-    def block(cen, cnt, nv, thr, n_real, crops):
+    def ingest_megastep(cen, cnt, nv, thr, n_real, crops):
         # per-device block: cen (W,M,D) cnt (W,M) nv (W,) n_real (W,)
         # crops (W,B,R,R,3); thr is replicated. Unrolled so every slot
         # runs the unbatched single-device computation bit-for-bit.
@@ -530,8 +529,9 @@ def _sharded_megastep_jit(cheap_fn: Callable, k_top: int, with_topk: bool,
     if with_topk:
         out_specs = out_specs + (s(2), s(2))
     # check_vma=False: Pallas calls have no replication rule
-    fn = jax.jit(jax.shard_map(block, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False),
+    fn = jax.jit(jax.shard_map(ingest_megastep, mesh=mesh,
+                               in_specs=in_specs, out_specs=out_specs,
+                               check_vma=False),
                  donate_argnums=_donate_argnums())
     _MEGASTEP_JITS[key] = fn
     if len(_MEGASTEP_JITS) > _MEGASTEP_JITS_MAX:
@@ -553,7 +553,7 @@ def _sharded_tail_jit(mesh, width: int) -> Callable:
 
     from repro.distributed import sharding as shd
 
-    def block(cen, cnt, nv, feats, gather, valid, thr):
+    def ingest_tail(cen, cnt, nv, feats, gather, valid, thr):
         outs = []
         for w in range(width):
             st, sub = C._scan_unmatched(
@@ -563,7 +563,7 @@ def _sharded_tail_jit(mesh, width: int) -> Callable:
         return tuple(jnp.stack([o[i] for o in outs]) for i in range(4))
 
     s = lambda r: shd.stream_spec(mesh, r)          # noqa: E731
-    fn = jax.jit(jax.shard_map(block, mesh=mesh,
+    fn = jax.jit(jax.shard_map(ingest_tail, mesh=mesh,
                                in_specs=(s(2), s(1), s(0), s(2), s(1),
                                          s(1), P()),
                                out_specs=(s(2), s(1), s(0), s(1)),
@@ -741,99 +741,100 @@ class ShardedIngestPipeline:
         active = [h for h in self._slots if h is not None and h.queue]
         if not active:
             return 0
-        t0 = time.perf_counter()
-        cfg = self.cfg
-        lead_crops = active[0].queue[0][0]
-        bucket = batch_bucket(len(active[0].queue[0][1]), cfg.batch_size)
-        shape = lead_crops.shape[1:]
-        group = [h for h in active
-                 if batch_bucket(len(h.queue[0][1]),
-                                 cfg.batch_size) == bucket
-                 and h.queue[0][0].shape[1:] == shape]
-        if self._cen is None:
-            self._init_stacked(lead_crops)
-        key = (bucket, shape[0])
-        if key in self._seen_keys:
-            self.stats.compile_hits += 1
-        else:
-            self._seen_keys.add(key)
-            self.stats.compile_misses += 1
-
-        S = len(self._slots)
-        crops_stack = np.zeros((S, bucket) + shape, lead_crops.dtype)
-        n_real = np.zeros((S,), np.int32)
-        parts: Dict[int, tuple] = {}
-        for h in group:
-            crops, objs, frames = h.queue.popleft()
-            crops_stack[h.slot, :len(objs)] = crops
-            n_real[h.slot] = len(objs)
-            parts[h.slot] = (h, crops, objs, frames)
-
-        k_top = self.topk_k if self.topk_k is not None else cfg.K
-        with_topk = self.topk_sink is not None
-        fn = self._megastep_fn = _sharded_megastep_jit(
-            self.cheap_fn, k_top, with_topk, self.mesh, self.width)
-        out = fn(self._cen, self._cnt, self._n, self._thr,
-                 jax.device_put(n_real, self._shardings["n_real"]),
-                 jax.device_put(crops_stack, self._shardings["crops"]))
-        if with_topk:
-            cen, cnt, nv, probs, feats, j, matched, vals, idxs = out
-        else:
-            cen, cnt, nv, probs, feats, j, matched = out
-            vals = idxs = None
-        self._cen, self._cnt, self._n = cen, cnt, nv
-        self.stats.n_dispatches += 1
-        self.stats.n_steps += 1
-        self.stats.n_batches += len(parts)
-
-        # focuslint: disable=host-sync -- the ONE designed per-step
-        # (j, matched) fetch: the whole stack in a single device_get (a
-        # per-slot slice fetch would dispatch a gather per stream)
-        j_h, m_h = jax.device_get((j, matched))
-        j_h, m_h = np.asarray(j_h), np.asarray(m_h)
-
-        # stacked unmatched tail: one more dispatch covering every stream
-        # that needs it; others ride along as bitwise no-ops
-        tails: Dict[int, np.ndarray] = {}
-        u_max = 0
-        for slot, (h, crops, objs, frames) in parts.items():
-            um = np.nonzero(~m_h[slot, :len(objs)])[0]
-            if len(um):
-                tails[slot] = um
-                u_max = max(u_max, len(um))
-        sub_h = None
-        if tails:
-            P = C._pad_bucket(u_max)
-            tail_key = ("tail", P, bucket)
-            if tail_key in self._seen_keys:
-                self.stats.tail_compile_hits += 1
+        step = spans.Wall()             # shared out over the step's streams
+        with spans.span("ingest.megastep", step):
+            cfg = self.cfg
+            lead_crops = active[0].queue[0][0]
+            bucket = batch_bucket(len(active[0].queue[0][1]), cfg.batch_size)
+            shape = lead_crops.shape[1:]
+            group = [h for h in active
+                     if batch_bucket(len(h.queue[0][1]),
+                                     cfg.batch_size) == bucket
+                     and h.queue[0][0].shape[1:] == shape]
+            if self._cen is None:
+                self._init_stacked(lead_crops)
+            key = (bucket, shape[0])
+            if key in self._seen_keys:
+                self.stats.compile_hits += 1
             else:
-                self._seen_keys.add(tail_key)
-                self.stats.tail_compile_misses += 1
-            gather = np.zeros((S, P), np.int64)
-            valid = np.zeros((S, P), bool)
-            for slot, um in tails.items():
-                gather[slot, :len(um)] = um
-                valid[slot, :len(um)] = True
-            gfn = self._tail_fn = _sharded_tail_jit(self.mesh, self.width)
-            cen, cnt, nv, sub = gfn(
-                self._cen, self._cnt, self._n, feats,
-                jax.device_put(gather, self._shardings["rows"]),
-                jax.device_put(valid, self._shardings["rows"]), self._thr)
+                self._seen_keys.add(key)
+                self.stats.compile_misses += 1
+
+            S = len(self._slots)
+            crops_stack = np.zeros((S, bucket) + shape, lead_crops.dtype)
+            n_real = np.zeros((S,), np.int32)
+            parts: Dict[int, tuple] = {}
+            for h in group:
+                crops, objs, frames = h.queue.popleft()
+                crops_stack[h.slot, :len(objs)] = crops
+                n_real[h.slot] = len(objs)
+                parts[h.slot] = (h, crops, objs, frames)
+
+            k_top = self.topk_k if self.topk_k is not None else cfg.K
+            with_topk = self.topk_sink is not None
+            fn = self._megastep_fn = _sharded_megastep_jit(
+                self.cheap_fn, k_top, with_topk, self.mesh, self.width)
+            out = fn(self._cen, self._cnt, self._n, self._thr,
+                     jax.device_put(n_real, self._shardings["n_real"]),
+                     jax.device_put(crops_stack, self._shardings["crops"]))
+            if with_topk:
+                cen, cnt, nv, probs, feats, j, matched, vals, idxs = out
+            else:
+                cen, cnt, nv, probs, feats, j, matched = out
+                vals = idxs = None
             self._cen, self._cnt, self._n = cen, cnt, nv
             self.stats.n_dispatches += 1
-            self.stats.n_tail_scans += 1
+            self.stats.n_steps += 1
+            self.stats.n_batches += len(parts)
 
-        # focuslint: disable=host-sync -- designed fold boundary: the fold
-        # rows (probs/feats[/topk/tail ids]) for ALL streams in ONE fetch
-        fetch = jax.device_get(tuple(
-            a for a in (probs, feats, vals, idxs,
-                        sub if tails else None) if a is not None))
-        probs_h, feats_h = np.asarray(fetch[0]), np.asarray(fetch[1])
-        if with_topk:
-            vals_h, idxs_h = np.asarray(fetch[2]), np.asarray(fetch[3])
-        if tails:
-            sub_h = np.asarray(fetch[-1])
+            # focuslint: disable=host-sync -- the ONE designed per-step
+            # (j, matched) fetch: the whole stack in a single device_get (a
+            # per-slot slice fetch would dispatch a gather per stream)
+            j_h, m_h = jax.device_get((j, matched))
+            j_h, m_h = np.asarray(j_h), np.asarray(m_h)
+
+            # stacked unmatched tail: one more dispatch covering every stream
+            # that needs it; others ride along as bitwise no-ops
+            tails: Dict[int, np.ndarray] = {}
+            u_max = 0
+            for slot, (h, crops, objs, frames) in parts.items():
+                um = np.nonzero(~m_h[slot, :len(objs)])[0]
+                if len(um):
+                    tails[slot] = um
+                    u_max = max(u_max, len(um))
+            sub_h = None
+            if tails:
+                P = C._pad_bucket(u_max)
+                tail_key = ("tail", P, bucket)
+                if tail_key in self._seen_keys:
+                    self.stats.tail_compile_hits += 1
+                else:
+                    self._seen_keys.add(tail_key)
+                    self.stats.tail_compile_misses += 1
+                gather = np.zeros((S, P), np.int64)
+                valid = np.zeros((S, P), bool)
+                for slot, um in tails.items():
+                    gather[slot, :len(um)] = um
+                    valid[slot, :len(um)] = True
+                gfn = self._tail_fn = _sharded_tail_jit(self.mesh, self.width)
+                cen, cnt, nv, sub = gfn(
+                    self._cen, self._cnt, self._n, feats,
+                    jax.device_put(gather, self._shardings["rows"]),
+                    jax.device_put(valid, self._shardings["rows"]), self._thr)
+                self._cen, self._cnt, self._n = cen, cnt, nv
+                self.stats.n_dispatches += 1
+                self.stats.n_tail_scans += 1
+
+            # focuslint: disable=host-sync -- designed fold boundary: the fold
+            # rows (probs/feats[/topk/tail ids]) for ALL streams in ONE fetch
+            fetch = jax.device_get(tuple(
+                a for a in (probs, feats, vals, idxs,
+                            sub if tails else None) if a is not None))
+            probs_h, feats_h = np.asarray(fetch[0]), np.asarray(fetch[1])
+            if with_topk:
+                vals_h, idxs_h = np.asarray(fetch[2]), np.asarray(fetch[3])
+            if tails:
+                sub_h = np.asarray(fetch[-1])
 
         # host fold per stream in slot order; evictions collect and run
         # once after the loop (per-slot independent, so batching the
@@ -846,37 +847,40 @@ class ShardedIngestPipeline:
             h, crops, objs, frames = parts[slot]
             n = len(objs)
             ing = h._ing
-            slots_v = j_h[slot, :n].astype(np.int32)
-            um = tails.get(slot)
-            if um is not None:
-                slots_v[um] = sub_h[slot, :len(um)]
-                h._n_hi += len(um)
-            ing.stats.n_cnn_invocations += n
-            ing.stats.cheap_flops += n * ing.cheap_flops_per_image
-            ing._fold_rows(crops, objs, frames, probs_h[slot, :n],
-                           feats_h[slot, :n], slots_v)
-            self.stats.n_objects += n
-            total += n
-            if with_topk:
-                self.topk_sink(h.name, objs, vals_h[slot, :n],
-                               idxs_h[slot, :n])
-            # same bound-gated eviction trigger as IngestPipeline._resolve:
-            # n_hi >= live n always, so no staged eviction point is missed
-            if h._n_hi >= hw:
-                if n_host is None:
-                    self.stats.n_eviction_syncs += 1
-                    # focuslint: disable=host-sync -- bound-gated: the
-                    # tiny (S,) live-count vector, once per crossing step
-                    n_host = np.asarray(jax.device_get(self._n))
-                h._n_hi = int(n_host[slot])
+            with spans.span("ingest.fold", ing.stats):
+                slots_v = j_h[slot, :n].astype(np.int32)
+                um = tails.get(slot)
+                if um is not None:
+                    slots_v[um] = sub_h[slot, :len(um)]
+                    h._n_hi += len(um)
+                ing.stats.n_cnn_invocations += n
+                ing.stats.cheap_flops += n * ing.cheap_flops_per_image
+                ing._fold_rows(crops, objs, frames, probs_h[slot, :n],
+                               feats_h[slot, :n], slots_v)
+                self.stats.n_objects += n
+                total += n
+                if with_topk:
+                    self.topk_sink(h.name, objs, vals_h[slot, :n],
+                                   idxs_h[slot, :n])
+                # same bound-gated eviction trigger as
+                # IngestPipeline._resolve: n_hi >= live n always, so no
+                # staged eviction point is missed
                 if h._n_hi >= hw:
-                    evictors.append(h)
+                    if n_host is None:
+                        self.stats.n_eviction_syncs += 1
+                        # focuslint: disable=host-sync -- bound-gated:
+                        # the tiny (S,) live-count vector, once per
+                        # crossing step
+                        n_host = np.asarray(jax.device_get(self._n))
+                    h._n_hi = int(n_host[slot])
+                    if h._n_hi >= hw:
+                        evictors.append(h)
         if evictors:
-            self._evict_slots(evictors)
-        dt = time.perf_counter() - t0
+            with spans.span("ingest.fold", step):
+                self._evict_slots(evictors)
         for slot in parts:
             h, _, objs, _ = parts[slot]
-            h._ing.stats.wall_s += dt * (len(objs) / max(total, 1))
+            h._ing.stats.wall_s += step.wall_s * (len(objs) / max(total, 1))
         return total
 
     # -- internals -------------------------------------------------------------

@@ -28,12 +28,12 @@ still waiting for a full batch and the duplicates chained to them.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
+from repro.common import spans
 from repro.core import clustering as C
 from repro.core.index import ClassMap, TopKIndex
 from repro.core.ingest import IngestConfig, IngestStats
@@ -82,21 +82,23 @@ class _PixelTracker:
             raise ValueError(
                 f"frames must be non-decreasing across feeds: got frame {f} "
                 f"after frame {self._open_frame}")
-        if self._open_frame is None or f > self._open_frame:
-            if self._open_crops:
-                self._prev_frame = self._open_frame
-                self._prev_crops = np.concatenate(self._open_crops)
-                self._prev_roots = np.concatenate(self._open_roots)
-            self._open_frame = f
-            self._open_crops, self._open_roots = [], []
-        roots = obj_ids.copy()
-        if self._prev_frame == f - 1 and self._prev_crops is not None \
-                and len(self._prev_crops):
-            match = pixel_difference(crops, self._prev_crops, self.threshold)
-            m = match >= 0
-            roots[m] = self._prev_roots[match[m]]
-        self._open_crops.append(crops)
-        self._open_roots.append(roots)
+        with spans.span("ingest.track"):
+            if self._open_frame is None or f > self._open_frame:
+                if self._open_crops:
+                    self._prev_frame = self._open_frame
+                    self._prev_crops = np.concatenate(self._open_crops)
+                    self._prev_roots = np.concatenate(self._open_roots)
+                self._open_frame = f
+                self._open_crops, self._open_roots = [], []
+            roots = obj_ids.copy()
+            if self._prev_frame == f - 1 and self._prev_crops is not None \
+                    and len(self._prev_crops):
+                match = pixel_difference(crops, self._prev_crops,
+                                         self.threshold)
+                m = match >= 0
+                roots[m] = self._prev_roots[match[m]]
+            self._open_crops.append(crops)
+            self._open_roots.append(roots)
         return roots
 
     def amend_last(self, roots: np.ndarray):
@@ -147,21 +149,22 @@ class _RedundancyGate:
         """Ring root id per crop (or -1) for one frame-``f`` segment.
         Also advances the open-frame bookkeeping, so call it once per
         resolved segment even when ``crops2d`` is empty."""
-        if self._open_frame is None or f > self._open_frame:
-            if self._open_crops:
-                self._push(np.concatenate(self._open_crops),
-                           np.concatenate(self._open_roots))
-                self._open_crops, self._open_roots = [], []
-            self._open_frame = f
-        out = np.full((len(crops2d),), -1, np.int64)
-        if self._n == 0 or len(crops2d) == 0:
-            return out
-        m = match_flat(crops2d, np.concatenate(self._ring_crops),
-                       self.threshold, backend=self.backend)
-        hit = m >= 0
-        if hit.any():
-            roots = np.concatenate(self._ring_roots)
-            out[hit] = roots[m[hit]]
+        with spans.span("ingest.gate"):
+            if self._open_frame is None or f > self._open_frame:
+                if self._open_crops:
+                    self._push(np.concatenate(self._open_crops),
+                               np.concatenate(self._open_roots))
+                    self._open_crops, self._open_roots = [], []
+                self._open_frame = f
+            out = np.full((len(crops2d),), -1, np.int64)
+            if self._n == 0 or len(crops2d) == 0:
+                return out
+            m = match_flat(crops2d, np.concatenate(self._ring_crops),
+                           self.threshold, backend=self.backend)
+            hit = m >= 0
+            if hit.any():
+                roots = np.concatenate(self._ring_roots)
+                out[hit] = roots[m[hit]]
         return out
 
     def admit(self, crops2d: np.ndarray, roots: np.ndarray):
@@ -541,35 +544,34 @@ class StreamingIngestor:
                       obj_ids: np.ndarray):
         """Pixel-diff + buffer one frame-sorted, single-shard segment,
         folding every completed CNN batch."""
-        t0 = time.perf_counter()
         n = len(crops)
-        if self.cfg.pixel_diff or self._gate is not None:
-            i = 0
-            while i < n:
-                f = int(frames[i])
-                j = i
-                while j < n and frames[j] == f:
-                    j += 1
-                ids = obj_ids[i:j]
-                if self.cfg.pixel_diff:
-                    roots = self._tracker.resolve(f, crops[i:j], ids)
-                    self.stats.n_pixel_dedup += int((roots != ids).sum())
-                else:
-                    roots = ids.copy()
-                if self._gate is not None:
-                    roots = self._gate_segment(f, crops[i:j], ids, roots)
-                uniq = roots == ids
-                self._buffer_unique(crops[i:j][uniq], ids[uniq],
-                                    frames[i:j][uniq])
-                if not uniq.all():
-                    dup = ~uniq
-                    self._dup_objs.append(ids[dup])
-                    self._dup_frames.append(frames[i:j][dup])
-                    self._dup_roots.append(roots[dup])
-                i = j
-        else:
-            self._buffer_unique(crops, obj_ids, frames)
-        self.stats.wall_s += time.perf_counter() - t0
+        with spans.span("ingest.frames", self.stats):
+            if self.cfg.pixel_diff or self._gate is not None:
+                i = 0
+                while i < n:
+                    f = int(frames[i])
+                    j = i
+                    while j < n and frames[j] == f:
+                        j += 1
+                    ids = obj_ids[i:j]
+                    if self.cfg.pixel_diff:
+                        roots = self._tracker.resolve(f, crops[i:j], ids)
+                        self.stats.n_pixel_dedup += int((roots != ids).sum())
+                    else:
+                        roots = ids.copy()
+                    if self._gate is not None:
+                        roots = self._gate_segment(f, crops[i:j], ids, roots)
+                    uniq = roots == ids
+                    self._buffer_unique(crops[i:j][uniq], ids[uniq],
+                                        frames[i:j][uniq])
+                    if not uniq.all():
+                        dup = ~uniq
+                        self._dup_objs.append(ids[dup])
+                        self._dup_frames.append(frames[i:j][dup])
+                        self._dup_roots.append(roots[dup])
+                    i = j
+            else:
+                self._buffer_unique(crops, obj_ids, frames)
         if self.cheap_apply is not None or self.pipeline is not None:
             self._drain_ready()
 
@@ -619,11 +621,13 @@ class StreamingIngestor:
                 self.pipeline.submit(*self.take_ready_batch())
             return
         while self.n_ready_batches:
-            crops, objs, frames = self.take_ready_batch()
-            t0 = time.perf_counter()
+            self._staged_step(*self.take_ready_batch())
+
+    def _staged_step(self, crops, objs, frames):
+        """Host-staged path: the cheap CNN's forward, then the fold."""
+        with spans.span("ingest.megastep", self.stats):
             probs, feats = self.cheap_apply(crops)
-            self.stats.wall_s += time.perf_counter() - t0
-            self.fold_batch(crops, objs, frames, probs, feats)
+        self.fold_batch(crops, objs, frames, probs, feats)
 
     # -- the chunk-step --------------------------------------------------------
 
@@ -634,30 +638,32 @@ class StreamingIngestor:
         the index — the loop body of the old one-shot ``ingest()``, with
         ``slot_cid`` / eviction remaps carried across calls. An
         ``IngestPipeline`` computes clustering on-device instead and
-        enters below at ``_fold_rows`` with precomputed slots.
+        enters below at ``_fold_rows`` with precomputed slots; the staged
+        clustering inside counts as ``ingest.megastep``.
         """
-        t0 = time.perf_counter()
-        probs = np.asarray(probs)
-        feats = np.asarray(feats, np.float32)
-        self.stats.n_cnn_invocations += len(obj_ids)
-        self.stats.cheap_flops += len(obj_ids) * self.cheap_flops_per_image
-
-        if self._state is None:
-            self._state = C.init_state(self.cfg.max_clusters, feats.shape[1])
-        state, slots = self._cluster_fn(self._state, feats,
-                                        self.cfg.threshold)
-        self._state = state
-        # focuslint: disable=host-sync -- staged path folds on host per
-        # batch by design; the fused pipeline removes this sync
-        slots_np = np.asarray(slots)
-        self._fold_rows(crops, obj_ids, frames, probs, feats, slots_np)
-        # eviction keeps the live table at M (paper: evict smallest)
-        # focuslint: disable=host-sync -- staged path checks the live
-        # count per fold; the fused pipeline's _n_hi bound replaces it
-        if int(self._state.n) >= int(self.cfg.high_water
-                                     * self.cfg.max_clusters):
-            self._evict_live()
-        self.stats.wall_s += time.perf_counter() - t0
+        with spans.span("ingest.fold", self.stats):
+            probs = np.asarray(probs)
+            feats = np.asarray(feats, np.float32)
+            self.stats.n_cnn_invocations += len(obj_ids)
+            self.stats.cheap_flops += (len(obj_ids)
+                                       * self.cheap_flops_per_image)
+            if self._state is None:
+                self._state = C.init_state(self.cfg.max_clusters,
+                                           feats.shape[1])
+            with spans.span("ingest.megastep"):
+                state, slots = self._cluster_fn(self._state, feats,
+                                                self.cfg.threshold)
+                self._state = state
+                # focuslint: disable=host-sync -- staged path folds on
+                # host per batch by design; the fused pipeline removes it
+                slots_np = np.asarray(slots)
+            self._fold_rows(crops, obj_ids, frames, probs, feats, slots_np)
+            # eviction keeps the live table at M (paper: evict smallest)
+            # focuslint: disable=host-sync -- staged path checks the live
+            # count per fold; the fused pipeline's _n_hi bound replaces it
+            if int(self._state.n) >= int(self.cfg.high_water
+                                         * self.cfg.max_clusters):
+                self._evict_live()
 
     def _fold_rows(self, crops: np.ndarray, obj_ids: np.ndarray,
                    frames: np.ndarray, probs: np.ndarray,
@@ -719,52 +725,53 @@ class StreamingIngestor:
         tracker, object ids). The next shard then ingests exactly like a
         fresh run, which is what makes every sealed shard byte-identical
         to a one-shot ``ingest()`` of its window."""
-        self._drain_ready()
-        if len(self._buf):
-            crops, objs, frames = self.take_tail()
-            self._fold_tail(crops, objs, frames)
-        if self.pipeline is not None:
-            self.pipeline.flush_pending()
-        if self._index is None:
-            self._index = self._empty_index()
-        self._attach_eligible()
-        self._dup_objs, self._dup_frames, self._dup_roots = [], [], []
-        seal_kw = ({} if self.shard_format is None
-                   else {"format": self.shard_format})
-        meta = self.catalog.seal(
-            self._index,
-            frame_lo=(self._shard_frame_lo
-                      if self._shard_frame_lo is not None else 0),
-            frame_hi=(self._shard_frame_hi
-                      if self._shard_frame_hi is not None else 0),
-            obj_base=self._shard_obj_base, **seal_kw)
-        # clusters touched since the last flush now live in the sealed
-        # shard; report them shard-tagged so a query-side cache can warm
-        # them under their final identity
-        self._delta_sealed.append(meta.shard_id)
-        self._delta_touched_sealed.extend(
-            (meta.shard_id, c) for c in sorted(self._delta_touched))
-        self._delta_touched = set()
-        self._delta_new = []
-        self._state = None
-        self._slot_cid = np.full(self.cfg.max_clusters, -1, np.int64)
-        self._next_cid = 0
-        self._tracker = _PixelTracker(self.cfg.pixel_diff_threshold)
-        self._gate = (_RedundancyGate(self.cfg.gate_threshold,
-                                      self.cfg.gate_capacity)
-                      if self.cfg.gate else None)
-        self._root_cid = {}
-        self._index = (self._empty_index()
-                       if self.n_local_classes is not None
-                       or self.class_map is not None else None)
-        self._shard_obj_base += self._shard_n_fed
-        self._shard_n_fed = 0
-        self._obj_next = 0
-        self._shard_frame_lo = None
-        self._shard_frame_hi = None
-        self._shard_window_end = None
-        if self.pipeline is not None:
-            self.pipeline.reset()
+        with spans.span("ingest.seal"):
+            self._drain_ready()
+            if len(self._buf):
+                crops, objs, frames = self.take_tail()
+                self._fold_tail(crops, objs, frames)
+            if self.pipeline is not None:
+                self.pipeline.flush_pending()
+            if self._index is None:
+                self._index = self._empty_index()
+            self._attach_eligible()
+            self._dup_objs, self._dup_frames, self._dup_roots = [], [], []
+            seal_kw = ({} if self.shard_format is None
+                       else {"format": self.shard_format})
+            meta = self.catalog.seal(
+                self._index,
+                frame_lo=(self._shard_frame_lo
+                          if self._shard_frame_lo is not None else 0),
+                frame_hi=(self._shard_frame_hi
+                          if self._shard_frame_hi is not None else 0),
+                obj_base=self._shard_obj_base, **seal_kw)
+            # clusters touched since the last flush now live in the sealed
+            # shard; report them shard-tagged so a query-side cache can warm
+            # them under their final identity
+            self._delta_sealed.append(meta.shard_id)
+            self._delta_touched_sealed.extend(
+                (meta.shard_id, c) for c in sorted(self._delta_touched))
+            self._delta_touched = set()
+            self._delta_new = []
+            self._state = None
+            self._slot_cid = np.full(self.cfg.max_clusters, -1, np.int64)
+            self._next_cid = 0
+            self._tracker = _PixelTracker(self.cfg.pixel_diff_threshold)
+            self._gate = (_RedundancyGate(self.cfg.gate_threshold,
+                                          self.cfg.gate_capacity)
+                          if self.cfg.gate else None)
+            self._root_cid = {}
+            self._index = (self._empty_index()
+                           if self.n_local_classes is not None
+                           or self.class_map is not None else None)
+            self._shard_obj_base += self._shard_n_fed
+            self._shard_n_fed = 0
+            self._obj_next = 0
+            self._shard_frame_lo = None
+            self._shard_frame_hi = None
+            self._shard_window_end = None
+            if self.pipeline is not None:
+                self.pipeline.reset()
         return meta
 
     def _fold_tail(self, crops, objs, frames):
@@ -773,10 +780,7 @@ class StreamingIngestor:
         if self.pipeline is not None:
             self.pipeline.submit(crops, objs, frames)
             return
-        t0 = time.perf_counter()
-        probs, feats = self.cheap_apply(crops)
-        self.stats.wall_s += time.perf_counter() - t0
-        self.fold_batch(crops, objs, frames, probs, feats)
+        self._staged_step(crops, objs, frames)
 
     # -- publication -----------------------------------------------------------
 
@@ -829,25 +833,24 @@ class StreamingIngestor:
         makes chunked and one-shot ingests identical)."""
         if self.pipeline is not None:
             self.pipeline.flush_pending()     # publication barrier
-        t0 = time.perf_counter()
-        self._attach_eligible()
-        self._prune_root_cids()
-        delta = IngestDelta(
-            n_objects_published=self._delta_published,
-            new_cids=list(self._delta_new),
-            touched_cids=sorted(self._delta_touched),
-            n_evictions=self._delta_evictions,
-            n_pending_unique=self.n_pending_unique,
-            n_pending_dups=self.n_pending_dups,
-            sealed_shards=list(self._delta_sealed),
-            touched_sealed=list(self._delta_touched_sealed))
-        self._delta_new = []
-        self._delta_touched = set()
-        self._delta_evictions = 0
-        self._delta_published = 0
-        self._delta_sealed = []
-        self._delta_touched_sealed = []
-        self.stats.wall_s += time.perf_counter() - t0
+        with spans.span("ingest.publish", self.stats):
+            self._attach_eligible()
+            self._prune_root_cids()
+            delta = IngestDelta(
+                n_objects_published=self._delta_published,
+                new_cids=list(self._delta_new),
+                touched_cids=sorted(self._delta_touched),
+                n_evictions=self._delta_evictions,
+                n_pending_unique=self.n_pending_unique,
+                n_pending_dups=self.n_pending_dups,
+                sealed_shards=list(self._delta_sealed),
+                touched_sealed=list(self._delta_touched_sealed))
+            self._delta_new = []
+            self._delta_touched = set()
+            self._delta_evictions = 0
+            self._delta_published = 0
+            self._delta_sealed = []
+            self._delta_touched_sealed = []
         return delta
 
     def finish(self) -> Tuple[TopKIndex, IngestStats]:
@@ -1041,21 +1044,21 @@ class MultiStreamRunner:
 
     def _fold_stacked(self, parts):
         from repro.core.query import pad_to_bucket
-        t0 = time.perf_counter()
-        stacked = np.concatenate([p[1] for p in parts])
-        n = len(stacked)
-        padded = pad_to_bucket(stacked, self.batch_pad)
-        if self._stack_sharding is not None:
-            import jax
-            padded = jax.device_put(padded, self._stack_sharding)
-        probs, feats = self.cheap_apply(padded)
-        probs = np.asarray(probs)[:n]
-        feats = np.asarray(feats)[:n]
-        cnn_s = time.perf_counter() - t0     # shared pass, attributed below
+        cnn = spans.Wall()                   # shared pass, attributed below
+        with spans.span("ingest.megastep", cnn):
+            stacked = np.concatenate([p[1] for p in parts])
+            n = len(stacked)
+            padded = pad_to_bucket(stacked, self.batch_pad)
+            if self._stack_sharding is not None:
+                import jax
+                padded = jax.device_put(padded, self._stack_sharding)
+            probs, feats = self.cheap_apply(padded)
+            probs = np.asarray(probs)[:n]
+            feats = np.asarray(feats)[:n]
         off = 0
         for ing, crops, objs, frames in parts:
             k = len(objs)
-            ing.stats.wall_s += cnn_s * (k / n)
+            ing.stats.wall_s += cnn.wall_s * (k / n)
             ing.fold_batch(crops, objs, frames, probs[off:off + k],
                            feats[off:off + k])
             off += k

@@ -20,6 +20,8 @@ from typing import List, NamedTuple
 
 import numpy as np
 
+from repro.common import spans
+
 # pair-elements cap for one numpy diff block: block_rows * Nb * D floats.
 # 2**24 floats = 64 MiB fp32 scratch, far below the old (Na, Nb, D) blow-up
 # (500 crops x 500 crops x 3072 = 3 GiB).
@@ -58,17 +60,20 @@ def match_flat(a: np.ndarray, b: np.ndarray, threshold: float,
     Na, Nb = len(a), len(b)
     if Na == 0 or Nb == 0:
         return np.full((Na,), -1, np.int64)
+    # calls come per frame (a few crops against a ring that grows to its
+    # capacity): the kernel path pads rows to power-of-two buckets, so it
+    # compiles O(log) shapes instead of one per (Na, Nb) pair. The
+    # counters read the kernel path's float32 bytes on every backend.
+    from repro.core.clustering import _pad_bucket
+    na, nb = _pad_bucket(Na), _pad_bucket(Nb)
+    spans.add("match.calls", 1)
+    spans.add("match.bytes", 4 * a.shape[1] * (na + nb))
     if _resolve_backend(backend) == "kernel":
-        from repro.core.clustering import _pad_bucket
         from repro.kernels import ops
         from repro.kernels.pixel_diff import PAD
-        # calls come per frame (a few crops against a ring that grows to
-        # its capacity): rows are padded to power-of-two buckets, crops
-        # with zeros trimmed below and references with the kernel's own
-        # never-matching sentinel, so the kernel compiles O(log) shapes
-        # instead of one per (Na, Nb) pair
-        m, _ = ops.pixel_match(_pad_rows(a, _pad_bucket(Na), 0.0),
-                               _pad_rows(b, _pad_bucket(Nb), PAD),
+        # crops padded with zeros trimmed below, references with the
+        # kernel's own never-matching sentinel
+        m, _ = ops.pixel_match(_pad_rows(a, na, 0.0), _pad_rows(b, nb, PAD),
                                threshold)
         # focuslint: disable=host-sync -- gate decision is consumed by
         # host control flow; match_flat returns numpy by contract

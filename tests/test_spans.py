@@ -1,0 +1,266 @@
+"""The span helper (``repro.common.spans``) and the ingest path's spans and
+counters: off by default and free of records, nested self time, declared
+names, the matcher counters against a hand count, and no output byte
+changed by recording."""
+import ast
+import glob
+import os
+import tempfile
+
+import jax
+import numpy as np
+import pytest
+
+from repro.common import spans
+from repro.core.archive import ShardCatalog
+from repro.core.index import saved_file_bytes
+from repro.core.ingest import IngestConfig
+from repro.core.pipeline import ShardedIngestPipeline, staged_cheap_apply
+from repro.core.streaming import StreamingIngestor, StreamPlacement
+from repro.data.video import get_stream
+from repro.launch.mesh import make_ingest_mesh
+
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "src", "repro")
+FEAT_DIM, N_CLASSES = 12, 5
+
+
+@pytest.fixture(autouse=True)
+def _recorder_off():
+    spans.disable()
+    spans.reset()
+    yield
+    spans.disable()
+    spans.reset()
+
+
+class _Clock:
+    """perf_counter stand-in: each read returns the next tick."""
+
+    def __init__(self, ticks):
+        self.ticks = list(ticks)
+
+    def perf_counter(self):
+        return self.ticks.pop(0)
+
+
+def test_recorder_off_records_nothing():
+    wall = spans.Wall()
+    assert spans.span("ingest.gate") is spans.span("ingest.track")
+    with spans.span("ingest.frames"):
+        with spans.span("ingest.gate"):
+            spans.add("match.calls", 1)
+    with spans.span("ingest.fold", wall):
+        pass
+    assert spans.snapshot() == {"spans": {}, "counters": {}}
+    assert wall.wall_s > 0.0          # the caller's clock runs off as well
+
+
+def test_nested_spans_self_and_total(monkeypatch):
+    # reads: outer in 0, inner in 1, inner out 4, inner in 5, inner out 6,
+    # outer out 10
+    monkeypatch.setattr(spans, "time", _Clock([0.0, 1.0, 4.0, 5.0, 6.0,
+                                               10.0]))
+    wall = spans.Wall()
+    spans.enable()
+    with spans.span("ingest.seal", wall):
+        with spans.span("ingest.fold"):
+            pass
+        with spans.span("ingest.fold"):
+            pass
+    got = spans.snapshot()["spans"]
+    assert got["ingest.seal"] == {"count": 1, "total_s": 10.0,
+                                  "self_s": 6.0}
+    assert got["ingest.fold"] == {"count": 2, "total_s": 4.0,
+                                  "self_s": 4.0}
+    assert wall.wall_s == 10.0        # the same two reads as the span's
+
+
+def test_nested_spans_with_real_clock():
+    import time
+    spans.enable()
+    with spans.span("ingest.frames"):
+        time.sleep(0.02)
+        with spans.span("ingest.gate"):
+            time.sleep(0.05)
+    got = spans.snapshot()["spans"]
+    outer, inner = got["ingest.frames"], got["ingest.gate"]
+    assert inner["total_s"] == inner["self_s"] >= 0.05
+    assert outer["total_s"] >= 0.07
+    assert outer["self_s"] == pytest.approx(outer["total_s"]
+                                            - inner["total_s"])
+    assert outer["self_s"] >= 0.02
+
+
+def test_add_accumulates_and_reset_clears():
+    spans.enable()
+    spans.add("match.calls", 1)
+    spans.add("match.calls", 2)
+    spans.add("match.bytes", 4096)
+    assert spans.snapshot()["counters"] == {"match.calls": 3,
+                                            "match.bytes": 4096}
+    spans.reset()
+    assert spans.snapshot() == {"spans": {}, "counters": {}}
+
+
+def _literal_names():
+    """(span names, counter names) of every ``spans.span(...)`` /
+    ``spans.add(...)`` call under src/, and calls whose name is not a
+    string literal."""
+    found = {"span": set(), "add": set()}
+    dynamic = []
+    for path in glob.glob(os.path.join(SRC, "**", "*.py"), recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read(), path)
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call) \
+                    and isinstance(node.func, ast.Attribute) \
+                    and isinstance(node.func.value, ast.Name) \
+                    and node.func.value.id == "spans" \
+                    and node.func.attr in found:
+                arg = node.args[0] if node.args else None
+                if isinstance(arg, ast.Constant) and isinstance(arg.value,
+                                                                str):
+                    found[node.func.attr].add(arg.value)
+                else:
+                    dynamic.append(f"{path}:{node.lineno}")
+    return found["span"], found["add"], dynamic
+
+
+def test_every_name_in_src_is_declared():
+    span_names, counter_names, dynamic = _literal_names()
+    assert not dynamic
+    assert span_names == set(spans.SPAN_NAMES)
+    assert counter_names == set(spans.COUNTER_NAMES)
+
+
+def _zoo(n_frames=150, res=8):
+    crops, frames, _, _ = get_stream("jacksonh", obj_res=res,
+                                     duration_s=10).objects_array(n_frames)
+    return crops, frames
+
+
+def _cheap_fn(crops):
+    flat = crops.reshape(crops.shape[0], -1)
+    return (jax.nn.softmax(flat[:, FEAT_DIM:FEAT_DIM + N_CLASSES] * 5.0,
+                           axis=-1), flat[:, :FEAT_DIM] * 10.0)
+
+
+_CFG = dict(K=2, threshold=1.5, max_clusters=64, batch_size=32,
+            high_water=0.8, evict_frac=0.5, gate=True, gate_threshold=0.05,
+            gate_capacity=64)
+
+
+def _bucket(n):
+    return max(8, 1 << (n - 1).bit_length())
+
+
+def test_match_counters_equal_hand_count(monkeypatch):
+    """A gated zoo stream through the staged ingestor: ``match.calls``
+    and ``match.bytes`` equal a count of the matcher's own calls under
+    the power-of-two bucket rule."""
+    import repro.core.streaming as S
+    import repro.data.bgsub as B
+    calls = []
+    real = B.match_flat
+
+    def spy(a, b, threshold, backend="auto"):
+        calls.append((len(a), len(b), a.shape[1]))
+        return real(a, b, threshold, backend)
+
+    monkeypatch.setattr(B, "match_flat", spy)     # the tracker's path
+    monkeypatch.setattr(S, "match_flat", spy)     # the gate's
+    crops, frames = _zoo()
+    cfg = IngestConfig(**_CFG)
+    spans.enable()
+    ing = StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9, cfg)
+    for s in range(0, len(crops), 97):
+        ing.feed(crops[s:s + 97], frames[s:s + 97])
+        ing.flush()
+    ing.finish()
+    real_calls = [(na, nb, d) for na, nb, d in calls if na and nb]
+    assert len(real_calls) > 20
+    assert ing.stats.n_gate_skipped + ing.stats.n_pixel_dedup > 0
+    got = spans.snapshot()
+    assert got["counters"] == {
+        "match.calls": len(real_calls),
+        "match.bytes": sum(4 * d * (_bucket(na) + _bucket(nb))
+                           for na, nb, d in real_calls)}
+    assert {"ingest.frames", "ingest.track", "ingest.gate",
+            "ingest.megastep", "ingest.fold",
+            "ingest.publish"} <= set(got["spans"])
+
+
+def _sharded_ingest(crops, frames, root):
+    """The benchmark's path: one stream on a one-device sharded pipeline,
+    rolling over into a catalog."""
+    cfg = IngestConfig(**_CFG)
+    placement = StreamPlacement(["cam"], 1)
+    shared = ShardedIngestPipeline(_cheap_fn, make_ingest_mesh(1),
+                                   placement.slots, cfg=cfg)
+    catalog = ShardCatalog.open(root)
+    ing = StreamingIngestor(None, 1e9, cfg, pipeline=shared.handle("cam"),
+                            catalog=catalog, shard_objects=120)
+    for s in range(0, len(crops), 61):
+        ing.feed(crops[s:s + 61], frames[s:s + 61])
+        ing.flush()
+    ing.finish()
+    return catalog, ing.stats, shared.stats
+
+
+def test_recorder_changes_no_output_byte():
+    crops, frames = _zoo()
+    with tempfile.TemporaryDirectory() as d:
+        cat_off, st_off, ps_off = _sharded_ingest(
+            crops, frames, os.path.join(d, "off"))
+        spans.enable()
+        cat_on, st_on, ps_on = _sharded_ingest(
+            crops, frames, os.path.join(d, "on"))
+        spans.disable()
+        assert len(cat_off.shards) == len(cat_on.shards) > 1
+        for a, b in zip(cat_off.shards, cat_on.shards):
+            assert saved_file_bytes(os.path.join(cat_off.root, a.path)) \
+                == saved_file_bytes(os.path.join(cat_on.root, b.path))
+    for f in ("n_objects", "n_cnn_invocations", "n_pixel_dedup",
+              "n_gate_skipped", "n_evictions"):
+        assert getattr(st_off, f) == getattr(st_on, f), f
+    assert ps_off == ps_on
+    assert st_off.wall_s > 0.0
+
+
+def test_wall_s_is_the_sum_of_its_spans():
+    """On one stream, ``IngestStats.wall_s`` is fed by the spans' own
+    clock reads: the frame loop, the megastep, the folds and publication
+    (the seal's own time is not ingest wall time; its children are)."""
+    crops, frames = _zoo()
+    spans.enable()
+    with tempfile.TemporaryDirectory() as d:
+        _, stats, _ = _sharded_ingest(crops, frames, d)
+    got = spans.snapshot()["spans"]
+    assert got["ingest.seal"]["count"] >= 2
+    assert stats.wall_s == pytest.approx(sum(
+        got[k]["total_s"] for k in ("ingest.frames", "ingest.megastep",
+                                    "ingest.fold", "ingest.publish")),
+        rel=1e-9)
+
+
+def test_spans_reach_the_profiler_host_plane(tmp_path):
+    """With the recorder on, every span is a ``TraceAnnotation``: a
+    profiler trace holds the ingest spans on its host plane."""
+    from jax.profiler import ProfileData
+    crops, frames = _zoo(n_frames=60)
+    spans.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tempfile.TemporaryDirectory() as d:
+            _sharded_ingest(crops, frames, d)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for p in ProfileData.from_file(path).planes
+             if p.name.startswith("/host:") for ln in p.lines
+             for e in ln.events}
+    recorded = set(spans.snapshot()["spans"])
+    assert {"ingest.frames", "ingest.megastep", "ingest.fold",
+            "ingest.seal"} <= recorded
+    assert recorded <= names
