@@ -23,8 +23,8 @@ from __future__ import annotations
 import contextlib
 import time
 
-SPAN_NAMES = ("ingest.frames", "ingest.track", "ingest.gate",
-              "ingest.megastep", "ingest.fold", "ingest.seal",
+SPAN_NAMES = ("ingest.frames", "ingest.match", "ingest.track",
+              "ingest.gate", "ingest.megastep", "ingest.fold", "ingest.seal",
               "ingest.publish")
 COUNTER_NAMES = ("match.calls", "match.bytes")
 

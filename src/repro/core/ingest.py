@@ -64,25 +64,19 @@ def pixel_tracks(crops: np.ndarray, frames: np.ndarray,
 
     Objects in frame t whose pixels nearly match an object in frame t-1
     join that object's track (and will share its cluster) without a CNN
-    pass. Thin one-shot view over the streaming ``_PixelTracker`` — the
-    same code path ingest uses — so its tests pin the live tracker.
+    pass. Thin one-shot view over the streaming ``_FrameMatcher`` (tracker
+    only) — the same code path ingest uses — so its tests pin the live
+    tracker.
     """
-    from repro.core.streaming import _PixelTracker
+    from repro.core.streaming import _FrameMatcher
     n = len(crops)
     roots = np.arange(n)
     if n == 0:
         return roots
     order = np.argsort(frames, kind="stable")
-    tracker = _PixelTracker(threshold)
-    i = 0
-    while i < n:
-        f = int(frames[order[i]])
-        j = i
-        while j < n and frames[order[j]] == f:
-            j += 1
-        ids = order[i:j]
-        roots[ids] = tracker.resolve(f, crops[ids], ids.astype(np.int64))
-        i = j
+    roots[order] = _FrameMatcher(threshold).resolve(
+        crops[order], np.asarray(frames)[order], order.astype(np.int64),
+        IngestStats())
     return roots
 
 

@@ -28,6 +28,7 @@ still waiting for a full batch and the duplicates chained to them.
 """
 from __future__ import annotations
 
+import collections
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
@@ -37,7 +38,7 @@ from repro.common import spans
 from repro.core import clustering as C
 from repro.core.index import ClassMap, TopKIndex
 from repro.core.ingest import IngestConfig, IngestStats
-from repro.data.bgsub import match_flat, pixel_difference
+from repro.data.bgsub import _pad_rows, _resolve_backend, decide, numpy_block
 
 
 @dataclass
@@ -56,60 +57,248 @@ class IngestDelta:
     # shard has since been sealed — what an ArchiveQueryEngine prefetches
 
 
+# crops per distance block: a longer segment is cut into blocks of at
+# most this many rows (a power of two, the largest crop bucket)
+_MAX_ROWS = 256
+# store rows beyond the gate's capacity, for the ring's overshoot (up to
+# one frame group) and the tracker's and the gate's carried frame groups
+_SPARE_ROWS = 64
+
+
+def _store_slots(gate_capacity: int) -> int:
+    """Row-store size for a gate capacity (0: no gate): the reference
+    width ``slots + _MAX_ROWS`` is a power of two, so one width serves
+    every crop bucket."""
+    return C._pad_bucket(gate_capacity + _SPARE_ROWS + _MAX_ROWS) - _MAX_ROWS
+
+
+class _RowStore:
+    """The reference rows the tracker and the gate may still read, by
+    slot: the gate's ring, its frame group awaiting admission, and the
+    tracker's previous and open frame groups.
+
+    On the kernel backend the rows live on the device in one fixed-size
+    buffer: a block's new crops cross to the chip once, and the rows the
+    host replay keeps are copied into free slots there, from the crops
+    already on the device (``ops.store_put``, enqueued without a fetch).
+    On the numpy backend the buffer is a host array. The host keeps only
+    ids and slots; a slot no live group names is free.
+
+    ``block(crops)`` is one matcher call per block of at most
+    ``_MAX_ROWS`` crops: the kernel backend computes and fetches the
+    whole (crops x [store; crops]) float32 distance block in one
+    dispatch (``ops.pixel_match_resident``), the numpy backend the
+    entries the replay reads, with ``bgsub.numpy_block``.
+    """
+
+    def __init__(self, slots: int):
+        self.slots = slots
+        self.kernel = _resolve_backend("auto") == "kernel"
+        self._rows = None          # (slots, D): device or host buffer
+
+    def _width(self, n: int) -> int:
+        return C._pad_bucket(self.slots + max(n, _MAX_ROWS))
+
+    def _alloc(self, d: int):
+        if not self.kernel:
+            self._rows = np.zeros((self.slots, d), np.float32)
+            return
+        import jax
+        from repro.kernels import ops
+        self._rows = jax.device_put(np.zeros((self.slots, d), np.float32))
+        # run every crop bucket against this width once, so no block
+        # compiles inside a measured window (the first store of a
+        # configuration compiles them, later ones hit the jit cache);
+        # every index is padding, so the puts write nothing
+        nn, outs = 8, []
+        while nn <= _MAX_ROWS:
+            rows = jax.device_put(np.zeros((nn, d), np.float32))
+            outs.append(ops.pixel_match_resident(self._rows, rows,
+                                                 self._width(nn)))
+            self._rows = ops.store_put(
+                self._rows, rows, np.zeros(nn, np.int32),
+                np.full(nn, self.slots, np.int32))
+            nn *= 2
+        jax.block_until_ready((outs, self._rows))
+
+    def block(self, crops: np.ndarray) -> "_Block":
+        n, d = crops.shape
+        nn = C._pad_bucket(n)
+        spans.add("match.calls", 1)
+        spans.add("match.bytes", 4 * d * nn)
+        if self._rows is None:
+            self._alloc(d)
+        if not self.kernel:
+            return _Block(self, np.asarray(crops, np.float32))
+        import jax
+        from repro.kernels import ops
+        dev = jax.device_put(_pad_rows(crops, nn, 0.0))
+        # focuslint: disable=host-sync -- the one fetch of the block:
+        # the tracker's and the gate's decisions are host control flow
+        dist = np.asarray(ops.pixel_match_resident(self._rows, dev,
+                                                   self._width(nn)))
+        return _Block(self, crops, dist, dev)
+
+    def commit(self, blk: "_Block", groups: List[np.ndarray]):
+        """Give every block column the live ``groups`` name a free slot,
+        copy those rows there, and return ``groups`` by slot."""
+        base = self.slots
+        live = np.concatenate(groups) if groups else np.zeros(0, np.int64)
+        new = np.unique(live[live >= base]) - base
+        if not len(new):
+            return groups
+        used = np.zeros(base, bool)
+        used[live[live < base]] = True
+        free = np.flatnonzero(~used)
+        if len(free) < len(new):
+            self._grow(base - len(free) + len(new))
+            free = np.concatenate([free, np.arange(base, self.slots)])
+        dst = free[:len(new)]
+        remap = np.arange(base + len(blk.crops))
+        remap[base + new] = dst
+        if self.kernel:
+            from repro.kernels import ops
+            nn = len(blk.dev)
+            src = np.zeros(nn, np.int32)
+            src[:len(new)] = new
+            to = np.full(nn, self.slots, np.int32)
+            to[:len(new)] = dst
+            self._rows = ops.store_put(self._rows, blk.dev, src, to)
+        else:
+            self._rows[dst] = blk.crops[new]
+        return [remap[g] for g in groups]
+
+    def _grow(self, need: int):
+        """More slots than the configuration's (a frame group larger than
+        ``_SPARE_ROWS`` allows for): widen the buffer to the next
+        power-of-two reference width. The wider blocks compile on their
+        first use; no shape of the configuration's own changes."""
+        old = self.slots
+        self.slots = C._pad_bucket(need + _MAX_ROWS) - _MAX_ROWS
+        pad = ((0, self.slots - old), (0, 0))
+        if self.kernel:
+            import jax.numpy as jnp
+            self._rows = jnp.pad(self._rows, pad)
+        else:
+            self._rows = np.pad(self._rows, pad)
+
+
+class _Block:
+    """One block's distances. Column ``s < base`` is store slot ``s``;
+    column ``base + k`` is the block's crop ``k``."""
+
+    def __init__(self, store: _RowStore, crops: np.ndarray,
+                 dist: Optional[np.ndarray] = None, dev=None):
+        self.store, self.crops = store, crops
+        self.base = store.slots
+        self.dist, self.dev = dist, dev
+
+    def cols(self, rows: np.ndarray) -> np.ndarray:
+        return self.base + rows
+
+    def _refs(self, cols: np.ndarray) -> np.ndarray:
+        old = cols < self.base
+        refs = np.empty((len(cols), self.crops.shape[1]), np.float32)
+        refs[old] = self.store._rows[cols[old]]
+        refs[~old] = self.crops[cols[~old] - self.base]
+        return refs
+
+    def match(self, rows: np.ndarray, cols: np.ndarray,
+              threshold: float) -> np.ndarray:
+        """``decide`` for crops ``rows`` against columns ``cols``: the
+        position in ``cols`` of each crop's match, or -1."""
+        if self.dist is not None:
+            return decide(self.dist[rows[:, None], cols], threshold)
+        return decide(numpy_block(self.crops[rows], self._refs(cols)),
+                      threshold)
+
+    def match_groups(self, cols: np.ndarray, labels: np.ndarray,
+                     want: np.ndarray, threshold: float) -> np.ndarray:
+        """For every crop k, ``decide`` against the columns ``cols`` whose
+        label is ``want[k]``: the position in ``cols`` of its match, or
+        -1."""
+        if self.dist is not None:
+            sub = np.where(labels[None, :] == want[:, None],
+                           self.dist[:len(want), cols], np.inf)
+            return decide(sub, threshold)
+        out = np.full(len(want), -1, np.int64)
+        for w in np.unique(want):
+            sel = np.flatnonzero(labels == w)
+            if len(sel):
+                rows = np.flatnonzero(want == w)
+                m = self.match(rows, cols[sel], threshold)
+                out[rows] = np.where(m >= 0, sel[m], -1)
+        return out
+
+
 class _PixelTracker:
     """Streaming §4.2 pixel differencing.
 
     Mirrors ``ingest.pixel_tracks`` exactly, but over an unbounded stream:
     a frame group may arrive split across chunks (the *open* frame keeps
     accepting members until a later frame appears), while the previous
-    frame's completed group — crops and resolved root ids — is retained
-    for matching. Requires frames to arrive in non-decreasing order.
+    frame's completed group is retained for matching. Requires frames to
+    arrive in non-decreasing order. The tracker keeps no pixels: its
+    state is the last two frame groups as row-store columns, their
+    frames and their resolved root ids, in stream order.
+
+    A crop of frame ``f`` matches within frame ``f - 1``'s group, so a
+    whole block is decided at once (``begin``): its crops against the
+    carried groups and the block's own earlier crops, each row masked to
+    its frame's predecessor. Roots then follow frame by frame (``roots``,
+    ``record``), since a match takes its reference's final root — after
+    the gate may have rewritten it.
     """
 
     def __init__(self, threshold: float):
         self.threshold = threshold
-        self._open_frame: Optional[int] = None
-        self._open_crops: List[np.ndarray] = []
-        self._open_roots: List[np.ndarray] = []
-        self._prev_frame: Optional[int] = None
-        self._prev_crops: Optional[np.ndarray] = None
-        self._prev_roots: Optional[np.ndarray] = None
+        self._cols = np.zeros(0, np.int64)
+        self._frames = np.zeros(0, np.int64)
+        self._roots = np.zeros(0, np.int64)
 
-    def resolve(self, f: int, crops: np.ndarray,
-                obj_ids: np.ndarray) -> np.ndarray:
-        """Root object ids for one (possibly partial) frame-``f`` group."""
-        if self._open_frame is not None and f < self._open_frame:
+    def begin(self, blk: _Block, frames: np.ndarray):
+        """Decide every match of the block's crops (frames ``frames``)."""
+        if len(self._frames) and frames[0] < self._frames[-1]:
             raise ValueError(
-                f"frames must be non-decreasing across feeds: got frame {f} "
-                f"after frame {self._open_frame}")
-        with spans.span("ingest.track"):
-            if self._open_frame is None or f > self._open_frame:
-                if self._open_crops:
-                    self._prev_frame = self._open_frame
-                    self._prev_crops = np.concatenate(self._open_crops)
-                    self._prev_roots = np.concatenate(self._open_roots)
-                self._open_frame = f
-                self._open_crops, self._open_roots = [], []
-            roots = obj_ids.copy()
-            if self._prev_frame == f - 1 and self._prev_crops is not None \
-                    and len(self._prev_crops):
-                match = pixel_difference(crops, self._prev_crops,
-                                         self.threshold)
-                m = match >= 0
-                roots[m] = self._prev_roots[match[m]]
-            self._open_crops.append(crops)
-            self._open_roots.append(roots)
+                f"frames must be non-decreasing across feeds: got frame "
+                f"{int(frames[0])} after frame {int(self._frames[-1])}")
+        n, self._off = len(frames), len(self._cols)
+        self._cols = np.concatenate([self._cols, blk.cols(np.arange(n))])
+        self._frames = np.concatenate([self._frames, frames])
+        self._roots = np.concatenate([self._roots,
+                                      np.zeros(n, np.int64)])
+        self._match = blk.match_groups(self._cols, self._frames,
+                                       frames - 1, self.threshold)
+
+    def roots(self, i: int, j: int, ids: np.ndarray) -> np.ndarray:
+        """Roots of the block's crops ``i:j`` (one frame group)."""
+        m = self._match[i:j]
+        roots = ids.copy()
+        hit = m >= 0
+        roots[hit] = self._roots[m[hit]]
         return roots
 
-    def amend_last(self, roots: np.ndarray):
-        """Replace the roots of the most recent ``resolve`` segment.
+    def record(self, i: int, j: int, roots: np.ndarray):
+        """The final roots of crops ``i:j``, gate rewrites included: a
+        next-frame match chains to them, never to a never-folded id."""
+        self._roots[self._off + i:self._off + j] = roots
 
-        The redundancy gate rewrites roots *after* the tracker resolved a
-        group; the tracker must see the rewrite, or a next-frame tracker
-        match would chain to the crop's own (never-CNN'd, never-folded)
-        id and its duplicate record could never attach.
-        """
-        self._open_roots[-1] = np.asarray(roots, np.int64)
+    def end(self):
+        """Keep the last two frame groups (the open and the previous)."""
+        fr = self._frames
+        prev = fr[fr < fr[-1]]
+        keep = fr >= (prev[-1] if len(prev) else fr[-1])
+        self._cols, self._frames = self._cols[keep], fr[keep]
+        self._roots = self._roots[keep]
+
+    def live_roots(self) -> set:
+        return set(self._roots.tolist())
+
+    def columns(self) -> List[np.ndarray]:
+        return [self._cols]
+
+    def set_columns(self, cols: List[np.ndarray]):
+        self._cols = cols[0]
 
 
 class _RedundancyGate:
@@ -117,11 +306,12 @@ class _RedundancyGate:
 
     The §4.2 tracker only matches consecutive frames; on a static camera
     the same object re-surfaces for minutes. This gate keeps a bounded
-    FIFO ring of the most recent *CNN-bound* unique crops (flattened)
-    with their root ids; a new crop matching a ring entry (mean abs diff
-    STRICTLY below ``threshold``, via ``bgsub.match_flat`` — the Pallas
-    ``pixel_diff`` kernel on accelerators) skips the CNN and attaches to
-    the ring root's cluster through the duplicate/attach log.
+    FIFO ring of the most recent *CNN-bound* unique crops, as row-store
+    slots (on the device, on the kernel backend) with their root ids; a
+    new crop matching a ring entry (mean abs diff STRICTLY below
+    ``threshold``, ``bgsub.decide`` over the segment's block; ties to the
+    oldest entry) skips the CNN and attaches to the ring root's cluster
+    through the duplicate/attach log.
 
     Chunk invariance: matching only sees entries from strictly earlier
     frames — a frame's own uniques are queued and admitted to the ring
@@ -131,70 +321,166 @@ class _RedundancyGate:
     happen per closed frame group, a function of the stream alone.
     """
 
-    def __init__(self, threshold: float, capacity: int,
-                 backend: str = "auto"):
+    def __init__(self, threshold: float, capacity: int):
         if capacity < 1:
             raise ValueError(f"gate_capacity must be >= 1, got {capacity}")
         self.threshold = threshold
         self.capacity = capacity
-        self.backend = backend
-        self._ring_crops: List[np.ndarray] = []    # per-frame (k, D) groups
-        self._ring_roots: List[np.ndarray] = []
-        self._n = 0
+        # the ring, oldest first: store columns, root ids, and the size of
+        # each admitted frame group (trims drop whole groups)
+        self._ring_cols = np.zeros(0, np.int64)
+        self._ring_roots = np.zeros(0, np.int64)
+        self._sizes: collections.deque = collections.deque()
         self._open_frame: Optional[int] = None
-        self._open_crops: List[np.ndarray] = []
+        self._open_cols: List[np.ndarray] = []
         self._open_roots: List[np.ndarray] = []
 
-    def match(self, f: int, crops2d: np.ndarray) -> np.ndarray:
-        """Ring root id per crop (or -1) for one frame-``f`` segment.
-        Also advances the open-frame bookkeeping, so call it once per
-        resolved segment even when ``crops2d`` is empty."""
-        with spans.span("ingest.gate"):
-            if self._open_frame is None or f > self._open_frame:
-                if self._open_crops:
-                    self._push(np.concatenate(self._open_crops),
-                               np.concatenate(self._open_roots))
-                    self._open_crops, self._open_roots = [], []
-                self._open_frame = f
-            out = np.full((len(crops2d),), -1, np.int64)
-            if self._n == 0 or len(crops2d) == 0:
-                return out
-            m = match_flat(crops2d, np.concatenate(self._ring_crops),
-                           self.threshold, backend=self.backend)
-            hit = m >= 0
-            if hit.any():
-                roots = np.concatenate(self._ring_roots)
-                out[hit] = roots[m[hit]]
-        return out
+    def match(self, f: int, rows: np.ndarray, blk: _Block) -> np.ndarray:
+        """Ring root id per crop (or -1) for the block's frame-``f`` crops
+        ``rows``. Also advances the open-frame bookkeeping, so call it
+        once per resolved group even when ``rows`` is empty."""
+        if self._open_frame is None or f > self._open_frame:
+            if self._open_cols:
+                self._push(np.concatenate(self._open_cols),
+                           np.concatenate(self._open_roots))
+                self._open_cols, self._open_roots = [], []
+            self._open_frame = f
+        if not len(self._ring_cols) or not len(rows):
+            return np.full(len(rows), -1, np.int64)
+        m = blk.match(rows, self._ring_cols, self.threshold)
+        return np.where(m >= 0, self._ring_roots[m], -1)
 
-    def admit(self, crops2d: np.ndarray, roots: np.ndarray):
+    def admit(self, cols: np.ndarray, roots: np.ndarray):
         """Queue frame-``f`` CNN-bound uniques (f = the frame of the last
         ``match`` call); they join the ring when the frame closes."""
-        if len(crops2d):
-            self._open_crops.append(crops2d)
-            self._open_roots.append(np.asarray(roots, np.int64))
+        if len(cols):
+            self._open_cols.append(cols)
+            self._open_roots.append(roots)
 
-    def _push(self, crops: np.ndarray, roots: np.ndarray):
-        self._ring_crops.append(crops)
-        self._ring_roots.append(roots)
-        self._n += len(roots)
+    def _push(self, cols: np.ndarray, roots: np.ndarray):
+        self._sizes.append(len(roots))
+        n = len(self._ring_roots) + len(roots)
         # trim whole frame groups while the remainder still covers the
         # capacity: ring size stays in [capacity, capacity + group)
-        while len(self._ring_roots) > 1 \
-                and self._n - len(self._ring_roots[0]) >= self.capacity:
-            self._n -= len(self._ring_roots[0])
-            self._ring_crops.pop(0)
-            self._ring_roots.pop(0)
+        cut = 0
+        while len(self._sizes) > 1 and n - self._sizes[0] >= self.capacity:
+            k = self._sizes.popleft()
+            n -= k
+            cut += k
+        self._ring_cols = np.concatenate([self._ring_cols[cut:], cols])
+        self._ring_roots = np.concatenate([self._ring_roots[cut:], roots])
 
     def live_roots(self) -> set:
         """Root ids a future gate match may still return (ring + open) —
         their ``_root_cid`` entries must survive pruning."""
-        keep: set = set()
-        for seg in self._ring_roots:
-            keep.update(seg.tolist())
+        keep = set(self._ring_roots.tolist())
         for seg in self._open_roots:
             keep.update(seg.tolist())
         return keep
+
+    def columns(self) -> List[np.ndarray]:
+        return [self._ring_cols] + self._open_cols
+
+    def set_columns(self, cols: List[np.ndarray]):
+        self._ring_cols, self._open_cols = cols[0], list(cols[1:])
+
+
+class _FrameMatcher:
+    """The pixel tracker and the redundancy gate of one stream, decided
+    from one distance block per segment (DESIGN.md §10).
+
+    Every distance either layer reads while a segment is ingested is
+    between a crop of the segment and a row known when the segment
+    begins (a store row: the ring, a carried frame group) or an earlier
+    crop of the same segment. So ``resolve`` makes one matcher call per
+    block of at most ``_MAX_ROWS`` crops, then replays the tracker's and
+    the gate's per-frame decisions from that block on the host, in
+    stream order and under ``bgsub.decide``'s rule — the decisions a
+    per-frame ``match_flat`` against the same references would make.
+    """
+
+    def __init__(self, track_threshold: Optional[float],
+                 gate_threshold: Optional[float] = None,
+                 gate_capacity: int = 0):
+        self._track_threshold = track_threshold
+        self._gate_args = (None if gate_threshold is None
+                           else (gate_threshold, gate_capacity))
+        self.store = _RowStore(
+            _store_slots(gate_capacity if self._gate_args else 0))
+        self.reset()
+
+    def reset(self):
+        """Fresh tracker and gate (a sealed shard shares no state with
+        the next); every store slot becomes free."""
+        self.tracker = (None if self._track_threshold is None
+                        else _PixelTracker(self._track_threshold))
+        self.gate = (None if self._gate_args is None
+                     else _RedundancyGate(*self._gate_args))
+
+    def resolve(self, crops: np.ndarray, frames: np.ndarray,
+                ids: np.ndarray, stats: IngestStats) -> np.ndarray:
+        """Root object id per object of one frame-sorted segment; counts
+        ``n_pixel_dedup`` and ``n_gate_skipped`` into ``stats``."""
+        n = len(crops)
+        flat = crops.reshape(n, -1)
+        roots = ids.copy()
+        tracker, gate = self.tracker, self.gate
+        for p0 in range(0, n, _MAX_ROWS):
+            p1 = min(n, p0 + _MAX_ROWS)
+            with spans.span("ingest.match"):
+                blk = self.store.block(flat[p0:p1])
+            fr, bids = frames[p0:p1], ids[p0:p1]
+            if tracker is not None:
+                with spans.span("ingest.track"):
+                    tracker.begin(blk, fr)
+            cuts = np.flatnonzero(fr[1:] != fr[:-1]) + 1
+            for i, j in zip([0, *cuts.tolist()], [*cuts.tolist(), p1 - p0]):
+                fid = bids[i:j]
+                r = fid
+                if tracker is not None:
+                    r = tracker.roots(i, j, fid)
+                    stats.n_pixel_dedup += int((r != fid).sum())
+                if gate is not None:
+                    with spans.span("ingest.gate"):
+                        r = self._gate_group(int(fr[i]), i, r, fid, blk,
+                                             stats)
+                if tracker is not None:
+                    tracker.record(i, j, r)
+                roots[p0 + i:p0 + j] = r
+            if tracker is not None:
+                tracker.end()
+            with spans.span("ingest.match"):
+                self._commit(blk)
+        return roots
+
+    def _gate_group(self, f: int, i: int, roots: np.ndarray,
+                    ids: np.ndarray, blk: _Block,
+                    stats: IngestStats) -> np.ndarray:
+        """Run one frame-``f`` group's (block rows ``i:``) tracker-unique
+        crops through the redundancy gate; returns the (possibly
+        rewritten) roots. Gate hits become duplicates rooted at a ring
+        entry (a CNN-bound object), misses are admitted as future ring
+        entries."""
+        uniq = np.flatnonzero(roots == ids)
+        groots = self.gate.match(f, i + uniq, blk)
+        hit = groots >= 0
+        if hit.any():
+            roots = roots.copy()
+            roots[uniq[hit]] = groots[hit]
+            stats.n_gate_skipped += int(hit.sum())
+            uniq = uniq[~hit]
+        self.gate.admit(blk.cols(i + uniq), ids[uniq])
+        return roots
+
+    def _commit(self, blk: _Block):
+        """Move the block's crops that a live group still names into the
+        store, and rename them by slot."""
+        owners = [o for o in (self.tracker, self.gate) if o is not None]
+        parts = [o.columns() for o in owners]
+        cols = self.store.commit(blk, [c for p in parts for c in p])
+        for o, p in zip(owners, parts):
+            o.set_columns(cols[:len(p)])
+            cols = cols[len(p):]
 
 
 class _ChunkBuffer:
@@ -346,10 +632,11 @@ class StreamingIngestor:
         self._state = None                      # lazy: dims from first batch
         self._slot_cid = np.full(self.cfg.max_clusters, -1, np.int64)
         self._next_cid = 0
-        self._tracker = _PixelTracker(self.cfg.pixel_diff_threshold)
-        self._gate = (_RedundancyGate(self.cfg.gate_threshold,
-                                      self.cfg.gate_capacity)
-                      if self.cfg.gate else None)
+        c = self.cfg
+        self._matcher = (_FrameMatcher(
+            c.pixel_diff_threshold if c.pixel_diff else None,
+            c.gate_threshold if c.gate else None, c.gate_capacity)
+            if c.pixel_diff or c.gate else None)
         if self.cfg.frame_stride < 1:
             raise ValueError(
                 f"frame_stride must be >= 1: {self.cfg.frame_stride}")
@@ -544,58 +831,22 @@ class StreamingIngestor:
                       obj_ids: np.ndarray):
         """Pixel-diff + buffer one frame-sorted, single-shard segment,
         folding every completed CNN batch."""
-        n = len(crops)
         with spans.span("ingest.frames", self.stats):
-            if self.cfg.pixel_diff or self._gate is not None:
-                i = 0
-                while i < n:
-                    f = int(frames[i])
-                    j = i
-                    while j < n and frames[j] == f:
-                        j += 1
-                    ids = obj_ids[i:j]
-                    if self.cfg.pixel_diff:
-                        roots = self._tracker.resolve(f, crops[i:j], ids)
-                        self.stats.n_pixel_dedup += int((roots != ids).sum())
-                    else:
-                        roots = ids.copy()
-                    if self._gate is not None:
-                        roots = self._gate_segment(f, crops[i:j], ids, roots)
-                    uniq = roots == ids
-                    self._buffer_unique(crops[i:j][uniq], ids[uniq],
-                                        frames[i:j][uniq])
-                    if not uniq.all():
-                        dup = ~uniq
-                        self._dup_objs.append(ids[dup])
-                        self._dup_frames.append(frames[i:j][dup])
-                        self._dup_roots.append(roots[dup])
-                    i = j
+            if self._matcher is not None:
+                roots = self._matcher.resolve(crops, frames, obj_ids,
+                                              self.stats)
+                uniq = roots == obj_ids
+                self._buffer_unique(crops[uniq], obj_ids[uniq],
+                                    frames[uniq])
+                if not uniq.all():
+                    dup = ~uniq
+                    self._dup_objs.append(obj_ids[dup])
+                    self._dup_frames.append(frames[dup])
+                    self._dup_roots.append(roots[dup])
             else:
                 self._buffer_unique(crops, obj_ids, frames)
         if self.cheap_apply is not None or self.pipeline is not None:
             self._drain_ready()
-
-    def _gate_segment(self, f: int, crops: np.ndarray, ids: np.ndarray,
-                      roots: np.ndarray) -> np.ndarray:
-        """Run one frame-``f`` segment's tracker-unique crops through the
-        redundancy gate; returns the (possibly rewritten) roots. Gate
-        hits become duplicates rooted at a ring entry (a CNN-bound
-        object), misses are admitted as future ring entries."""
-        uniq = roots == ids
-        flat = crops[uniq].reshape(int(uniq.sum()),
-                                   int(np.prod(crops.shape[1:])))
-        groots = self._gate.match(f, flat)
-        hit = groots >= 0
-        if hit.any():
-            roots = roots.copy()
-            roots[np.nonzero(uniq)[0][hit]] = groots[hit]
-            self.stats.n_gate_skipped += int(hit.sum())
-            if self.cfg.pixel_diff:
-                # the tracker must see the rewritten roots, else a
-                # next-frame tracker match chains to a never-folded id
-                self._tracker.amend_last(roots)
-        self._gate.admit(flat[~hit], ids[uniq][~hit])
-        return roots
 
     def _buffer_unique(self, crops, obj_ids, frames):
         self._buf.append(crops, obj_ids, frames)
@@ -756,10 +1007,8 @@ class StreamingIngestor:
             self._state = None
             self._slot_cid = np.full(self.cfg.max_clusters, -1, np.int64)
             self._next_cid = 0
-            self._tracker = _PixelTracker(self.cfg.pixel_diff_threshold)
-            self._gate = (_RedundancyGate(self.cfg.gate_threshold,
-                                          self.cfg.gate_capacity)
-                          if self.cfg.gate else None)
+            if self._matcher is not None:
+                self._matcher.reset()
             self._root_cid = {}
             self._index = (self._empty_index()
                            if self.n_local_classes is not None
@@ -812,16 +1061,16 @@ class StreamingIngestor:
         O(active window) over a continuously ingested stream instead of
         O(total unique objects)."""
         keep = set()
-        for seg in self._tracker._open_roots:
-            keep.update(seg.tolist())
-        if self._tracker._prev_roots is not None:
-            keep.update(self._tracker._prev_roots.tolist())
+        tracker = self._matcher.tracker if self._matcher else None
+        if tracker is not None:
+            keep |= tracker.live_roots()
         for seg in self._dup_roots:
             keep.update(seg.tolist())
-        if self._gate is not None:
+        gate = self._matcher.gate if self._matcher else None
+        if gate is not None:
             # gate roots can be far older than the tracker window; any
             # ring entry may still be matched (and need its cid) later
-            keep |= self._gate.live_roots()
+            keep |= gate.live_roots()
         self._root_cid = {r: c for r, c in self._root_cid.items()
                           if r in keep}
 

@@ -48,22 +48,50 @@ def _resolve_backend(backend: str) -> str:
     return backend
 
 
+def decide(dist: np.ndarray, threshold: float) -> np.ndarray:
+    """The one matching rule, for a (Na, Nb) float32 distance block:
+    ``out[i]`` is the lowest column j among row i's minima when that
+    minimum is STRICTLY below ``threshold`` (a distance exactly at the
+    threshold does NOT match), else -1. The comparison is in float32,
+    as on the device."""
+    if dist.shape[1] == 0:
+        return np.full((dist.shape[0],), -1, np.int64)
+    return np.where(dist.min(1) < np.float32(threshold), dist.argmin(1),
+                    -1).astype(np.int64, copy=False)
+
+
+def numpy_block(a: np.ndarray, b: np.ndarray) -> np.ndarray:
+    """(Na, D), (Nb, D) -> (Na, Nb) float32 ``mean |a_i - b_j|`` on the
+    host, in row blocks: the (r, Nb, D) broadcast is scratch bounded by
+    ``_BLOCK_ELEMS`` and freed per block."""
+    a = np.ascontiguousarray(a, np.float32)
+    b = np.ascontiguousarray(b, np.float32)
+    Na, Nb, D = len(a), len(b), a.shape[1]
+    rows = max(1, _BLOCK_ELEMS // max(1, Nb * D))
+    out = np.empty((Na, Nb), np.float32)
+    for i in range(0, Na, rows):
+        blk = a[i:i + rows]                          # (r, D)
+        out[i:i + rows] = np.abs(blk[:, None, :] - b[None, :, :]).mean(-1)
+    return out
+
+
 def match_flat(a: np.ndarray, b: np.ndarray, threshold: float,
                backend: str = "auto") -> np.ndarray:
     """Flattened-crop matcher: a (Na, D), b (Nb, D) -> (Na,) int64.
 
-    ``out[i]`` is the lowest index j minimizing ``mean |a_i - b_j|`` when
-    that minimum is STRICTLY below ``threshold`` (a diff exactly at the
-    threshold does NOT match), else -1. Shared by ``pixel_difference``
-    and the streaming redundancy gate so both paths agree bit-for-bit.
+    ``decide`` over the distance block of a against b: ``out[i]`` is the
+    lowest index j minimizing ``mean |a_i - b_j|`` when that minimum is
+    STRICTLY below ``threshold``, else -1. The streaming tracker and
+    gate decide from blocks of the same two backends with the same
+    ``decide``, so this per-call matcher and their segment replay agree
+    bit for bit.
     """
     Na, Nb = len(a), len(b)
     if Na == 0 or Nb == 0:
         return np.full((Na,), -1, np.int64)
-    # calls come per frame (a few crops against a ring that grows to its
-    # capacity): the kernel path pads rows to power-of-two buckets, so it
-    # compiles O(log) shapes instead of one per (Na, Nb) pair. The
-    # counters read the kernel path's float32 bytes on every backend.
+    # the kernel path pads rows to power-of-two buckets, so it compiles
+    # O(log) shapes instead of one per (Na, Nb) pair. The counters read
+    # the kernel path's float32 bytes on every backend.
     from repro.core.clustering import _pad_bucket
     na, nb = _pad_bucket(Na), _pad_bucket(Nb)
     spans.add("match.calls", 1)
@@ -71,27 +99,14 @@ def match_flat(a: np.ndarray, b: np.ndarray, threshold: float,
     if _resolve_backend(backend) == "kernel":
         from repro.kernels import ops
         from repro.kernels.pixel_diff import PAD
-        # crops padded with zeros trimmed below, references with the
-        # kernel's own never-matching sentinel
-        m, _ = ops.pixel_match(_pad_rows(a, na, 0.0), _pad_rows(b, nb, PAD),
-                               threshold)
-        # focuslint: disable=host-sync -- gate decision is consumed by
-        # host control flow; match_flat returns numpy by contract
-        return np.asarray(m)[:Na].astype(np.int64)
-    a = np.ascontiguousarray(a, np.float32)
-    b = np.ascontiguousarray(b, np.float32)
-    D = a.shape[1]
-    rows = max(1, _BLOCK_ELEMS // max(1, Nb * D))
-    out = np.empty((Na,), np.int64)
-    for i in range(0, Na, rows):
-        blk = a[i:i + rows]                          # (r, D)
-        # (r, Nb): one block of the pairwise matrix; the (r, Nb, D)
-        # broadcast is scratch bounded by _BLOCK_ELEMS, freed per block
-        d = np.abs(blk[:, None, :] - b[None, :, :]).mean(-1)
-        j = d.argmin(1)
-        out[i:i + rows] = np.where(d[np.arange(len(blk)), j] < threshold,
-                                   j, -1)
-    return out
+        # crops padded with zeros, references with the kernel's own
+        # never-matching sentinel; both trimmed off the block below
+        d = ops.pixel_match_block(_pad_rows(a, na, 0.0),
+                                  _pad_rows(b, nb, PAD))
+        # focuslint: disable=host-sync -- the decision is host control
+        # flow; match_flat returns numpy by contract
+        return decide(np.asarray(d)[:Na, :Nb], threshold)
+    return decide(numpy_block(a, b), threshold)
 
 
 class MotionBox(NamedTuple):

@@ -145,6 +145,49 @@ def pixel_match(a, b, threshold, *, ba: int | None = None,
     return _pd.pixel_match(thr, a, b, ba=ba, bn=bn, interpret=interp)
 
 
+def _block_tiles(ba, bn):
+    # interpret mode: one crop tile covers the problem (per-grid-step
+    # dispatch dominates there); 128-wide reference tiles keep the
+    # per-row lane select of the block kernel cheap on both
+    return (ba if ba is not None else (4096 if _interpret() else 128),
+            bn if bn is not None else 128)
+
+
+def pixel_match_block(a, b, *, ba: int | None = None,
+                      bn: int | None = None):
+    """(Na, D), (Nb, D) -> (Na, Nb) f32 block of ``mean |a_i - b_j|``.
+
+    Every entry equals the distance ``pixel_match`` computes for that
+    pair, bit for bit (one shared per-pair body); deciding the matches
+    from the block is ``data.bgsub.decide``'s. Na and Nb are padded to
+    tile multiples inside (crops with zeros, references with the ``3e18``
+    sentinel) and the block is trimmed back to ``(Na, Nb)``. ``Na == 0``
+    or ``Nb == 0`` short-circuits to an empty block.
+    """
+    Na, Nb = a.shape[0], b.shape[0]
+    if Na == 0 or Nb == 0:
+        return jnp.zeros((Na, Nb), jnp.float32)
+    ba, bn = _block_tiles(ba, bn)
+    return _pd.pixel_match_block(a, b, ba=ba, bn=bn,
+                                 interpret=_interpret())
+
+
+def pixel_match_resident(store, a, nb: int, *, ba: int | None = None,
+                         bn: int | None = None):
+    """store (S, D) device-resident references, a (Na, D) crops ->
+    (Na, S + Na) f32 distances to ``[store; a]``, the references padded
+    to ``nb >= S + Na`` rows so every crop bucket shares one width."""
+    ba, bn = _block_tiles(ba, bn)
+    return _pd.pixel_match_resident(store, a, nb=nb, ba=ba, bn=bn,
+                                    interpret=_interpret())
+
+
+def store_put(store, rows, src, dst):
+    """``store[dst] = rows[src]`` in place on the device (``store`` is
+    donated); ``dst`` entries past the store's end are dropped."""
+    return _pd.store_put(store, rows, src, dst)
+
+
 def motion_gate(frame, bg, alpha, threshold, *, tile: int = 8,
                 bh: int | None = None):
     """frame/bg (H, W, 3) -> (new_bg (H, W, 3) f32, tiles (ty, tx) f32,

@@ -42,6 +42,19 @@ def pixel_match_ref(a, b, threshold):
     return jnp.where(min_d < jnp.float32(threshold), j, -1), min_d
 
 
+def pixel_match_block_ref(a, b):
+    """a (Na, D), b (Nb, D) -> (Na, Nb) f32 ``mean |a_i - b_j|``."""
+    af = a.astype(jnp.float32)
+    bf = b.astype(jnp.float32)
+    return jnp.mean(jnp.abs(af[:, None, :] - bf[None, :, :]), axis=-1)
+
+
+def pixel_match_resident_ref(store, a):
+    """store (S, D), a (Na, D) -> (Na, S + Na) f32 distances of each crop
+    to ``[store; a]``."""
+    return pixel_match_block_ref(a, jnp.concatenate([store, a]))
+
+
 def motion_gate_ref(frame, bg, alpha, threshold, tile: int):
     """frame/bg (H, W, 3) -> (new_bg (H, W, 3) f32, tiles (ty, tx) f32,
     hot (ty, tx) bool) with ty = H // tile, tx = W // tile.

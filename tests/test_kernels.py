@@ -296,6 +296,69 @@ def test_pixel_match_property(Na, Nb, data):
 
 
 # ---------------------------------------------------------------------------
+# pixel_match_block / pixel_match_resident
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("Na,Nb,D", [
+    (8, 8, 48), (16, 40, 192), (32, 256, 96), (256, 1024, 48),
+])
+def test_pixel_match_block_equals_numpy_and_pixel_match(Na, Nb, D):
+    """The block's distances equal the numpy block within float32
+    rounding, and at each row's argmin they are ``pixel_match``'s
+    ``min_d`` bit for bit; ``decide`` over the block is its match."""
+    from repro.data.bgsub import decide, numpy_block
+    r = np.random.default_rng(Na * Nb + D)
+    a = r.random((Na, D)).astype(np.float32)
+    b = r.random((Nb, D)).astype(np.float32)
+    b[Nb // 2] = a[0]                        # an exact duplicate
+    blk = np.asarray(ops.pixel_match_block(a, b))
+    assert blk.shape == (Na, Nb) and blk.dtype == np.float32
+    np.testing.assert_allclose(blk, numpy_block(a, b), rtol=1e-6,
+                               atol=1e-7)
+    np.testing.assert_allclose(blk, np.asarray(ref.pixel_match_block_ref(
+        a, b)), rtol=1e-6, atol=1e-7)
+    m, d = ops.pixel_match(a, b, 0.3)
+    j = blk.argmin(1)
+    np.testing.assert_array_equal(blk[np.arange(Na), j], np.asarray(d))
+    np.testing.assert_array_equal(decide(blk, 0.3), np.asarray(m))
+    assert decide(blk, 0.3)[0] == Nb // 2
+
+
+@pytest.mark.parametrize("S,Na,nb", [(8, 8, 64), (24, 16, 64),
+                                     (256, 8, 512), (256, 256, 512)])
+def test_pixel_match_resident_pads_with_sentinels(S, Na, nb):
+    """Against ``[store; crops]`` padded to ``nb`` rows: the trimmed block
+    equals ``pixel_match_block`` on the unpadded references, and the
+    sentinel rows, kept in the untrimmed kernel output, never win."""
+    from repro.kernels import pixel_diff as _pd
+    r = np.random.default_rng(S + Na)
+    store = r.random((S, 48)).astype(np.float32)
+    a = r.random((Na, 48)).astype(np.float32)
+    got = np.asarray(ops.pixel_match_resident(store, a, nb))
+    assert got.shape == (Na, S + Na)
+    want = np.asarray(ops.pixel_match_block(a, np.concatenate([store, a])))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got, np.asarray(
+        ref.pixel_match_resident_ref(store, a)), rtol=1e-6, atol=1e-7)
+    np.testing.assert_array_equal(np.diag(got[:, S:]), 0.0)
+    full = np.asarray(_pd._block(a, np.pad(
+        np.concatenate([store, a]), ((0, nb - S - Na), (0, 0)),
+        constant_values=_pd.PAD), 4096, 128, True))
+    assert (full[:Na, S + Na:] > 1e17).all()
+    assert (full[:Na].argmin(1) < S + Na).all()
+
+
+def test_store_put_writes_and_drops_padding():
+    store = np.zeros((16, 8), np.float32)
+    rows = np.arange(64, dtype=np.float32).reshape(8, 8)
+    out = np.asarray(ops.store_put(store, rows, np.array([3, 5, 0, 0]),
+                                   np.array([2, 9, 16, 16])))
+    want = np.zeros((16, 8), np.float32)
+    want[2], want[9] = rows[3], rows[5]
+    np.testing.assert_array_equal(out, want)
+
+
+# ---------------------------------------------------------------------------
 # motion_gate
 # ---------------------------------------------------------------------------
 

@@ -157,36 +157,37 @@ def _bucket(n):
 
 def test_match_counters_equal_hand_count(monkeypatch):
     """A gated zoo stream through the staged ingestor: ``match.calls``
-    and ``match.bytes`` equal a count of the matcher's own calls under
-    the power-of-two bucket rule."""
+    counts one matcher call per block of at most 256 crops of each
+    segment the ingestor receives, and ``match.bytes`` the float32 crops
+    that cross to the device for it, in power-of-two buckets; the store's
+    rows are never counted again."""
     import repro.core.streaming as S
-    import repro.data.bgsub as B
-    calls = []
-    real = B.match_flat
+    segments = []
+    real = StreamingIngestor._ingest_chunk
 
-    def spy(a, b, threshold, backend="auto"):
-        calls.append((len(a), len(b), a.shape[1]))
-        return real(a, b, threshold, backend)
+    def spy(self, crops, frames, obj_ids):
+        segments.append((len(crops), int(np.prod(crops.shape[1:]))))
+        return real(self, crops, frames, obj_ids)
 
-    monkeypatch.setattr(B, "match_flat", spy)     # the tracker's path
-    monkeypatch.setattr(S, "match_flat", spy)     # the gate's
-    crops, frames = _zoo()
+    monkeypatch.setattr(StreamingIngestor, "_ingest_chunk", spy)
+    crops, frames = _zoo(n_frames=300)
     cfg = IngestConfig(**_CFG)
     spans.enable()
     ing = StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9, cfg)
-    for s in range(0, len(crops), 97):
-        ing.feed(crops[s:s + 97], frames[s:s + 97])
+    cuts = [0, 97, 140, 600, len(crops)]    # 460 crops: two blocks
+    for a, b in zip(cuts, cuts[1:]):
+        ing.feed(crops[a:b], frames[a:b])
         ing.flush()
     ing.finish()
-    real_calls = [(na, nb, d) for na, nb, d in calls if na and nb]
-    assert len(real_calls) > 20
+    blocks = [(min(S._MAX_ROWS, n - p), d) for n, d in segments
+              for p in range(0, n, S._MAX_ROWS)]
+    assert len(blocks) > len(segments) >= 4
     assert ing.stats.n_gate_skipped + ing.stats.n_pixel_dedup > 0
     got = spans.snapshot()
     assert got["counters"] == {
-        "match.calls": len(real_calls),
-        "match.bytes": sum(4 * d * (_bucket(na) + _bucket(nb))
-                           for na, nb, d in real_calls)}
-    assert {"ingest.frames", "ingest.track", "ingest.gate",
+        "match.calls": len(blocks),
+        "match.bytes": sum(4 * d * _bucket(n) for n, d in blocks)}
+    assert {"ingest.frames", "ingest.match", "ingest.track", "ingest.gate",
             "ingest.megastep", "ingest.fold",
             "ingest.publish"} <= set(got["spans"])
 

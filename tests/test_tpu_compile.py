@@ -105,6 +105,31 @@ def test_pixel_match_compiles(one_chip, Na, Nb):
     _assert_kernel(c)
 
 
+@pytest.mark.parametrize("Na", [8, 256])
+def test_pixel_match_resident_compiles(one_chip, Na):
+    """One streaming block: new crops against the gate's row store (768
+    slots for a 512-row ring) and themselves, one 1,024-row width."""
+    c = _pd.pixel_match_resident.lower(
+        _spec((768, CROP_D), jnp.float32, one_chip),
+        _spec((Na, CROP_D), jnp.float32, one_chip),
+        nb=1024, ba=128, bn=128, interpret=False).compile()
+    _assert_kernel(c)
+    c = _pd.store_put.lower(
+        _spec((768, CROP_D), jnp.float32, one_chip),
+        _spec((Na, CROP_D), jnp.float32, one_chip),
+        _spec((Na,), jnp.int32, one_chip),
+        _spec((Na,), jnp.int32, one_chip)).compile()
+    assert c is not None
+
+
+def test_pixel_match_block_compiles(one_chip):
+    c = _pd.pixel_match_block.lower(
+        _spec((32, CROP_D), jnp.float32, one_chip),
+        _spec((GATE_RING, CROP_D), jnp.float32, one_chip),
+        ba=128, bn=128, interpret=False).compile()
+    _assert_kernel(c)
+
+
 def test_spec1_megastep_compiles(one_chip, monkeypatch):
     """The fused ingest megastep (cheap-CNN forward -> topk -> phase-1
     centroid_assign -> matched fold) of ``spec1`` at batch 512."""
