@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
@@ -211,12 +211,26 @@ class EffNetConfig:
 
 @dataclass(frozen=True)
 class CheapCNNConfig:
-    """Focus ingest CNN: a small convnet (compressed family member).
+    """Focus ingest CNN, a member of the compressed ResNet family (§4.1).
 
-    ``n_blocks`` plays the role of "number of conv layers kept" and
-    ``input_res`` the rescaled input resolution — the two compression axes the
-    paper uses (§4.1). ``n_classes`` shrinks under specialization (§4.3:
-    Ls most-frequent classes + OTHER).
+    Two members share the entry points of ``models/cnn.py``:
+
+    - plain (``stage_widths`` empty): ``n_blocks`` 3x3 convs from
+      ``width`` channels, then a ``tanh`` dense layer to ``feature_dim``,
+      the clustering feature;
+    - residual (``stage_widths`` given): a ResNet (arXiv:1512.03385,
+      Table 1) with a 7x7 stride-2 stem to ``stem_width`` channels and a
+      3x3 max pool, then stages of ``stage_depths[i]`` BasicBlocks at
+      ``stage_widths[i]`` channels, each stage after the first opening at
+      stride 2 with a 1x1 projection shortcut. The feature is the pooled
+      output, so ``feature_dim`` must equal ``stage_widths[-1]``;
+      ``n_blocks`` and ``width`` are unused. ResNet-18 is stem 64,
+      widths (64, 128, 256, 512), depths (2, 2, 2, 2).
+
+    The paper's two compression axes are the layers kept (``n_blocks``,
+    ``stage_depths``) and the rescaled input: crops are repeated on the
+    device by an integer factor up to ``input_res``. ``n_classes`` shrinks
+    under specialization (§4.3: Ls most-frequent classes + OTHER).
     """
 
     name: str
@@ -227,6 +241,22 @@ class CheapCNNConfig:
     feature_dim: int = 128        # penultimate-layer feature vector (clustering)
     in_channels: int = 3
     dtype: str = "float32"
+    stem_width: int = 64
+    stage_widths: Tuple[int, ...] = ()
+    stage_depths: Tuple[int, ...] = ()
+
+    def __post_init__(self):
+        if len(self.stage_widths) != len(self.stage_depths):
+            raise ValueError("stage_widths and stage_depths differ in length")
+        if self.stage_widths and self.feature_dim != self.stage_widths[-1]:
+            raise ValueError(
+                f"a residual member's feature is its pooled output: "
+                f"feature_dim must be {self.stage_widths[-1]}, got "
+                f"{self.feature_dim}")
+
+    @property
+    def residual(self) -> bool:
+        return bool(self.stage_widths)
 
     def flops_per_image(self) -> int:
         from repro.models import cnn

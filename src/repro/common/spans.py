@@ -26,7 +26,9 @@ import time
 SPAN_NAMES = ("ingest.frames", "ingest.match", "ingest.track",
               "ingest.gate", "ingest.megastep", "ingest.fold", "ingest.seal",
               "ingest.publish")
-COUNTER_NAMES = ("match.calls", "match.bytes")
+# cnn.rows: rows sent through the cheap CNN, bucket padding (and idle
+# stream slots) included, counted where a batch is built
+COUNTER_NAMES = ("match.calls", "match.bytes", "cnn.rows")
 
 _on = False
 _annotation = None                 # jax.profiler.TraceAnnotation, once on

@@ -45,6 +45,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from repro.common import spans
 from repro.core import clustering as C
@@ -86,6 +87,19 @@ def _donate_argnums() -> tuple:
     return () if jax.default_backend() == "cpu" else (0, 1, 2)
 
 
+def _donate_after_model() -> tuple:
+    """``_donate_argnums`` for a step whose first argument is the model."""
+    return () if jax.default_backend() == "cpu" else (1, 2, 3)
+
+
+def _as_model(cheap_fn: Callable) -> Partial:
+    """``cheap_fn`` as a pytree the jitted steps take as their first
+    argument: a ``Partial`` (``SpecializedModel.make_traceable``) passes
+    its parameters as program arguments; a plain function has no leaves
+    and stays a closure, its program unchanged."""
+    return cheap_fn if isinstance(cheap_fn, Partial) else Partial(cheap_fn)
+
+
 # ---------------------------------------------------------------------------
 # jitted steps (module-cached so every pipeline over the same cheap_fn
 # shares compiled executables)
@@ -115,8 +129,8 @@ def _megastep_jit(cheap_fn: Callable, k_top: int,
         _MEGASTEP_JITS.move_to_end(key)
         return fn
 
-    def megastep(centroids, counts, n, threshold, n_real, crops):
-        probs, feats = cheap_fn(crops)
+    def megastep(model, centroids, counts, n, threshold, n_real, crops):
+        probs, feats = model(crops)
         probs = probs.astype(jnp.float32)
         feats = feats.astype(jnp.float32)
         if with_topk:
@@ -130,7 +144,7 @@ def _megastep_jit(cheap_fn: Callable, k_top: int,
         return (state.centroids, state.counts, state.n,
                 probs, feats, j, matched, vals, idxs)
 
-    fn = jax.jit(megastep, donate_argnums=_donate_argnums())
+    fn = jax.jit(megastep, donate_argnums=_donate_after_model())
     _MEGASTEP_JITS[key] = fn
     if len(_MEGASTEP_JITS) > _MEGASTEP_JITS_MAX:
         _MEGASTEP_JITS.popitem(last=False)
@@ -160,7 +174,8 @@ def staged_cheap_apply(cheap_fn: Callable, cfg) -> Callable:
     forward with the SAME ``batch_bucket`` padding the pipeline uses,
     returning numpy ``(probs, feats)``. This is the baseline the fused
     megastep is benchmarked — and byte-compared — against."""
-    fwd = jax.jit(cheap_fn)
+    model = _as_model(cheap_fn)
+    fwd = jax.jit(lambda m, x: m(x))
 
     # focuslint: disable=host-sync -- staged boundary by contract: apply
     # returns host arrays; the fused pipeline is the async path
@@ -173,7 +188,7 @@ def staged_cheap_apply(cheap_fn: Callable, cfg) -> Callable:
             return (np.zeros((0, p_s.shape[1]), np.float32),
                     np.zeros((0, f_s.shape[1]), np.float32))
         padded = _pad_rows(np.asarray(crops), batch_bucket(n, cfg.batch_size))
-        probs, feats = fwd(jnp.asarray(padded))
+        probs, feats = fwd(model, jnp.asarray(padded))
         return (np.asarray(probs, np.float32)[:n],
                 np.asarray(feats, np.float32)[:n])
 
@@ -240,6 +255,7 @@ class IngestPipeline:
                  topk_k: Optional[int] = None,
                  topk_sink: Optional[Callable] = None):
         self.cheap_fn = cheap_fn
+        self._model = _as_model(cheap_fn)
         self.cfg = cfg
         if cfg is not None:
             self._check_clustering(cfg)
@@ -361,7 +377,8 @@ class IngestPipeline:
         fn = self._megastep_fn = _megastep_jit(self.cheap_fn, k_top,
                                                self.topk_sink is not None)
         st = self._ing._state
-        out = fn(st.centroids, st.counts, st.n,
+        spans.add("cnn.rows", bucket)
+        out = fn(self._model, st.centroids, st.counts, st.n,
                  jnp.asarray(self.cfg.threshold, jnp.float32),
                  np.int32(n), jnp.asarray(_pad_rows(np.asarray(crops),
                                                     bucket)))
@@ -501,13 +518,14 @@ def _sharded_megastep_jit(cheap_fn: Callable, k_top: int, with_topk: bool,
 
     from repro.distributed import sharding as shd
 
-    def ingest_megastep(cen, cnt, nv, thr, n_real, crops):
+    def ingest_megastep(model, cen, cnt, nv, thr, n_real, crops):
         # per-device block: cen (W,M,D) cnt (W,M) nv (W,) n_real (W,)
-        # crops (W,B,R,R,3); thr is replicated. Unrolled so every slot
-        # runs the unbatched single-device computation bit-for-bit.
+        # crops (W,B,R,R,3); the model's parameters and thr are
+        # replicated. Unrolled so every slot runs the unbatched
+        # single-device computation bit-for-bit.
         outs = []
         for w in range(width):
-            probs, feats = cheap_fn(crops[w])
+            probs, feats = model(crops[w])
             probs = probs.astype(jnp.float32)
             feats = feats.astype(jnp.float32)
             if with_topk:
@@ -524,7 +542,7 @@ def _sharded_megastep_jit(cheap_fn: Callable, k_top: int, with_topk: bool,
                      for i in range(len(outs[0])))
 
     s = lambda r: shd.stream_spec(mesh, r)          # noqa: E731
-    in_specs = (s(2), s(1), s(0), P(), s(0), s(4))
+    in_specs = (P(), s(2), s(1), s(0), P(), s(0), s(4))
     out_specs = (s(2), s(1), s(0), s(2), s(2), s(1), s(1))
     if with_topk:
         out_specs = out_specs + (s(2), s(2))
@@ -532,7 +550,7 @@ def _sharded_megastep_jit(cheap_fn: Callable, k_top: int, with_topk: bool,
     fn = jax.jit(jax.shard_map(ingest_megastep, mesh=mesh,
                                in_specs=in_specs, out_specs=out_specs,
                                check_vma=False),
-                 donate_argnums=_donate_argnums())
+                 donate_argnums=_donate_after_model())
     _MEGASTEP_JITS[key] = fn
     if len(_MEGASTEP_JITS) > _MEGASTEP_JITS_MAX:
         _MEGASTEP_JITS.popitem(last=False)
@@ -670,6 +688,8 @@ class ShardedIngestPipeline:
         self.stats = PipelineStats()
         # hoisted once: shardings are never rebuilt per step
         self._shardings = shd.ingest_shardings(mesh)
+        self._model = jax.device_put(_as_model(cheap_fn),
+                                     self._shardings["replicated"])
         self._slots: List[Optional[_ShardSlot]] = [
             (_ShardSlot(self, nm, i) if nm is not None else None)
             for i, nm in enumerate(slots)]
@@ -774,7 +794,8 @@ class ShardedIngestPipeline:
             with_topk = self.topk_sink is not None
             fn = self._megastep_fn = _sharded_megastep_jit(
                 self.cheap_fn, k_top, with_topk, self.mesh, self.width)
-            out = fn(self._cen, self._cnt, self._n, self._thr,
+            spans.add("cnn.rows", S * bucket)   # idle slots run it too
+            out = fn(self._model, self._cen, self._cnt, self._n, self._thr,
                      jax.device_put(n_real, self._shardings["n_real"]),
                      jax.device_put(crops_stack, self._shardings["crops"]))
             if with_topk:

@@ -9,13 +9,16 @@ accurate on their stream, which lets Focus use a much smaller K.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
+from repro.common import spans
 from repro.common.config import CheapCNNConfig
 from repro.core.index import ClassMap
 from repro.models import cnn
@@ -52,24 +55,39 @@ class SpecializedModel:
             if pad:
                 crops = np.concatenate(
                     [crops, np.zeros((pad,) + crops.shape[1:], crops.dtype)])
+            spans.add("cnn.rows", n + pad)
             probs, feats = fwd(jnp.asarray(crops))
             return np.asarray(probs)[:n], np.asarray(feats)[:n]
 
         return apply
 
-    def make_traceable(self) -> Callable:
+    def make_traceable(self) -> Partial:
         """The bare jax-traceable forward ``crops -> (probs, feats)`` —
         what a fused ``IngestPipeline``/``ShardedIngestPipeline`` inlines
         into its megastep (``make_apply`` wraps the same computation in a
-        host pad/unpad boundary, which cannot be traced)."""
-        cfg = self.cfg
-        params = self.params
+        host pad/unpad boundary, which cannot be traced). A ``Partial``
+        over the parameters: the pipelines pass them to their programs as
+        arguments, so the weights are not compiled in as constants."""
+        return Partial(functools.partial(_probs_and_feats, self.cfg),
+                       self.params)
 
-        def fwd(crops):
-            logits, feats = cnn.forward(params, crops, cfg)
-            return jax.nn.softmax(logits, axis=-1), feats
 
-        return fwd
+def _probs_and_feats(cfg, params, crops):
+    logits, feats = cnn.forward(params, crops, cfg)
+    return jax.nn.softmax(logits, axis=-1), feats
+
+
+def bn_statistics(params, crops: np.ndarray, cfg: CheapCNNConfig,
+                  batch_size: int = 128):
+    """A residual member's BN statistics over a sample: each BN's batch
+    mean and variance (training form), averaged over the sample's batches
+    — the population statistics the running averages estimate."""
+    stats_of = jax.jit(lambda p, x: cnn.forward_train(p, x, cfg)[2])
+    n = len(crops)
+    parts = [stats_of(params, jnp.asarray(crops[b:b + batch_size]))
+             for b in range(0, n - batch_size + 1, batch_size)] or \
+        [stats_of(params, jnp.asarray(crops))]
+    return jax.tree.map(lambda *a: jnp.mean(jnp.stack(a), 0), *parts)
 
 
 def estimate_distribution(gt_labels: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
@@ -124,6 +142,11 @@ def specialize(sample_crops: np.ndarray, sample_gt_labels: np.ndarray,
                         total_steps=steps, weight_decay=1e-4)
     params, history = train(loss_fn, params, data_iter(), opt_cfg,
                             TrainConfig(steps=steps, log_every=max(steps // 4, 1)))
+    if cfg.residual:
+        # trained on batch statistics: fold the sample's into the
+        # inference form the pipelines run
+        params = cnn.fold(params, bn_statistics(params, sample_crops, cfg,
+                                                batch_size))
     return SpecializedModel(params, cfg, cmap, history)
 
 
@@ -151,4 +174,7 @@ def train_generic(sample_crops: np.ndarray, sample_gt_labels: np.ndarray,
                         total_steps=steps, weight_decay=1e-4)
     params, history = train(loss_fn, params, data_iter(), opt_cfg,
                             TrainConfig(steps=steps, log_every=max(steps // 4, 1)))
+    if cfg.residual:
+        params = cnn.fold(params, bn_statistics(params, sample_crops, cfg,
+                                                batch_size))
     return SpecializedModel(params, cfg, None, history)

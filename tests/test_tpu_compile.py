@@ -12,7 +12,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+from jax.tree_util import Partial
 
 from repro.kernels import centroid_assign as _ca
 from repro.kernels import dequant_topk as _dq
@@ -22,6 +24,7 @@ from repro.kernels import topk_mask as _tk
 BATCH = 512            # IngestConfig.batch_size
 MAX_CLUSTERS = 4096    # IngestConfig.max_clusters
 FEAT_DIM = 128         # cheap-CNN feature_dim
+R18_FEAT_DIM = 512     # ResNet-18's pooled feature
 CROP_D = 32 * 32 * 3   # flattened 32 px crops
 GATE_RING = 512        # IngestConfig.gate_capacity
 GENERIC_C = 1000       # generic cheap-CNN classes
@@ -65,12 +68,13 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("B", [BATCH, 37])
-def test_centroid_assign_compiles(one_chip, B):
+@pytest.mark.parametrize("B,D", [(BATCH, FEAT_DIM), (37, FEAT_DIM),
+                                 (BATCH, R18_FEAT_DIM)])
+def test_centroid_assign_compiles(one_chip, B, D):
     c = _ca._assign_impl.lower(
         _spec((1,), jnp.float32, one_chip),
-        _spec((B, FEAT_DIM), jnp.float32, one_chip),
-        _spec((MAX_CLUSTERS, FEAT_DIM), jnp.float32, one_chip),
+        _spec((B, D), jnp.float32, one_chip),
+        _spec((MAX_CLUSTERS, D), jnp.float32, one_chip),
         bb=128, bm=128, interpret=False).compile()
     _assert_kernel(c)
 
@@ -155,6 +159,7 @@ def test_spec1_megastep_compiles(one_chip, monkeypatch):
     try:
         step = pipeline._megastep_jit(cheap_fn, 4, True)
         c = step.lower(
+            Partial(cheap_fn),
             _spec((MAX_CLUSTERS, FEAT_DIM), jnp.float32, one_chip),
             _spec((MAX_CLUSTERS,), jnp.int32, one_chip),
             _spec((), jnp.int32, one_chip),
@@ -166,3 +171,48 @@ def test_spec1_megastep_compiles(one_chip, monkeypatch):
         jax.clear_caches()
     # two kernels in the one program: topk and centroid_assign
     assert c.as_text().count("tpu_custom_call") >= 2
+
+
+def test_resnet18_megastep_compiles(topo, monkeypatch):
+    """The sharded ingest megastep as the ResNet-18 cell runs it: one
+    stream slot on a one-chip mesh, 32 px crops repeated to 224 px, batch
+    512, the weights as program arguments. It fits the chip's 16 GB."""
+    from repro.common.config import CheapCNNConfig
+    from repro.core import pipeline
+    from repro.core.specialize import SpecializedModel
+    from repro.kernels import ops
+    from repro.models import cnn
+
+    cfg = CheapCNNConfig("resnet18", input_res=224, n_classes=7,
+                         feature_dim=R18_FEAT_DIM, stem_width=64,
+                         stage_widths=(64, 128, 256, 512),
+                         stage_depths=(2, 2, 2, 2))
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    rep = NamedSharding(mesh, P())
+    row = lambda *shape: NamedSharding(                        # noqa: E731
+        mesh, P("data", *[None] * (len(shape) - 1)))
+    shapes = jax.eval_shape(lambda: cnn.init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, rep), shapes)
+    model = SpecializedModel(params, cfg, None, []).make_traceable()
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    jax.clear_caches()
+    try:
+        step = pipeline._sharded_megastep_jit(model, 4, False, mesh, 1)
+        c = step.lower(
+            model,
+            _spec((1, MAX_CLUSTERS, R18_FEAT_DIM), jnp.float32,
+                  row(1, MAX_CLUSTERS, R18_FEAT_DIM)),
+            _spec((1, MAX_CLUSTERS), jnp.int32, row(1, MAX_CLUSTERS)),
+            _spec((1,), jnp.int32, row(1)),
+            _spec((), jnp.float32, rep),
+            _spec((1,), jnp.int32, row(1)),
+            _spec((1, BATCH, 32, 32, 3), jnp.float32,
+                  row(1, BATCH, 32, 32, 3))).compile()
+    finally:
+        pipeline._MEGASTEP_JITS.clear()
+        jax.clear_caches()
+    assert "tpu_custom_call" in c.as_text()          # centroid_assign
+    mem = c.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < 16e9, used
