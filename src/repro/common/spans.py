@@ -27,8 +27,9 @@ SPAN_NAMES = ("ingest.frames", "ingest.match", "ingest.track",
               "ingest.gate", "ingest.megastep", "ingest.fold", "ingest.seal",
               "ingest.publish")
 # cnn.rows: rows sent through the cheap CNN, bucket padding (and idle
-# stream slots) included, counted where a batch is built
-COUNTER_NAMES = ("match.calls", "match.bytes", "cnn.rows")
+# stream slots) included, counted where a batch is built; match.passes:
+# the gate replay's passes over its blocks
+COUNTER_NAMES = ("match.calls", "match.bytes", "match.passes", "cnn.rows")
 
 _on = False
 _annotation = None                 # jax.profiler.TraceAnnotation, once on

@@ -28,7 +28,6 @@ still waiting for a full batch and the duplicates chained to them.
 """
 from __future__ import annotations
 
-import collections
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Mapping, Optional, Tuple
 
@@ -203,14 +202,37 @@ class _Block:
         refs[~old] = self.crops[cols[~old] - self.base]
         return refs
 
-    def match(self, rows: np.ndarray, cols: np.ndarray,
-              threshold: float) -> np.ndarray:
-        """``decide`` for crops ``rows`` against columns ``cols``: the
-        position in ``cols`` of each crop's match, or -1."""
-        if self.dist is not None:
-            return decide(self.dist[rows[:, None], cols], threshold)
-        return decide(numpy_block(self.crops[rows], self._refs(cols)),
-                      threshold)
+    def dists(self, rows: np.ndarray, cols: np.ndarray) -> np.ndarray:
+        """The float32 distances of crops ``rows`` (ascending) to columns
+        ``cols``."""
+        if self.dist is None:
+            return numpy_block(self.crops[rows], self._refs(cols))
+        if len(rows) and rows[-1] - rows[0] == len(rows) - 1:
+            return np.take(self.dist[rows[0]:rows[-1] + 1], cols, 1)
+        return np.take(np.take(self.dist, rows, 0), cols, 1)
+
+    def window_match(self, rows: np.ndarray, cols: np.ndarray,
+                     lo: np.ndarray, hi: np.ndarray,
+                     threshold: float) -> np.ndarray:
+        """``decide`` for each crop ``rows[k]`` against the columns
+        ``cols[lo[k]:hi[k]]`` alone: the position in ``cols`` of its
+        match, or -1."""
+        c0, c1 = int(lo.min()), int(hi.max())
+        if c1 <= c0:
+            return np.full(len(rows), -1, np.int64)
+        d = self.dists(rows, cols[c0:c1])
+        # a column out of row k's window lies left of max(lo) or right of
+        # min(hi): only those two edges need a mask
+        lo, hi = lo - c0, hi - c0
+        k = np.arange(c1 - c0)
+        left, right = lo.max(), hi.min()
+        inf = np.float32(np.inf)
+        if left > 0:
+            np.copyto(d[:, :left], inf, where=k[:left] < lo[:, None])
+        if right < c1 - c0:
+            np.copyto(d[:, right:], inf, where=k[right:] >= hi[:, None])
+        m = decide(d, threshold)
+        return np.where(m >= 0, m + c0, -1)
 
     def match_groups(self, cols: np.ndarray, labels: np.ndarray,
                      want: np.ndarray, threshold: float) -> np.ndarray:
@@ -226,7 +248,7 @@ class _Block:
             sel = np.flatnonzero(labels == w)
             if len(sel):
                 rows = np.flatnonzero(want == w)
-                m = self.match(rows, cols[sel], threshold)
+                m = decide(self.dists(rows, cols[sel]), threshold)
                 out[rows] = np.where(m >= 0, sel[m], -1)
         return out
 
@@ -245,9 +267,10 @@ class _PixelTracker:
     A crop of frame ``f`` matches within frame ``f - 1``'s group, so a
     whole block is decided at once (``begin``): its crops against the
     carried groups and the block's own earlier crops, each row masked to
-    its frame's predecessor. Roots then follow frame by frame (``roots``,
-    ``record``), since a match takes its reference's final root — after
-    the gate may have rewritten it.
+    its frame's predecessor. Whether a crop is tracker-unique is then
+    known, and the gate decides those. A match takes its reference's
+    final root, gate rewrites included, so ``settle`` follows the match
+    pointers over the whole block by pointer jumping.
     """
 
     def __init__(self, threshold: float):
@@ -256,8 +279,9 @@ class _PixelTracker:
         self._frames = np.zeros(0, np.int64)
         self._roots = np.zeros(0, np.int64)
 
-    def begin(self, blk: _Block, frames: np.ndarray):
-        """Decide every match of the block's crops (frames ``frames``)."""
+    def begin(self, blk: _Block, frames: np.ndarray) -> np.ndarray:
+        """Decide every match of the block's crops (frames ``frames``);
+        returns the tracker-unique block rows."""
         if len(self._frames) and frames[0] < self._frames[-1]:
             raise ValueError(
                 f"frames must be non-decreasing across feeds: got frame "
@@ -269,21 +293,32 @@ class _PixelTracker:
                                       np.zeros(n, np.int64)])
         self._match = blk.match_groups(self._cols, self._frames,
                                        frames - 1, self.threshold)
+        return np.flatnonzero(self._match < 0)
 
-    def roots(self, i: int, j: int, ids: np.ndarray) -> np.ndarray:
-        """Roots of the block's crops ``i:j`` (one frame group)."""
-        m = self._match[i:j]
-        roots = ids.copy()
-        hit = m >= 0
-        roots[hit] = self._roots[m[hit]]
-        return roots
+    def settle(self, roots: np.ndarray) -> np.ndarray:
+        """The block's final roots from ``roots`` (ids, with the gate's
+        rewrites of tracker-unique crops): a next-frame match chains to
+        its reference's final root, never to a never-folded id. Keeps the
+        last two frame groups."""
+        off, m = self._off, self._match
+        self._roots[off:] = roots
+        hit = np.flatnonzero(m >= 0)
+        if len(hit):
+            # every match points to an earlier entry: halve each chain
+            # until every pointer names an unmatched entry
+            ptr = np.arange(len(self._roots))
+            ptr[off + hit] = m[hit]
+            while True:
+                nxt = ptr[ptr]
+                if np.array_equal(nxt, ptr):
+                    break
+                ptr = nxt
+            self._roots = self._roots[ptr]
+        out = self._roots[off:]
+        self._end()
+        return out
 
-    def record(self, i: int, j: int, roots: np.ndarray):
-        """The final roots of crops ``i:j``, gate rewrites included: a
-        next-frame match chains to them, never to a never-folded id."""
-        self._roots[self._off + i:self._off + j] = roots
-
-    def end(self):
+    def _end(self):
         """Keep the last two frame groups (the open and the previous)."""
         fr = self._frames
         prev = fr[fr < fr[-1]]
@@ -327,62 +362,138 @@ class _RedundancyGate:
         self.threshold = threshold
         self.capacity = capacity
         # the ring, oldest first: store columns, root ids, and the size of
-        # each admitted frame group (trims drop whole groups)
+        # each admitted frame group (trims drop whole groups); then the
+        # open frame's admissions, which join the ring when it closes
         self._ring_cols = np.zeros(0, np.int64)
         self._ring_roots = np.zeros(0, np.int64)
-        self._sizes: collections.deque = collections.deque()
+        self._sizes = np.zeros(0, np.int64)
         self._open_frame: Optional[int] = None
-        self._open_cols: List[np.ndarray] = []
-        self._open_roots: List[np.ndarray] = []
+        self._open_cols = np.zeros(0, np.int64)
+        self._open_roots = np.zeros(0, np.int64)
 
-    def match(self, f: int, rows: np.ndarray, blk: _Block) -> np.ndarray:
-        """Ring root id per crop (or -1) for the block's frame-``f`` crops
-        ``rows``. Also advances the open-frame bookkeeping, so call it
-        once per resolved group even when ``rows`` is empty."""
-        if self._open_frame is None or f > self._open_frame:
-            if self._open_cols:
-                self._push(np.concatenate(self._open_cols),
-                           np.concatenate(self._open_roots))
-                self._open_cols, self._open_roots = [], []
-            self._open_frame = f
-        if not len(self._ring_cols) or not len(rows):
-            return np.full(len(rows), -1, np.int64)
-        m = blk.match(rows, self._ring_cols, self.threshold)
-        return np.where(m >= 0, self._ring_roots[m], -1)
+    def replay(self, blk: _Block, frames: np.ndarray, rows: np.ndarray,
+               ids: np.ndarray, pace: "_Pace") -> np.ndarray:
+        """Ring root id (or -1) per tracker-unique crop ``rows`` (block
+        rows, ascending, with ids ``ids``) of a block whose crops have
+        frames ``frames``; moves the ring and the open group past the
+        block (DESIGN.md §10).
 
-    def admit(self, cols: np.ndarray, roots: np.ndarray):
-        """Queue frame-``f`` CNN-bound uniques (f = the frame of the last
-        ``match`` call); they join the ring when the frame closes."""
-        if len(cols):
-            self._open_cols.append(cols)
-            self._open_roots.append(roots)
+        Every ring a block's crops meet is a window of one admission
+        sequence: the ring, the open group, then the block's admitted
+        crops in row order. A pass over a window of frame groups assumes
+        each of its crops' admission, derives every group's ring window
+        from that, and decides each crop against its window, as
+        ``decide`` would. Its decisions hold through the first group
+        where one departs from the assumption; the next pass starts
+        after that group and assumes what this one decided.
+        """
+        cuts = np.flatnonzero(frames[1:] != frames[:-1]) + 1
+        last = int(frames[-1])
+        # each crop's frame group, and each group's first crop
+        group = np.searchsorted(cuts, rows, side="right")
+        start = np.searchsorted(group, np.arange(len(cuts) + 2))
+        # the block's first group joins the open group if it continues the
+        # open frame; crop k's ring ends where its group's predecessor does
+        merged = int(self._open_frame is not None
+                     and frames[0] <= self._open_frame)
+        at = len(self._sizes) + 1 - merged + group
+        head = np.concatenate([[0], np.cumsum(self._sizes)])
+        base = len(self._ring_cols) + len(self._open_cols)
+        cols = np.concatenate([self._ring_cols, self._open_cols,
+                               blk.cols(rows)])
+        roots = np.concatenate([self._ring_roots, self._open_roots, ids])
+        adm = np.full(len(rows), pace.admit)
+        out = np.full(len(rows), -1, np.int64)
+        a = reach = 0
+        seq = None
+        while a < len(start) - 1:
+            b = min(len(start) - 1, a + pace.window)
+            ja, jb = start[a], start[b]
+            if ja == jb:
+                a = b
+                continue
+            spans.add("match.passes", 1)
+            if seq is None:
+                seq, ends = self._sequence(adm, head, base, start[merged:])
+            hi = ends[at[ja:jb]]
+            m = blk.window_match(rows[ja:jb], cols[seq],
+                                 self._ring_start(ends, hi), hi,
+                                 self.threshold)
+            hit = m >= 0
+            # a decision that departs from the assumption holds, and
+            # every decision after its frame group is void
+            off = np.flatnonzero(hit == adm[ja:jb])
+            if len(off):
+                b = group[ja + off[0]] + 1
+            h = np.flatnonzero(hit[:start[b] - ja])
+            out[ja + h] = roots[seq[m[h]]]
+            if len(off):
+                adm[ja:jb] = ~hit
+                seq = None
+                pace.window = max(1, pace.window // 2)
+            else:
+                pace.window = min(_MAX_ROWS, 2 * pace.window)
+            if jb > reach:
+                # past every pass's reach, assume what the last crop did
+                reach = jb
+                if jb < len(adm):
+                    adm[jb:] = adm[jb - 1]
+                    seq = None
+            a = b
+        if len(rows):
+            pace.admit = bool(adm[-1])
+        if seq is None:
+            seq, ends = self._sequence(adm, head, base, start[merged:])
+        e = ends[len(self._sizes) + len(cuts) + 1 - merged]
+        s = self._ring_start(ends, e)
+        kept = np.diff(ends[(ends >= s) & (ends <= e)])
+        self._sizes = kept[kept > 0]
+        self._ring_cols, self._open_cols = cols[seq[s:e]], cols[seq[e:]]
+        self._ring_roots, self._open_roots = roots[seq[s:e]], roots[seq[e:]]
+        self._open_frame = (last if self._open_frame is None
+                            else max(last, self._open_frame))
+        return out
 
-    def _push(self, cols: np.ndarray, roots: np.ndarray):
-        self._sizes.append(len(roots))
-        n = len(self._ring_roots) + len(roots)
-        # trim whole frame groups while the remainder still covers the
-        # capacity: ring size stays in [capacity, capacity + group)
-        cut = 0
-        while len(self._sizes) > 1 and n - self._sizes[0] >= self.capacity:
-            k = self._sizes.popleft()
-            n -= k
-            cut += k
-        self._ring_cols = np.concatenate([self._ring_cols[cut:], cols])
-        self._ring_roots = np.concatenate([self._ring_roots[cut:], roots])
+    @staticmethod
+    def _sequence(adm: np.ndarray, head: np.ndarray, base: int,
+                  start: np.ndarray):
+        """Under admissions ``adm`` of the block's crops: the index into
+        [ring; open group; block crops] of each admission sequence
+        position, and the end of each frame group in that sequence
+        (``head``: 0 and the ring's group ends; then the open group and
+        the block's groups, ``start`` giving each one's first crop)."""
+        n = np.concatenate([[0], np.cumsum(adm)])
+        seq = np.concatenate([np.arange(base), base + np.flatnonzero(adm)])
+        return seq, np.concatenate([head, base + n[start]])
+
+    def _ring_start(self, ends: np.ndarray, end):
+        """Where the ring that ends at ``end`` starts: trims drop the
+        oldest whole group while the rest still covers the capacity."""
+        return ends[np.searchsorted(ends, np.maximum(end - self.capacity, 0),
+                                    side="right") - 1]
 
     def live_roots(self) -> set:
         """Root ids a future gate match may still return (ring + open) —
         their ``_root_cid`` entries must survive pruning."""
-        keep = set(self._ring_roots.tolist())
-        for seg in self._open_roots:
-            keep.update(seg.tolist())
-        return keep
+        return set(self._ring_roots.tolist()) | set(
+            self._open_roots.tolist())
 
     def columns(self) -> List[np.ndarray]:
-        return [self._ring_cols] + self._open_cols
+        return [self._ring_cols, self._open_cols]
 
     def set_columns(self, cols: List[np.ndarray]):
-        self._ring_cols, self._open_cols = cols[0], list(cols[1:])
+        self._ring_cols, self._open_cols = cols
+
+
+class _Pace:
+    """How a stream's gate replay paces its passes (DESIGN.md §10): the
+    frame groups a pass covers, and the admission it assumes for a crop
+    no pass has reached. It follows the hits it observes and decides
+    nothing, so it outlives a shard's reset."""
+    __slots__ = ("window", "admit")
+
+    def __init__(self):
+        self.window, self.admit = 1, True
 
 
 class _FrameMatcher:
@@ -394,9 +505,9 @@ class _FrameMatcher:
     begins (a store row: the ring, a carried frame group) or an earlier
     crop of the same segment. So ``resolve`` makes one matcher call per
     block of at most ``_MAX_ROWS`` crops, then replays the tracker's and
-    the gate's per-frame decisions from that block on the host, in
-    stream order and under ``bgsub.decide``'s rule — the decisions a
-    per-frame ``match_flat`` against the same references would make.
+    the gate's decisions from that block on the host, a block at a time
+    and under ``bgsub.decide``'s rule — the decisions a per-frame
+    ``match_flat`` against the same references would make.
     """
 
     def __init__(self, track_threshold: Optional[float],
@@ -407,6 +518,7 @@ class _FrameMatcher:
                            else (gate_threshold, gate_capacity))
         self.store = _RowStore(
             _store_slots(gate_capacity if self._gate_args else 0))
+        self._pace = _Pace()
         self.reset()
 
     def reset(self):
@@ -429,47 +541,25 @@ class _FrameMatcher:
             p1 = min(n, p0 + _MAX_ROWS)
             with spans.span("ingest.match"):
                 blk = self.store.block(flat[p0:p1])
-            fr, bids = frames[p0:p1], ids[p0:p1]
+            fr, r = frames[p0:p1], roots[p0:p1]
+            uniq = np.arange(p1 - p0)
             if tracker is not None:
                 with spans.span("ingest.track"):
-                    tracker.begin(blk, fr)
-            cuts = np.flatnonzero(fr[1:] != fr[:-1]) + 1
-            for i, j in zip([0, *cuts.tolist()], [*cuts.tolist(), p1 - p0]):
-                fid = bids[i:j]
-                r = fid
-                if tracker is not None:
-                    r = tracker.roots(i, j, fid)
-                    stats.n_pixel_dedup += int((r != fid).sum())
-                if gate is not None:
-                    with spans.span("ingest.gate"):
-                        r = self._gate_group(int(fr[i]), i, r, fid, blk,
-                                             stats)
-                if tracker is not None:
-                    tracker.record(i, j, r)
-                roots[p0 + i:p0 + j] = r
+                    uniq = tracker.begin(blk, fr)
+                stats.n_pixel_dedup += p1 - p0 - len(uniq)
+            if gate is not None:
+                # gate hits become duplicates rooted at a ring entry (a
+                # CNN-bound object); misses join the ring
+                with spans.span("ingest.gate"):
+                    g = gate.replay(blk, fr, uniq, r[uniq], self._pace)
+                hit = g >= 0
+                r[uniq[hit]] = g[hit]
+                stats.n_gate_skipped += int(hit.sum())
             if tracker is not None:
-                tracker.end()
+                with spans.span("ingest.track"):
+                    r[:] = tracker.settle(r)
             with spans.span("ingest.match"):
                 self._commit(blk)
-        return roots
-
-    def _gate_group(self, f: int, i: int, roots: np.ndarray,
-                    ids: np.ndarray, blk: _Block,
-                    stats: IngestStats) -> np.ndarray:
-        """Run one frame-``f`` group's (block rows ``i:``) tracker-unique
-        crops through the redundancy gate; returns the (possibly
-        rewritten) roots. Gate hits become duplicates rooted at a ring
-        entry (a CNN-bound object), misses are admitted as future ring
-        entries."""
-        uniq = np.flatnonzero(roots == ids)
-        groots = self.gate.match(f, i + uniq, blk)
-        hit = groots >= 0
-        if hit.any():
-            roots = roots.copy()
-            roots[uniq[hit]] = groots[hit]
-            stats.n_gate_skipped += int(hit.sum())
-            uniq = uniq[~hit]
-        self.gate.admit(blk.cols(i + uniq), ids[uniq])
         return roots
 
     def _commit(self, blk: _Block):
@@ -836,9 +926,11 @@ class StreamingIngestor:
                 roots = self._matcher.resolve(crops, frames, obj_ids,
                                               self.stats)
                 uniq = roots == obj_ids
-                self._buffer_unique(crops[uniq], obj_ids[uniq],
-                                    frames[uniq])
-                if not uniq.all():
+                if uniq.all():
+                    self._buffer_unique(crops, obj_ids, frames)
+                else:
+                    self._buffer_unique(crops[uniq], obj_ids[uniq],
+                                        frames[uniq])
                     dup = ~uniq
                     self._dup_objs.append(obj_ids[dup])
                     self._dup_frames.append(frames[dup])

@@ -1,12 +1,15 @@
 """The pixel tracker and the redundancy gate decide from one distance block
-per segment, then replay the per-frame decisions on the host
+per segment, then replay the decisions on the host a block at a time
 (``core.streaming._FrameMatcher``). Pinned here against a per-frame
 oracle kept in this file — the tracker and the gate as they were, one
 ``match_flat`` per frame group — on both backends (the kernel one runs
 interpreted on the CPU, with the row store on the device), at several
-chunkings, across shard rollovers, and at the edges of the rule: a
+chunkings, across shard rollovers, on a busy stream and on a static one
+where the gate fires in most frames, and at the edges of the rule: a
 distance exactly at the threshold, two equally near ring rows, a frame
-group larger than the ring and than the row store."""
+group larger than the ring and than the row store, a block's first gate
+hit at its edges, and a crop whose only match is a crop the gate
+drops."""
 import os
 import tempfile
 
@@ -163,6 +166,18 @@ def _zoo(n_frames=300, res=8):
     return crops, frames
 
 
+def _static(n_frames=150, n_base=12, res=8):
+    """``benchmarks/fig12_frame_sampling``'s static camera: object k shows
+    on frames with ``(f + k) % 3 == 0`` as an exact copy of its base crop,
+    never on consecutive frames, so the gate fires in most frames and the
+    tracker in none."""
+    base = np.random.default_rng(0).random((n_base, res, res, 3)
+                                           ).astype(np.float32)
+    f, k = np.nonzero((np.arange(n_frames)[:, None]
+                       + np.arange(n_base)[None, :]) % 3 == 0)
+    return base[k], f.astype(np.int64)
+
+
 _CFG = dict(K=2, threshold=1.5, max_clusters=64, batch_size=32,
             high_water=0.8, evict_frac=0.5, gate=True, gate_threshold=0.05,
             gate_capacity=24)
@@ -214,6 +229,7 @@ def _assert_same(a, b):
 # replay == per-frame oracle
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("stream", ["zoo", "static"])
 @pytest.mark.parametrize("chunk,shard_objects", [
     (10_000, None),     # one segment, cut into 256-crop blocks
     (97, None),         # frames split across feeds
@@ -221,8 +237,9 @@ def _assert_same(a, b):
     (61, 120),          # segments cut at shard boundaries mid-feed
     (300, 200),         # blocks and shard cuts inside one feed
 ])
-def test_replay_equals_per_frame_oracle(backend, chunk, shard_objects):
-    crops, frames = _zoo()
+def test_replay_equals_per_frame_oracle(backend, stream, chunk,
+                                        shard_objects):
+    crops, frames = _zoo() if stream == "zoo" else _static()
     cfg = IngestConfig(**_CFG)
     with tempfile.TemporaryDirectory() as d:
         got = _run(crops, frames, cfg, chunk, False, backend,
@@ -230,7 +247,11 @@ def test_replay_equals_per_frame_oracle(backend, chunk, shard_objects):
         want = _run(crops, frames, cfg, chunk, True, backend,
                     os.path.join(d, "o"), shard_objects)
     _assert_same(got, want)
-    assert got[1].n_gate_skipped > 0 and got[1].n_pixel_dedup > 0
+    if stream == "zoo":
+        assert got[1].n_gate_skipped > 0 and got[1].n_pixel_dedup > 0
+    else:
+        # the gate fires in most frames
+        assert got[1].n_gate_skipped > len(crops) // 2
     if shard_objects is not None:
         assert len(got[2]) > 1
 
@@ -303,6 +324,63 @@ def test_ring_trims_whole_groups_down_to_capacity(backend):
                             gate=True, gate_threshold=0.05, gate_capacity=4)
     np.testing.assert_array_equal(roots, [0, 1, 2, 3, 4, 5, 6, 2])
     assert stats.n_gate_skipped == 1
+
+
+def _matcher_and_oracle(crops, frames, cuts, backend, **cfg):
+    """Roots and stats of the replay and of the oracle, fed the segments
+    ``cuts`` (row offsets) of one stream."""
+    c = IngestConfig(**dict(_CFG, **cfg))
+    out = []
+    for m in (S._FrameMatcher(c.pixel_diff_threshold if c.pixel_diff
+                              else None, c.gate_threshold, c.gate_capacity),
+              _Oracle(c, backend)):
+        stats = IngestStats()
+        roots = [m.resolve(crops[a:b], frames[a:b], np.arange(a, b), stats)
+                 for a, b in zip(cuts, cuts[1:])]
+        out.append((np.concatenate(roots), stats))
+    (got, sg), (want, sw) = out
+    np.testing.assert_array_equal(got, want)
+    assert (sg.n_pixel_dedup, sg.n_gate_skipped) == (sw.n_pixel_dedup,
+                                                     sw.n_gate_skipped)
+    return got, sg
+
+
+@pytest.mark.parametrize("where", ["first", "last", "split_before",
+                                   "split_after"])
+def test_first_gate_hit_at_a_block_edge(backend, where):
+    """Frames of three distinct crops; one crop copies a crop of two frames
+    back, so the gate (and not the tracker) takes it. The copy is a
+    block's first gate hit in that block's first frame group, in its last,
+    or in a frame group the 256-crop block boundary splits (before the
+    boundary, or after it, where the group continues the open one)."""
+    r = np.random.default_rng(7)
+    n_frames = 100                                    # rows 0..299
+    crops = r.random((3 * n_frames, 4, 4, 3)).astype(np.float32)
+    frames = np.repeat(np.arange(n_frames), 3)
+    cuts = [0, 3 * n_frames]
+    if where in ("first", "last"):
+        cuts = [0, 150, 3 * n_frames]
+        row = 150 if where == "first" else 3 * n_frames - 1
+    else:
+        # frame 85 is rows 255..257: the first block ends after row 255
+        row = 255 if where == "split_before" else 257
+    crops[row] = crops[row - 6]                      # two frames back
+    roots, stats = _matcher_and_oracle(crops, frames, cuts, backend,
+                                       gate_capacity=64)
+    assert stats.n_gate_skipped == 1 and roots[row] == row - 6
+    assert stats.n_pixel_dedup == 0
+
+
+def test_crop_whose_only_match_the_gate_drops(backend):
+    """Frame 2's crop (0.04) is within 0.05 of frame 0's (0.0), so the gate
+    takes it and never admits it; frame 4's (0.08) is within 0.05 of
+    frame 2's alone, so it must miss. Frame 6's (0.12) then matches frame
+    4's, which the gate admitted."""
+    roots, stats = _matcher_and_oracle(
+        _flat_crops([0.0, 0.04, 0.08, 0.12]), np.array([0, 2, 4, 6]),
+        [0, 4], backend, pixel_diff=False)
+    np.testing.assert_array_equal(roots, [0, 0, 2, 2])
+    assert stats.n_gate_skipped == 2
 
 
 @pytest.mark.parametrize("n_group", [40, 300])

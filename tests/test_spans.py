@@ -14,7 +14,7 @@ import pytest
 from repro.common import spans
 from repro.core.archive import ShardCatalog
 from repro.core.index import saved_file_bytes
-from repro.core.ingest import IngestConfig
+from repro.core.ingest import IngestConfig, IngestStats
 from repro.core.pipeline import ShardedIngestPipeline, staged_cheap_apply
 from repro.core.streaming import StreamingIngestor, StreamPlacement
 from repro.data.video import get_stream
@@ -184,12 +184,55 @@ def test_match_counters_equal_hand_count(monkeypatch):
     assert len(blocks) > len(segments) >= 4
     assert ing.stats.n_gate_skipped + ing.stats.n_pixel_dedup > 0
     got = spans.snapshot()
-    assert got["counters"] == {
+    counters = dict(got["counters"])
+    # the gate replay passes at least once over every block
+    assert counters.pop("match.passes") >= len(blocks)
+    assert counters == {
         "match.calls": len(blocks),
         "match.bytes": sum(4 * d * _bucket(n) for n, d in blocks)}
     assert {"ingest.frames", "ingest.match", "ingest.track", "ingest.gate",
             "ingest.megastep", "ingest.fold",
             "ingest.publish"} <= set(got["spans"])
+
+
+def _passes(matcher, frames, values):
+    """``match.passes`` of one segment of one crop a frame, each crop a
+    constant ``values[i]``, its id its frame."""
+    crops = np.stack([np.full((4, 4, 3), v, np.float32) for v in values])
+    frames = np.asarray(frames, np.int64)
+    spans.reset()
+    spans.enable()
+    stats = IngestStats()
+    matcher.resolve(crops, frames, frames.copy(), stats)
+    spans.disable()
+    return spans.snapshot()["counters"]["match.passes"], stats
+
+
+def test_match_passes_equal_hand_count():
+    """``match.passes`` counts the gate replay's passes over its blocks. A
+    pass covers a window of frame groups that starts at one, doubles after
+    a pass whose assumption held and halves after one that failed; it
+    assumes each crop admitted (no gate hit) unless an earlier pass
+    decided otherwise. Crops 0.1 apart never match (thresholds 0.02 and
+    0.05)."""
+    import repro.core.streaming as S
+    m = S._FrameMatcher(0.02, 0.05, 64)
+    # hit-free, ten frame groups: windows of 1, 2, 4 and 3 (of 8) groups
+    passes, stats = _passes(m, range(10), 0.1 * np.arange(10))
+    assert passes == 4 and stats.n_gate_skipped == 0
+    # the window (16) now covers a block: one pass a block
+    for s0 in (10, 20):
+        passes, _ = _passes(m, range(s0, s0 + 10),
+                            0.1 * np.arange(s0, s0 + 10))
+        assert passes == 1
+    # frame 32 copies frame 30 (a gate hit the first pass did not assume,
+    # so a second pass starts at frame 33) and frame 36 copies frame 31
+    # (foreseen by the first pass: no third); frame 37's crop lies 0.04
+    # from frame 35's alone, which the gate takes (0.04 from frame 34's),
+    # so frame 37 misses where the second pass assumed a hit: a third pass
+    values = [3.0, 4.0, 3.0, 5.0, 6.0, 6.04, 4.0, 6.08, 7.0, 8.0]
+    passes, stats = _passes(m, range(30, 40), values)
+    assert passes == 3 and stats.n_gate_skipped == 3
 
 
 def _sharded_ingest(crops, frames, root):
