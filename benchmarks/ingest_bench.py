@@ -1,17 +1,19 @@
-"""Ingest throughput: driver-only (clustering variants) and end-to-end.
+"""Ingest throughput: driver-only and end-to-end.
 
 Two sections, one BENCH_ingest.json record:
 
-* ``variants`` — the ``ingest()`` driver hot path (clustering, slot ->
-  cid bookkeeping, SoA ClusterStore updates, eviction) with a precomputed
-  cheap-CNN stub, isolating the driver from CNN compute exactly as the
-  paper pipelines clustering (CPU) behind the CNN (GPU) in §6.3.
+* ``variants`` — the ``ingest()`` driver hot path (fused clustering,
+  slot -> cid bookkeeping, SoA ClusterStore updates, eviction) with a
+  precomputed cheap-CNN stub, isolating the driver from CNN compute
+  exactly as the paper pipelines clustering (CPU) behind the CNN (GPU)
+  in §6.3.
 * ``e2e`` — crops in -> index rows out with a REAL cheap CNN, comparing
-  the host-staged path (jitted forward, numpy round-trips between CNN /
-  top-K / clustering) against the fused ``IngestPipeline`` megastep
-  (DESIGN.md §9). Reports objects/sec for both, the fused path's device
-  dispatches per batch, its compile-cache hit/miss counts, and whether
-  the two paths saved byte-identical indexes — all gated in CI.
+  the host-staged path (jitted forward, numpy round-trips between CNN and
+  clustering) against the fused megastep on a one-slot
+  ``ShardedIngestPipeline`` (DESIGN.md §9). Reports objects/sec for both,
+  the fused path's device dispatches per batch, its compile-cache
+  hit/miss counts, and whether the two paths saved byte-identical
+  indexes — all gated in CI.
 """
 from __future__ import annotations
 
@@ -71,23 +73,21 @@ def run():
 
     record = {"ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
               "n_objects": N_OBJECTS, "variants": {}}
-    for variant in ("scan", "batched", "fused"):
-        cfg = IngestConfig(K=4, threshold=1.0, max_clusters=MAX_CLUSTERS,
-                           batch_size=2048, pixel_diff=False,
-                           clustering=variant)
-        # warmup run: compile everything, then measure a fresh run
-        ingest(crops, frames, make_apply(), 1e9, cfg)
-        t0 = time.perf_counter()
-        index, stats = ingest(crops, frames, make_apply(), 1e9, cfg)
-        wall = time.perf_counter() - t0
-        objs_per_s = N_OBJECTS / wall
-        record["variants"][variant] = {
-            "objects_per_sec": round(objs_per_s, 1),
-            "wall_s": round(wall, 4),
-            "n_clusters": index.n_clusters,
-        }
-        emit(f"ingest.{variant}.{N_OBJECTS}x{FEAT_DIM}", wall * 1e6,
-             f"objs_per_s={objs_per_s:.0f}|n_clusters={index.n_clusters}")
+    cfg = IngestConfig(K=4, threshold=1.0, max_clusters=MAX_CLUSTERS,
+                       batch_size=2048, pixel_diff=False)
+    # warmup run: compile everything, then measure a fresh run
+    ingest(crops, frames, make_apply(), 1e9, cfg)
+    t0 = time.perf_counter()
+    index, stats = ingest(crops, frames, make_apply(), 1e9, cfg)
+    wall = time.perf_counter() - t0
+    objs_per_s = N_OBJECTS / wall
+    record["variants"]["fused"] = {
+        "objects_per_sec": round(objs_per_s, 1),
+        "wall_s": round(wall, 4),
+        "n_clusters": index.n_clusters,
+    }
+    emit(f"ingest.fused.{N_OBJECTS}x{FEAT_DIM}", wall * 1e6,
+         f"objs_per_s={objs_per_s:.0f}|n_clusters={index.n_clusters}")
     record["e2e"] = run_e2e()
     append_trajectory(BENCH_PATH, record)
 
@@ -107,21 +107,18 @@ def _e2e_stream(seed: int = 1):
 def run_e2e() -> dict:
     """Crops -> index rows, host-staged vs fused-megastep pipeline.
 
-    Both paths produce the same artifacts: the saved index AND the
-    per-object top-K classes (the staged path runs the top-K kernel as
-    its own dispatch with a host round-trip, exactly the staging the
-    megastep removes). Gated timings are the median over ``E2E_REPS``
+    Both paths produce the same artifact, the saved index. Gated timings
+    are the median over ``E2E_REPS``
     interleaved runs — wall noise in this container swamps a single
     measurement, and a min is hostage to one lucky rep of either path
     (``best_speedup`` reports the min-based ratio for reference).
     """
     import jax
-    import jax.numpy as jnp
 
     from repro.common.config import CheapCNNConfig
-    from repro.core.pipeline import IngestPipeline, staged_cheap_apply
+    from repro.core.pipeline import ShardedIngestPipeline, staged_cheap_apply
     from repro.core.streaming import StreamingIngestor
-    from repro.kernels import ops as kops
+    from repro.launch.mesh import make_ingest_mesh
     from repro.models import cnn
 
     cnn_cfg = CheapCNNConfig("bench_e2e", input_res=E2E_RES, n_blocks=3,
@@ -137,30 +134,18 @@ def run_e2e() -> dict:
     flops = float(cnn.flops_per_image(cnn_cfg))
     crops, frames = _e2e_stream()
 
+    mesh = make_ingest_mesh(1)
+
     def run_staged():
-        base = staged_cheap_apply(cheap_fn, cfg)
-        topk_out = []
-
-        def apply(batch):
-            probs, feats = base(batch)
-            vals, idxs = kops.topk(jnp.asarray(probs), cfg.K)
-            # focuslint: disable=host-sync -- bench records top-K on
-            # host; the sync is the measured staged-path cost
-            topk_out.append((np.asarray(vals), np.asarray(idxs)))
-            return probs, feats
-
-        ing = StreamingIngestor(apply, flops, cfg)
+        ing = StreamingIngestor(staged_cheap_apply(cheap_fn, cfg), flops, cfg)
         for s in range(0, len(crops), 4 * E2E_BATCH):
             ing.feed(crops[s:s + 4 * E2E_BATCH],
                      frames[s:s + 4 * E2E_BATCH])
         return ing.finish()[0], None
 
     def run_pipeline():
-        topk_out = []
-        pipe = IngestPipeline(
-            cheap_fn, cfg,
-            topk_sink=lambda objs, vals, idxs: topk_out.append((vals, idxs)))
-        ing = StreamingIngestor(None, flops, cfg, pipeline=pipe)
+        pipe = ShardedIngestPipeline(cheap_fn, mesh, ["s"], cfg=cfg)
+        ing = StreamingIngestor(None, flops, cfg, pipeline=pipe.handle("s"))
         for s in range(0, len(crops), 4 * E2E_BATCH):
             ing.feed(crops[s:s + 4 * E2E_BATCH],
                      frames[s:s + 4 * E2E_BATCH])

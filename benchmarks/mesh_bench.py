@@ -3,11 +3,11 @@
 Eight camera streams ingest through ONE ``ShardedIngestPipeline`` over a
 1/2/4/8-device ``("data",)`` mesh (simulated host devices — this bench
 exports ``--xla_force_host_platform_device_count=8`` before the first
-jax import) and are compared against the pre-refactor multi-stream
-deployment (PR 3): a staged ``MultiStreamRunner`` stacking ready batches
-through one shared cheap-CNN executable with host-side clustering per
-per-stream batch. A second reference row runs each stream's own fused
-``IngestPipeline`` chain back to back (the PR-5 single-stream path).
+jax import) and are compared against the host-staged baseline: each
+stream's own ``StreamingIngestor`` over a staged cheap-CNN forward with
+host-side clustering per batch, streams run round-robin on one device.
+A second reference row runs each stream on its own one-slot sharded
+pipeline, back to back (S separate dispatch chains).
 
 Reported per row: objects/sec, device dispatches per per-stream batch,
 and stacked steps. Honest scaling note: the container's forced host
@@ -20,9 +20,9 @@ top.
 
 Gates (CI):
   * identity: every sharded row saves byte-identical per-stream indexes
-    (and equal eviction counts) to the single-device references;
-  * speedup: sharded @ 4 devices >= 1.5x the pre-refactor staged
-    baseline's objects/sec.
+    (and equal eviction counts) to the host-staged references;
+  * speedup: sharded @ 4 devices >= 1.5x the host-staged baseline's
+    objects/sec.
 
 One record per run is appended to the BENCH_mesh.json trajectory.
 """
@@ -44,9 +44,8 @@ import jax
 
 from benchmarks.common import append_trajectory, emit
 from repro.core.ingest import IngestConfig
-from repro.core.pipeline import IngestPipeline, staged_cheap_apply
-from repro.core.streaming import (MultiStreamRunner, StreamingIngestor,
-                                  make_sharded_runner)
+from repro.core.pipeline import staged_cheap_apply
+from repro.core.streaming import StreamingIngestor, make_sharded_runner
 from repro.launch.mesh import make_ingest_mesh
 
 BENCH_PATH = os.path.join(os.path.dirname(__file__), "..",
@@ -94,28 +93,10 @@ def _rounds(streams):
 
 
 def run_staged_baseline(streams):
-    """Pre-refactor multi-stream deployment (PR 3): staged runner, one
-    stacked cheap-CNN pass per step, host clustering per stream batch."""
-    ings = {nm: StreamingIngestor(None, 1e9, CFG) for nm in streams}
-    runner = MultiStreamRunner(ings,
-                               cheap_apply=staged_cheap_apply(_cheap_fn,
-                                                              CFG),
-                               batch_pad=BATCH)
-    t0 = time.perf_counter()
-    for feeds in _rounds(streams):
-        runner.feed(feeds)
-    out = runner.finish()
-    wall = time.perf_counter() - t0
-    n_batch = sum(ing.stats.n_cnn_invocations // BATCH
-                  for ing in ings.values())
-    return out, wall, n_batch
-
-
-def run_per_stream_pipeline(streams):
-    """PR-5 single-stream fused path, streams run round-robin on one
-    device: S separate dispatch chains."""
-    ings = {nm: StreamingIngestor(None, 1e9, CFG,
-                                  pipeline=IngestPipeline(_cheap_fn, CFG))
+    """Host-staged baseline: each stream's own ingestor, one cheap-CNN
+    forward and a host clustering fold per stream batch."""
+    ings = {nm: StreamingIngestor(staged_cheap_apply(_cheap_fn, CFG), 1e9,
+                                  CFG)
             for nm in streams}
     t0 = time.perf_counter()
     for feeds in _rounds(streams):
@@ -123,8 +104,26 @@ def run_per_stream_pipeline(streams):
             ings[nm].feed(c, f)
     out = {nm: ing.finish() for nm, ing in ings.items()}
     wall = time.perf_counter() - t0
-    n_disp = sum(ing.pipeline.stats.n_dispatches for ing in ings.values())
-    n_batch = sum(ing.pipeline.stats.n_batches for ing in ings.values())
+    n_batch = sum(ing.stats.n_cnn_invocations // BATCH
+                  for ing in ings.values())
+    return out, wall, n_batch
+
+
+def run_per_stream_pipeline(streams):
+    """Each stream on its own one-slot sharded pipeline, streams run
+    round-robin on one device: S separate dispatch chains."""
+    mesh = make_ingest_mesh(1)
+    runners = {nm: make_sharded_runner(_cheap_fn, mesh, [nm], cfg=CFG,
+                                       cheap_flops_per_image=1e9)
+               for nm in streams}
+    t0 = time.perf_counter()
+    for feeds in _rounds(streams):
+        for nm, fd in feeds.items():
+            runners[nm].feed({nm: fd})
+    out = {nm: r.finish()[nm] for nm, r in runners.items()}
+    wall = time.perf_counter() - t0
+    n_disp = sum(r.pipeline.stats.n_dispatches for r in runners.values())
+    n_batch = sum(r.pipeline.stats.n_batches for r in runners.values())
     return out, wall, n_disp / max(n_batch, 1)
 
 
@@ -149,7 +148,7 @@ def run():
               "objects_per_stream": N_OBJECTS, "batch_size": BATCH,
               "devices_visible": avail, "rows": []}
 
-    # pre-refactor staged baseline (the speedup reference)
+    # host-staged baseline (the speedup and identity reference)
     walls = []
     for _ in range(REPS):
         ref_out, wall, n_batch = run_staged_baseline(streams)
@@ -157,11 +156,11 @@ def run():
     wall = float(np.median(walls))
     base_rate = total / wall
     emit("mesh.baseline_staged_1dev", wall * 1e6 / max(n_batch, 1),
-         f"objs_per_s={base_rate:.0f}|mode=pre_refactor_staged_runner")
+         f"objs_per_s={base_rate:.0f}|mode=host_staged_per_stream")
     record["rows"].append({"mode": "staged_baseline", "devices": 1,
                            "objs_per_s": base_rate})
 
-    # PR-5 per-stream fused chains (identity reference + context row)
+    # per-stream one-slot fused chains (context row)
     walls = []
     for _ in range(REPS):
         pipe_out, wall, dpb = run_per_stream_pipeline(streams)
@@ -215,7 +214,7 @@ def run():
     if 4 in rates:
         speedup = rates[4] / base_rate
         assert speedup >= 1.5, (
-            f"sharded@4dev only {speedup:.2f}x the single-device baseline "
+            f"sharded@4dev only {speedup:.2f}x the host-staged baseline "
             f"(gate: >= 1.5x)")
         record["gate_speedup_4dev"] = speedup
     append_trajectory(BENCH_PATH, record)

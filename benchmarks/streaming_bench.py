@@ -1,17 +1,18 @@
 """Multi-stream streaming ingest with query-while-ingest (paper §5 shape).
 
 Two camera streams are fed chunk by chunk through a ``MultiStreamRunner``
-(one shared stacked cheap-CNN executable); between chunks a long-lived
-``QueryEngine`` per stream prefetches the flush delta and answers the
-dominant-class workload warm. Reported per run:
+(``make_sharded_runner``: one shared sharded megastep over a one-device
+mesh, one stacked step per round of ready batches); between chunks a
+long-lived ``QueryEngine`` per stream prefetches the flush delta and
+answers the dominant-class workload warm. Reported per run:
 
   * interleaved multi-stream ingest throughput (objects/sec),
   * query freshness latency: wall time from "chunk fed" to "warm queries
     answered on the updated index" (flush + prefetch + query_many),
   * correctness gates: every interleaved round returns frames identical
     to a fresh (cache-less) engine on the same index snapshot, and the
-    final per-stream index is byte-identical to a one-shot ``ingest()``
-    of the same stream.
+    final per-stream index is byte-identical to a one-shot host-staged
+    ``ingest()`` of the same stream.
 
 One record per run is appended to the BENCH_streaming.json trajectory so
 future streaming-path PRs are measured against this one.
@@ -21,12 +22,16 @@ from __future__ import annotations
 import os
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import append_trajectory, emit
 from repro.core.engine import QueryEngine
 from repro.core.ingest import IngestConfig, ingest
-from repro.core.streaming import MultiStreamRunner, StreamingIngestor
+from repro.core.pipeline import staged_cheap_apply
+from repro.core.streaming import make_sharded_runner
+from repro.launch.mesh import make_ingest_mesh
 
 BENCH_PATH = os.path.join(os.path.dirname(__file__), "..",
                           "BENCH_streaming.json")
@@ -62,15 +67,16 @@ def _make_stream(seed: int):
     return crops, frames
 
 
-def _cheap(batch):
-    """Per-example-pure cheap-CNN stub (stacked and stream-private batches
-    give identical per-object outputs, as a jitted inference CNN does)."""
-    flat = batch.reshape(len(batch), -1)
-    feats = (flat[:, :FEAT_DIM] * 8.0).astype(np.float32)
-    probs = np.abs(flat[:, FEAT_DIM:FEAT_DIM + N_CLASSES]) + 1e-3
-    probs[np.arange(len(batch)),
-          np.rint(batch[:, 0, 0, 0] * N_CLASSES).astype(int) % N_CLASSES] += 2.0
-    return (probs / probs.sum(1, keepdims=True)).astype(np.float32), feats
+def _cheap_fn(crops):
+    """Jax-traceable, per-example-pure cheap-CNN stub (stacked and
+    stream-private batches give identical per-object outputs, as a jitted
+    inference CNN does); the true class gets a +2 logit bump."""
+    flat = crops.reshape(crops.shape[0], -1)
+    feats = flat[:, :FEAT_DIM] * 8.0
+    cls = jnp.rint(crops[:, 0, 0, 0] * N_CLASSES).astype(jnp.int32)
+    probs = (jnp.abs(flat[:, FEAT_DIM:FEAT_DIM + N_CLASSES]) + 1e-3
+             + 2.0 * jax.nn.one_hot(cls % N_CLASSES, N_CLASSES))
+    return probs / probs.sum(1, keepdims=True), feats
 
 
 def _gt_apply(batch):
@@ -88,9 +94,10 @@ def run():
                        batch_size=BATCH, high_water=0.9, evict_frac=0.25)
     workload = list(range(N_CLASSES))
 
-    runner = MultiStreamRunner(
-        {nm: StreamingIngestor(None, 1e9, cfg, n_local_classes=N_CLASSES)
-         for nm in streams}, _cheap)
+    runner = make_sharded_runner(_cheap_fn, make_ingest_mesh(1),
+                                 list(streams), cfg=cfg,
+                                 cheap_flops_per_image=1e9,
+                                 n_local_classes=N_CLASSES)
     engines = {nm: QueryEngine(runner.ingestors[nm].index,
                                gt_apply=_gt_apply,
                                gt_flops_per_image=GT_FLOPS)
@@ -139,8 +146,8 @@ def run():
     posthoc_identical = True
     for nm, (c, f) in streams.items():
         idx, stats = finished[nm]
-        one_index, _ = ingest(c, f, _cheap, 1e9, cfg,
-                              n_local_classes=N_CLASSES)
+        one_index, _ = ingest(c, f, staged_cheap_apply(_cheap_fn, cfg), 1e9,
+                              cfg, n_local_classes=N_CLASSES)
         if _bytes_of(idx, nm) != _bytes_of(one_index, nm + "_one"):
             oneshot_identical = False
         # interleaved final answers == post-hoc answers on the final index

@@ -11,8 +11,9 @@ lazy == eager), and one vit-l16 GT pass at its published widths
 classifies the archive's candidate crops through ``classify_crops``.
 
 ``--chips 4``: eight zoo streams ingest through ``ShardedIngestPipeline``
-over a 4-device mesh, each stream byte-compared with its single-device
-run, and the devices holding each stream's cluster table are printed.
+over a 4-device mesh, each stream byte-compared with its own run on a
+one-device mesh, and the devices holding each stream's cluster table
+are printed.
 
 Every phase prints its wall time and how many programs it compiled. The
 last line is one JSON object, ``{"ok": ..., "device": {...}}``. With no
@@ -119,8 +120,7 @@ def identity_phase(checks):
     from benchmarks.common import get_model, load_stream
     from repro.core.archive import ShardCatalog
     from repro.core.ingest import IngestConfig
-    from repro.core.pipeline import (IngestPipeline, ShardedIngestPipeline,
-                                     staged_cheap_apply)
+    from repro.core.pipeline import ShardedIngestPipeline, staged_cheap_apply
     from repro.core.streaming import StreamingIngestor
     from repro.launch.mesh import make_ingest_mesh
 
@@ -136,10 +136,7 @@ def identity_phase(checks):
         "staged": StreamingIngestor(staged_cheap_apply(fwd, cfg), flops,
                                     cfg, class_map=cmap),
         "megastep": StreamingIngestor(None, flops, cfg, class_map=cmap,
-                                      pipeline=IngestPipeline(fwd, cfg)),
-        "sharded_megastep": StreamingIngestor(
-            None, flops, cfg, class_map=cmap,
-            pipeline=sharded.handle(STREAM)),
+                                      pipeline=sharded.handle(STREAM)),
     }
     saved, indexes = {}, {}
     for name, ing in runs.items():
@@ -154,11 +151,10 @@ def identity_phase(checks):
               f"{stats.n_cnn_invocations} CNN rows, {stats.n_gate_skipped} "
               f"gate hits, {index.n_clusters} clusters in "
               f"{time.perf_counter() - t0:.2f} s", flush=True)
-    for name in ("megastep", "sharded_megastep"):
-        check(checks, f"{name}_equals_staged",
-              saved[name] == saved["staged"],
-              f"({_nbytes(saved[name])} vs {_nbytes(saved['staged'])} "
-              f"bytes saved)")
+    check(checks, "megastep_equals_staged",
+          saved["megastep"] == saved["staged"],
+          f"({_nbytes(saved['megastep'])} vs {_nbytes(saved['staged'])} "
+          f"bytes saved)")
     # serve's §4.4 selection may pick K = 1, which would leave the later
     # passes of dequant_topk's extraction loop unchecked on the chip
     k4 = ShardCatalog(os.path.join(WORK, "k4_archive"))
@@ -279,7 +275,7 @@ def one_chip(compiles):
 # ---------------------------------------------------------------------------
 
 def four_chips(compiles, n_devices):
-    """Sharded multi-stream ingest vs single-device ingest, per stream."""
+    """Sharded multi-stream ingest vs one-device ingest, per stream."""
     checks = {}
     names = []
 
@@ -288,7 +284,7 @@ def four_chips(compiles, n_devices):
 
         from benchmarks.common import SPECIALIZED_FAMILY, load_stream
         from repro.core.ingest import IngestConfig
-        from repro.core.pipeline import IngestPipeline
+        from repro.core.pipeline import ShardedIngestPipeline
         from repro.core.streaming import StreamingIngestor, make_sharded_runner
         from repro.data.video import STREAM_ZOO
         from repro.launch.mesh import make_ingest_mesh
@@ -340,9 +336,11 @@ def four_chips(compiles, n_devices):
               f"({len(used)} of {mesh.size} devices hold stream state)")
 
         t0 = time.perf_counter()
+        one = make_ingest_mesh(1)
         for nm, (crops, frames) in streams.items():
             ref = StreamingIngestor(None, 1e9, cfg,
-                                    pipeline=IngestPipeline(fwd, cfg))
+                                    pipeline=ShardedIngestPipeline(
+                                        fwd, one, [nm], cfg=cfg).handle(nm))
             for lo, hi in _chunks(len(crops)):
                 ref.feed(crops[lo:hi], frames[lo:hi])
             want, _ = ref.finish()

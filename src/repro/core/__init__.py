@@ -22,7 +22,6 @@ from repro.core.archive import (  # noqa: F401
 )
 from repro.core.ingest import IngestConfig, IngestStats, ingest  # noqa: F401
 from repro.core.pipeline import (  # noqa: F401
-    IngestPipeline,
     PipelineStats,
     batch_bucket,
     staged_cheap_apply,

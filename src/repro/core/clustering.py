@@ -7,35 +7,29 @@ cluster count is bounded by M; when the buffer fills, the *smallest*
 clusters are evicted to the top-K index (handled by the ingest driver
 between batches) — complexity stays O(M·n).
 
-Three implementations (DESIGN.md §3):
+Two implementations (DESIGN.md §3):
   * ``cluster_scan``   — canonical sequential semantics via lax.scan
                          (the oracle; exactly the paper's algorithm).
-  * ``cluster_batched``— TPU-adapted two-phase variant: the (B, M) distance
-                         matrix is computed in one MXU-friendly shot (Pallas
-                         kernel on TPU, jnp on CPU) against the *batch-start*
-                         centroid table; objects that match no existing
-                         centroid are resolved sequentially within the batch.
-                         This exposes the parallelism the paper's CPU loop
-                         lacks and is provably equivalent to ``cluster_scan``
-                         whenever batch objects join pre-existing clusters
-                         (the common case: consecutive frames of the same
-                         object).
-  * ``cluster_fused``  — the vectorized fast path: phase-1 matched objects
-                         fold into their centroids in ONE segment-sum shot
-                         (a batched running-mean update), and the sequential
-                         scan runs only over the gathered *unmatched*
-                         subsequence (typically a small fraction of the
-                         batch) before ids are scattered back. Equivalent to
-                         ``cluster_scan`` on the same inputs where
-                         ``cluster_batched`` is (assignment decisions stable
-                         under within-batch centroid drift): the final
-                         centroid of a fixed member set is its arithmetic
-                         mean, which is fold-order independent.
+  * ``cluster_fused``  — the path ingest runs. Phase 1 computes the (B, M)
+                         distances of the whole batch against the
+                         *batch-start* centroid table in one MXU-friendly
+                         shot (Pallas kernel) with the threshold fused in;
+                         matched objects fold into their centroids in ONE
+                         segment-sum shot (a batched running-mean update),
+                         and the sequential scan runs only over the
+                         gathered *unmatched* subsequence (typically a
+                         small fraction of the batch) before ids are
+                         scattered back. Equivalent to ``cluster_scan``
+                         whenever assignment decisions are stable under
+                         within-batch centroid drift (the common case:
+                         consecutive frames of the same object join
+                         pre-existing clusters): the final centroid of a
+                         fixed member set is its arithmetic mean, which is
+                         fold-order independent.
 """
 from __future__ import annotations
 
-import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -105,7 +99,7 @@ def cluster_scan(state: ClusterState, feats, threshold: float):
 
 
 # ---------------------------------------------------------------------------
-# TPU-adapted two-phase batched variant
+# Fused fast path: kernel phase 1, segment-sum fold + unmatched-only scan
 # ---------------------------------------------------------------------------
 
 @jax.jit
@@ -124,44 +118,6 @@ def _phase1(centroids, counts, n, feats, threshold):
                                           threshold=threshold)
     return j, matched
 
-
-def cluster_batched(state: ClusterState, feats, threshold: float):
-    """Two-phase batched clustering. Returns (state, ids (B,)).
-
-    Phase 1 (parallel, MXU): distances of the whole batch against the
-    batch-start centroids -> matched mask. Phase 2 (scan): matched objects
-    fold into their centroid; unmatched objects run the sequential rule so
-    within-batch new clusters behave exactly like ``cluster_scan``.
-    """
-    feats = jnp.asarray(feats, jnp.float32)
-    j, matched = _phase1(state.centroids, state.counts, state.n, feats,
-                         jnp.float32(threshold))
-    return _phase2(state, feats, j, matched, jnp.float32(threshold))
-
-
-@jax.jit
-def _phase2(state, feats, j, matched, threshold):
-    def step(st, inp):
-        f, jj, m = inp
-
-        def fold(st):
-            cnt = st.counts[jj] + 1
-            c = st.centroids[jj]
-            c = c + (f - c) / cnt
-            return ClusterState(st.centroids.at[jj].set(c),
-                                st.counts.at[jj].set(cnt), st.n), jj
-
-        def slow(st):
-            return _assign_one(st, f, threshold)
-
-        return lax.cond(m, fold, slow, st)
-
-    return lax.scan(step, state, (feats, j, matched))
-
-
-# ---------------------------------------------------------------------------
-# Fused fast path: segment-sum fold + unmatched-only scan
-# ---------------------------------------------------------------------------
 
 @jax.jit
 def _fold_matched(state: ClusterState, feats, j, matched):
@@ -214,8 +170,8 @@ def cluster_fused(state: ClusterState, feats, threshold: float):
     segment-sum (no scan step for them at all). Phase 2 (scan) runs ONLY
     over the gathered unmatched subsequence — length U << B in steady-state
     video — padded to a power-of-two bucket; ids are scattered back into
-    batch order. Equivalent to ``cluster_scan`` wherever ``cluster_batched``
-    is (see module docstring).
+    batch order. Equivalent to ``cluster_scan`` wherever assignment
+    decisions are stable under within-batch drift (see module docstring).
     """
     feats = jnp.asarray(feats, jnp.float32)
     B = feats.shape[0]
@@ -243,13 +199,6 @@ def cluster_fused(state: ClusterState, feats, threshold: float):
         # winner ids feed the host-side fold
         ids[unmatched_idx] = np.asarray(sub_ids)[:U]
     return state, jnp.asarray(ids)
-
-
-CLUSTER_FNS = {
-    "scan": cluster_scan,
-    "batched": cluster_batched,
-    "fused": cluster_fused,
-}
 
 
 # ---------------------------------------------------------------------------

@@ -34,7 +34,10 @@ class IngestConfig:
     pixel_diff_threshold: float = 0.02
     evict_frac: float = 0.25
     high_water: float = 0.95        # evict when n >= high_water * M
-    clustering: str = "fused"       # "scan" | "batched" | "fused"
+    # "fused" only (core.clustering.cluster_fused, the one path ingest
+    # runs). The field stays only because the benchmark's configs name
+    # it; it leaves once they drop the key (ROADMAP design debt C11)
+    clustering: str = "fused"
     # redundancy gate (DESIGN.md §10): match CNN-bound uniques against a
     # ring of recent uniques from earlier frames; hits skip the CNN and
     # attach to their ring root's cluster
@@ -44,6 +47,12 @@ class IngestConfig:
     # keep only frames with frame_id % frame_stride == 0 (absolute grid,
     # so the kept set is a function of the stream alone, never chunking)
     frame_stride: int = 1
+
+    def __post_init__(self):
+        if self.clustering != "fused":
+            raise ValueError(
+                f"clustering={self.clustering!r}: ingest clusters with "
+                f"'fused' only (cluster_scan is the test oracle)")
 
 
 @dataclass
@@ -84,8 +93,8 @@ def ingest(crops: np.ndarray, frames: np.ndarray,
            cheap_apply: Callable[[np.ndarray], Tuple[np.ndarray, np.ndarray]],
            cheap_flops_per_image: float, cfg: IngestConfig,
            class_map: Optional[ClassMap] = None,
-           n_local_classes: Optional[int] = None,
-           pipeline=None) -> Tuple[TopKIndex, IngestStats]:
+           n_local_classes: Optional[int] = None
+           ) -> Tuple[TopKIndex, IngestStats]:
     """Build the top-K index for a stream of detected objects — the
     one-shot (single-chunk) wrapper over ``streaming.StreamingIngestor``.
 
@@ -96,15 +105,10 @@ def ingest(crops: np.ndarray, frames: np.ndarray,
     streams — every stream here — that is exactly the array order the
     pre-streaming implementation used, and a chunked ``StreamingIngestor``
     run over the same stream saves a byte-identical index.
-
-    With ``pipeline`` (a ``core.pipeline.IngestPipeline``) the CNN +
-    clustering fast path runs as the fused device megastep instead of
-    host-staged ``cheap_apply`` calls; pass ``cheap_apply=None`` then.
     """
     from repro.core.streaming import StreamingIngestor
     ing = StreamingIngestor(cheap_apply, cheap_flops_per_image, cfg,
                             class_map=class_map,
-                            n_local_classes=n_local_classes,
-                            pipeline=pipeline)
+                            n_local_classes=n_local_classes)
     ing.feed(np.asarray(crops), np.asarray(frames, np.int64))
     return ing.finish()

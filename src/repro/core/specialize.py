@@ -63,10 +63,10 @@ class SpecializedModel:
 
     def make_traceable(self) -> Partial:
         """The bare jax-traceable forward ``crops -> (probs, feats)`` —
-        what a fused ``IngestPipeline``/``ShardedIngestPipeline`` inlines
-        into its megastep (``make_apply`` wraps the same computation in a
-        host pad/unpad boundary, which cannot be traced). A ``Partial``
-        over the parameters: the pipelines pass them to their programs as
+        what the fused ``ShardedIngestPipeline`` inlines into its megastep
+        (``make_apply`` wraps the same computation in a host pad/unpad
+        boundary, which cannot be traced). A ``Partial`` over the
+        parameters: the pipeline passes them to its programs as
         arguments, so the weights are not compiled in as constants."""
         return Partial(functools.partial(_probs_and_feats, self.cfg),
                        self.params)

@@ -5,8 +5,8 @@ while "after the fact" queries arrive mid-stream. ``StreamingIngestor``
 accepts chunked ``(crops, frames)`` feeds for one stream and maintains
 clustering state + the top-K index incrementally across calls — carrying
 ``slot_cid``, pixel-track roots, and eviction remaps over chunk
-boundaries. ``MultiStreamRunner`` round-robins N streams through one
-shared bucket-padded cheap-CNN executable.
+boundaries. ``MultiStreamRunner`` drives N streams through one shared
+``ShardedIngestPipeline``, one stacked megastep per step.
 
 Determinism contract (pinned by ``tests/test_streaming.py``): chunk
 boundaries are invisible. Unique objects are buffered and cut into CNN
@@ -642,11 +642,11 @@ class _ChunkBuffer:
 class StreamingIngestor:
     """Incremental Focus ingest for one stream, fed in chunks.
 
-    ``cheap_apply(crops (B,R,R,3)) -> (probs (B, C_local), feats (B, D))``
-    may be ``None`` when the ingestor is driven by a ``MultiStreamRunner``
-    (which supplies CNN outputs for stacked device batches) or when a
-    fused ``core.pipeline.IngestPipeline`` is given via ``pipeline=`` —
-    the pipeline then runs CNN forward + top-K + clustering as one
+    Exactly one CNN sink drives it. Either ``cheap_apply(crops (B,R,R,3))
+    -> (probs (B, C_local), feats (B, D))``, the host-staged reference
+    that clusters each batch on the host side of the call; or
+    ``pipeline=``, a ``core.pipeline.ShardedIngestPipeline`` slot handle
+    (``shared.handle(name)``), which runs CNN forward + clustering as one
     device-resident megastep and routes the host fold back through
     ``_fold_rows`` (DESIGN.md §9). ``feed`` / ``flush`` / ``finish`` are
     the lifecycle; ``ingest()`` in ``core.ingest`` is the single-chunk
@@ -660,8 +660,7 @@ class StreamingIngestor:
     reset. Object ids restart per shard, so every sealed shard is
     byte-identical to a one-shot ``ingest()`` of its window (the rollover
     invariant; ``ShardMeta.obj_base`` maps ids back to global positions).
-    ``finish()`` seals the tail shard. Rollover requires a self-driven
-    ingestor (``cheap_apply`` given): sealing must drain the tail batch.
+    ``finish()`` seals the tail shard.
     """
 
     def __init__(self, cheap_apply: Optional[Callable] = None,
@@ -672,10 +671,10 @@ class StreamingIngestor:
                  catalog=None, shard_objects: Optional[int] = None,
                  shard_frames: Optional[int] = None,
                  shard_format: Optional[int] = None, pipeline=None):
-        if pipeline is not None and cheap_apply is not None:
+        if (cheap_apply is None) == (pipeline is None):
             raise ValueError(
-                "pass either cheap_apply (host-staged) or pipeline "
-                "(fused megastep), not both")
+                "pass exactly one of cheap_apply (host-staged) or "
+                "pipeline (a ShardedIngestPipeline handle)")
         self.cheap_apply = cheap_apply
         self.cheap_flops_per_image = cheap_flops_per_image
         self.cfg = cfg if cfg is not None else IngestConfig()
@@ -683,10 +682,6 @@ class StreamingIngestor:
         self.n_local_classes = n_local_classes
         self.stats = IngestStats()
         self.pipeline = pipeline
-        if catalog is not None and cheap_apply is None and pipeline is None:
-            raise ValueError(
-                "shard rollover needs a self-driven ingestor (cheap_apply "
-                "or pipeline); runner-driven ingestors cannot seal")
         if catalog is None and (shard_objects is not None
                                 or shard_frames is not None):
             raise ValueError("shard_objects/shard_frames need a catalog")
@@ -706,12 +701,6 @@ class StreamingIngestor:
             # bind last: a constructor rejected above must not consume
             # the pipeline (binding is permanent per stream)
             pipeline._bind(self)
-        try:
-            self._cluster_fn = C.CLUSTER_FNS[self.cfg.clustering]
-        except KeyError:
-            raise ValueError(
-                f"unknown clustering variant {self.cfg.clustering!r}; "
-                f"expected one of {sorted(C.CLUSTER_FNS)}") from None
         # the index exists up front whenever the class width is known, so a
         # QueryEngine can bind to it before the first chunk arrives
         self._index: Optional[TopKIndex] = None
@@ -937,37 +926,32 @@ class StreamingIngestor:
                     self._dup_roots.append(roots[dup])
             else:
                 self._buffer_unique(crops, obj_ids, frames)
-        if self.cheap_apply is not None or self.pipeline is not None:
-            self._drain_ready()
+        self._drain_ready()
 
     def _buffer_unique(self, crops, obj_ids, frames):
         self._buf.append(crops, obj_ids, frames)
 
     def take_ready_batch(self):
-        """Pop one full CNN batch of buffered uniques (runner API)."""
-        b = self.cfg.batch_size
-        return self._take(b)
+        """Pop one CNN batch (up to ``batch_size`` buffered uniques);
+        empty arrays when nothing is buffered."""
+        return self._buf.take(self.cfg.batch_size)
 
     def take_tail(self):
-        """Pop the remaining partial batch (runner finish); empty arrays
-        when nothing is buffered."""
-        return self._take(len(self._buf))
-
-    def _take(self, k: int):
-        return self._buf.take(k)
+        """Pop the remaining partial batch; empty arrays when nothing is
+        buffered."""
+        return self._buf.take(len(self._buf))
 
     def _drain_ready(self):
-        if self.pipeline is not None:
-            # the pipeline double-buffers internally: each submit
-            # dispatches the megastep, then host-folds the previous batch
-            while self.n_ready_batches:
-                self.pipeline.submit(*self.take_ready_batch())
-            return
         while self.n_ready_batches:
-            self._staged_step(*self.take_ready_batch())
+            self._run_batch(*self.take_ready_batch())
 
-    def _staged_step(self, crops, objs, frames):
-        """Host-staged path: the cheap CNN's forward, then the fold."""
+    def _run_batch(self, crops, objs, frames):
+        """Fold one batch through this ingestor's CNN sink: the pipeline
+        handle, or the host-staged ``cheap_apply`` forward then the
+        fold."""
+        if self.pipeline is not None:
+            self.pipeline.submit(crops, objs, frames)
+            return
         with spans.span("ingest.megastep", self.stats):
             probs, feats = self.cheap_apply(crops)
         self.fold_batch(crops, objs, frames, probs, feats)
@@ -979,10 +963,10 @@ class StreamingIngestor:
                    feats: np.ndarray):
         """Fold one CNN batch of unique objects into clustering state and
         the index — the loop body of the old one-shot ``ingest()``, with
-        ``slot_cid`` / eviction remaps carried across calls. An
-        ``IngestPipeline`` computes clustering on-device instead and
-        enters below at ``_fold_rows`` with precomputed slots; the staged
-        clustering inside counts as ``ingest.megastep``.
+        ``slot_cid`` / eviction remaps carried across calls. A pipeline
+        handle computes clustering on-device instead and enters below at
+        ``_fold_rows`` with precomputed slots; the staged clustering
+        inside counts as ``ingest.megastep``.
         """
         with spans.span("ingest.fold", self.stats):
             probs = np.asarray(probs)
@@ -994,8 +978,8 @@ class StreamingIngestor:
                 self._state = C.init_state(self.cfg.max_clusters,
                                            feats.shape[1])
             with spans.span("ingest.megastep"):
-                state, slots = self._cluster_fn(self._state, feats,
-                                                self.cfg.threshold)
+                state, slots = C.cluster_fused(self._state, feats,
+                                               self.cfg.threshold)
                 self._state = state
                 # focuslint: disable=host-sync -- staged path folds on
                 # host per batch by design; the fused pipeline removes it
@@ -1072,7 +1056,7 @@ class StreamingIngestor:
             self._drain_ready()
             if len(self._buf):
                 crops, objs, frames = self.take_tail()
-                self._fold_tail(crops, objs, frames)
+                self._run_batch(crops, objs, frames)
             if self.pipeline is not None:
                 self.pipeline.flush_pending()
             if self._index is None:
@@ -1114,14 +1098,6 @@ class StreamingIngestor:
             if self.pipeline is not None:
                 self.pipeline.reset()
         return meta
-
-    def _fold_tail(self, crops, objs, frames):
-        """Fold a ragged tail batch through whichever CNN path drives this
-        ingestor (fused pipeline or host-staged cheap_apply)."""
-        if self.pipeline is not None:
-            self.pipeline.submit(crops, objs, frames)
-            return
-        self._staged_step(crops, objs, frames)
 
     # -- publication -----------------------------------------------------------
 
@@ -1209,16 +1185,10 @@ class StreamingIngestor:
                 self._index = self._empty_index()
             self._finished = True
             return self._index, self.stats
-        if self.cheap_apply is not None or self.pipeline is not None:
-            self._drain_ready()
+        self._drain_ready()
         if len(self._buf):
-            if self.cheap_apply is None and self.pipeline is None:
-                raise RuntimeError(
-                    "pending unique objects but no cheap_apply; a "
-                    "runner-driven ingestor must be finished through "
-                    "MultiStreamRunner.finish()")
             crops, objs, frames = self.take_tail()
-            self._fold_tail(crops, objs, frames)
+            self._run_batch(crops, objs, frames)
         if self.pipeline is not None:
             self.pipeline.flush_pending()
         if self._index is None:          # empty stream: class width from the
@@ -1280,78 +1250,33 @@ class StreamPlacement:
 
 
 class MultiStreamRunner:
-    """Round-robins N per-stream ingestors through ONE shared cheap CNN.
+    """Drives N per-stream ingestors through ONE shared
+    ``ShardedIngestPipeline``.
 
-    Two modes:
-
-    * **Staged** (``cheap_apply`` given): ready batches (exactly
-      ``cfg.batch_size`` unique crops each) from all streams are stacked
-      into one device batch, bucket-padded to reuse the same compiled
-      executable, classified in a single ``cheap_apply`` call, and split
-      back per stream. When a mesh is given, the stacked batch is placed
-      with ``distributed.sharding.batch_spec`` (sharding hoisted to
-      construction — never rebuilt per step).
-    * **Sharded** (``pipeline`` = a ``ShardedIngestPipeline``): each
-      ingestor was constructed with ``pipeline=shared.handle(name)``;
-      feeds enqueue per-stream batches and every ``step()`` runs ONE
-      sharded megastep over the head batch of each stream (see
-      ``make_sharded_runner``). The runner disables the pipeline's
-      auto-pump so batches stack *across* streams.
-
-    Either way, per-stream fold order is preserved, so each stream's
-    index is byte-identical to a self-driven single-device run
-    (``cheap_apply`` must be per-example pure, which holds for the
-    inference CNNs here).
+    Each ingestor was constructed with ``pipeline=shared.handle(name)``
+    (``make_sharded_runner`` builds the whole stack). Feeds enqueue
+    per-stream batches, and every ``step()`` runs ONE sharded megastep
+    over the head batch of each queued stream. The runner disables the
+    pipeline's auto-pump so batches stack *across* streams. Per-stream
+    fold order is preserved, so each stream's index is byte-identical to
+    a run of that stream on its own.
     """
 
-    def __init__(self, ingestors: Mapping[str, StreamingIngestor],
-                 cheap_apply: Optional[Callable] = None,
-                 batch_pad: int = 64, mesh=None, pipeline=None,
+    def __init__(self, ingestors: Mapping[str, StreamingIngestor], pipeline,
                  placement: Optional[StreamPlacement] = None):
         if not ingestors:
             raise ValueError("need at least one ingestor")
-        if (cheap_apply is None) == (pipeline is None):
-            raise ValueError(
-                "pass exactly one of cheap_apply (staged stacking) or "
-                "pipeline (ShardedIngestPipeline)")
-        if pipeline is not None:
-            for name, ing in ingestors.items():
-                h = ing.pipeline
-                if h is None or getattr(h, "shared", None) is not pipeline:
-                    raise ValueError(
-                        f"ingestor {name!r} is not bound to this sharded "
-                        f"pipeline; construct it with "
-                        f"pipeline=shared.handle({name!r})")
-            pipeline.auto_pump = False   # runner owns step timing
-        else:
-            for name, ing in ingestors.items():
-                if ing.cheap_apply is not None or ing.pipeline is not None:
-                    raise ValueError(
-                        f"ingestor {name!r} owns a cheap_apply/pipeline; "
-                        f"runner-driven ingestors must be constructed "
-                        f"with neither")
+        for name, ing in ingestors.items():
+            h = ing.pipeline
+            if h is None or getattr(h, "shared", None) is not pipeline:
+                raise ValueError(
+                    f"ingestor {name!r} is not bound to this sharded "
+                    f"pipeline; construct it with "
+                    f"pipeline=shared.handle({name!r})")
+        pipeline.auto_pump = False       # runner owns step timing
         self.ingestors: Dict[str, StreamingIngestor] = dict(ingestors)
-        self.cheap_apply = cheap_apply
-        self.batch_pad = batch_pad
-        self.mesh = mesh
         self.pipeline = pipeline
         self.placement = placement
-        self._rotation = list(self.ingestors)
-        # hoisted: the stacked-batch sharding is a pure function of the
-        # mesh; rebuilding it (and re-importing jax) every step was the
-        # old per-step hot-path bug (ISSUE 9 satellite)
-        self._stack_sharding = None
-        if mesh is not None and cheap_apply is not None:
-            from jax.sharding import NamedSharding
-
-            from repro.distributed.sharding import batch_spec
-            if batch_pad % mesh.size:
-                # every stacked batch is padded to a batch_pad multiple, so
-                # this makes each one divisible over the data axis
-                raise ValueError(
-                    f"batch_pad={batch_pad} must be a multiple of the mesh "
-                    f"size {mesh.size} to shard stacked batches over it")
-            self._stack_sharding = NamedSharding(mesh, batch_spec(mesh, 3))
 
     def feed(self, feeds: Mapping[str, Tuple[np.ndarray, np.ndarray]]):
         """Feed per-stream chunks, then fold every ready batch."""
@@ -1360,75 +1285,30 @@ class MultiStreamRunner:
         self.drain()
 
     def step(self) -> int:
-        """One stacked device batch: up to one ready batch per stream.
-        Staged mode rotates which stream leads the stack; sharded mode
-        folds the head batch of every queued stream in one sharded
-        dispatch. Returns objects folded (0 = nothing ready)."""
-        if self.pipeline is not None:
-            for ing in self.ingestors.values():
-                ing._drain_ready()       # enqueue ready batches
-            return self.pipeline.pump_one()
-        parts = []
-        for name in self._rotation:
-            ing = self.ingestors[name]
-            if ing.n_ready_batches:
-                parts.append((ing, *ing.take_ready_batch()))
-        self._rotation = self._rotation[1:] + self._rotation[:1]
-        if not parts:
-            return 0
-        self._fold_stacked(parts)
-        return int(sum(len(p[2]) for p in parts))
+        """One stacked step: the head batch of every queued stream in one
+        sharded dispatch. Returns objects folded (0 = nothing ready)."""
+        for ing in self.ingestors.values():
+            ing._drain_ready()           # enqueue ready batches
+        return self.pipeline.pump_one()
 
     def drain(self):
         while self.step():
             pass
-
-    def _fold_stacked(self, parts):
-        from repro.core.query import pad_to_bucket
-        cnn = spans.Wall()                   # shared pass, attributed below
-        with spans.span("ingest.megastep", cnn):
-            stacked = np.concatenate([p[1] for p in parts])
-            n = len(stacked)
-            padded = pad_to_bucket(stacked, self.batch_pad)
-            if self._stack_sharding is not None:
-                import jax
-                padded = jax.device_put(padded, self._stack_sharding)
-            probs, feats = self.cheap_apply(padded)
-            probs = np.asarray(probs)[:n]
-            feats = np.asarray(feats)[:n]
-        off = 0
-        for ing, crops, objs, frames in parts:
-            k = len(objs)
-            ing.stats.wall_s += cnn.wall_s * (k / n)
-            ing.fold_batch(crops, objs, frames, probs[off:off + k],
-                           feats[off:off + k])
-            off += k
 
     def flush(self) -> Dict[str, IngestDelta]:
         self.drain()
         return {name: ing.flush() for name, ing in self.ingestors.items()}
 
     def finish(self) -> Dict[str, Tuple[TopKIndex, IngestStats]]:
-        """Fold the ragged per-stream tails in one final stacked pass,
-        then finalize every ingestor."""
+        """Finalize every ingestor: each ``finish()`` submits its own tail
+        and drains the shared pipeline; catalog'd streams seal
+        themselves."""
         self.drain()
-        if self.pipeline is not None:
-            # each finish() submits its own tail + flushes the shared
-            # pipeline; catalog'd streams seal themselves
-            return {name: ing.finish()
-                    for name, ing in self.ingestors.items()}
-        parts = [(ing, *ing.take_tail())
-                 for ing in self.ingestors.values()
-                 if ing.n_pending_unique]
-        if parts:
-            self._fold_stacked(parts)
         return {name: ing.finish() for name, ing in self.ingestors.items()}
 
 
 def make_sharded_runner(cheap_fn: Callable, mesh, stream_names,
                         cfg: Optional[IngestConfig] = None,
-                        topk_k: Optional[int] = None,
-                        topk_sink: Optional[Callable] = None,
                         ingestor_kwargs: Optional[Mapping[str, dict]] = None,
                         **common_kwargs) -> MultiStreamRunner:
     """Build the full sharded multi-stream stack: a ``StreamPlacement``
@@ -1443,14 +1323,11 @@ def make_sharded_runner(cheap_fn: Callable, mesh, stream_names,
     """
     from repro.core.pipeline import ShardedIngestPipeline
     placement = StreamPlacement(stream_names, mesh.size)
-    shared = ShardedIngestPipeline(cheap_fn, mesh, placement.slots,
-                                   cfg=cfg, topk_k=topk_k,
-                                   topk_sink=topk_sink)
+    shared = ShardedIngestPipeline(cheap_fn, mesh, placement.slots, cfg=cfg)
     ingestors = {}
     for nm in placement.names:
         kw = dict(common_kwargs)
         kw.update((ingestor_kwargs or {}).get(nm, {}))
         kw.setdefault("cfg", cfg)
         ingestors[nm] = StreamingIngestor(pipeline=shared.handle(nm), **kw)
-    return MultiStreamRunner(ingestors, mesh=mesh, pipeline=shared,
-                             placement=placement)
+    return MultiStreamRunner(ingestors, shared, placement=placement)

@@ -1,6 +1,5 @@
 from repro.distributed.sharding import (  # noqa: F401
     act_spec,
-    batch_spec,
     constrain,
     mesh_axes,
     param_shardings,

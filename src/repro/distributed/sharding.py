@@ -141,11 +141,6 @@ def _key_str(k) -> str:
 # Activation rules
 # ---------------------------------------------------------------------------
 
-def batch_spec(mesh: Mesh, extra_rank: int = 1) -> P:
-    dp, _ = mesh_axes(mesh)
-    return P(dp, *[None] * extra_rank)
-
-
 def act_spec(mesh: Mesh, kind: str) -> P:
     """Common activation shardings."""
     dp, mp = mesh_axes(mesh)
@@ -183,8 +178,8 @@ def act_spec(mesh: Mesh, kind: str) -> P:
 # ingest mesh (`launch.mesh.make_ingest_mesh`). Every device owns a
 # contiguous device-major block of stream slots — its streams' ClusterState
 # rows live on it for the whole run, so the hot path moves no cluster
-# state between devices; only the small per-stream (j, matched, top-K)
-# rows cross to the host at the designed fold boundary.
+# state between devices; only the small per-stream (j, matched) and
+# fold rows cross to the host at the designed fold boundary.
 
 
 def stream_spec(mesh: Mesh, extra_rank: int) -> P:

@@ -234,18 +234,17 @@ def test_sharded_fetch_without_suppression_is_flagged(tmp_path):
 def test_sharded_pipeline_sync_budget_is_pinned():
     """Regression fixture (ISSUE 9): core/pipeline.py carries exactly the
     designed set of sanctioned host syncs. A new ``device_get`` in the
-    sharded (or single-stream) path must either fail the CI lint gate or
-    consciously bump this pin with a justified suppression."""
+    megastep path must either fail the CI lint gate or consciously bump
+    this pin with a justified suppression."""
     path = os.path.join(REPO_ROOT, "src", "repro", "core", "pipeline.py")
     report = run_analysis([path])
     doc = json.loads(report.to_json(show_suppressed=True))
     assert not [f for f in doc["findings"] if f["rule"] == "host-sync"]
     syncs = [f for f in doc["suppressed"] if f["rule"] == "host-sync"]
-    # 5 single-stream (staged boundary x2, (j,matched) fetch, bound-gated
-    # n, rare evict) + 3 sharded ((j,matched) stack fetch, fold-rows
+    # staged boundary x2 + 3 megastep ((j,matched) stack fetch, fold-rows
     # fetch, bound-gated (S,) n); the evict/reset slot pulls sit in
     # non-dispatcher functions, outside the hot path this rule guards
-    assert len(syncs) == 8, sorted(f["line"] for f in syncs)
+    assert len(syncs) == 5, sorted(f["line"] for f in syncs)
     boundary = [f for f in syncs if "fold boundary" in f["justification"]
                 or "designed" in f["justification"]]
     assert len(boundary) >= 2      # both fold-boundary fetches named

@@ -41,13 +41,14 @@ def test_centroid_is_running_mean():
 
 
 def test_batched_matches_scan_when_no_new_clusters():
-    """Two-phase variant is exactly sequential when objects join existing
-    clusters (the common video case)."""
+    """The batched fast path (``cluster_fused``: phase 1 against the
+    batch-start table, then a segment-sum fold) is exactly sequential when
+    objects join existing clusters (the common video case)."""
     f, _ = _feats(64, 16, seed=1)
     st0 = C.init_state(64, 16)
     st0, _ = C.cluster_scan(st0, f[:16], 1.5)      # warm up table
     s_a, ids_a = C.cluster_scan(st0, f[16:], 1.5)
-    s_b, ids_b = C.cluster_batched(st0, f[16:], 1.5)
+    s_b, ids_b = C.cluster_fused(st0, f[16:], 1.5)
     np.testing.assert_array_equal(np.asarray(ids_a), np.asarray(ids_b))
     np.testing.assert_allclose(np.asarray(s_a.centroids),
                                np.asarray(s_b.centroids), atol=1e-5)
@@ -170,8 +171,9 @@ def test_fused_equals_scan_crossing_high_water():
 
 
 def test_fused_equals_batched_video_stream():
-    """Multi-batch video-style stream: fused and batched agree batch by
-    batch once warmed (the regime both are specified for)."""
+    """Multi-batch video-style stream: fused batches agree with the
+    sequential oracle batch by batch once warmed (the regime the fused
+    path is specified for)."""
     f, _ = _feats(400, 16, seed=21, spread=12.0, n_modes=6)
     sa = C.init_state(64, 16)
     sb = C.init_state(64, 16)
@@ -179,7 +181,7 @@ def test_fused_equals_batched_video_stream():
     sb, _ = C.cluster_scan(sb, f[:64], 1.5)
     for start in range(64, 400, 64):
         chunk = f[start:start + 64]
-        sa, ia = C.cluster_batched(sa, chunk, 1.5)
+        sa, ia = C.cluster_scan(sa, chunk, 1.5)
         sb, ib = C.cluster_fused(sb, chunk, 1.5)
         np.testing.assert_array_equal(np.asarray(ia), np.asarray(ib))
     _assert_state_eq(sa, sb)

@@ -1,13 +1,13 @@
 """Fused ingest megastep equivalence harness (DESIGN.md §9).
 
 Core property: a ``StreamingIngestor`` driven by the device-resident
-``IngestPipeline`` (one-dispatch cheap-CNN → top-K → cluster megastep,
-double-buffered) saves a *byte-identical index on disk* — and identical
-``IngestStats`` counters — to the host-staged ``cheap_apply`` path over
-the same stream, across random chunk splits, eviction boundaries, and
-shard rollovers. Plus: the ≤ 2 dispatches-per-batch budget, the
-``(batch_bucket, input_res)`` compile cache, and the megastep's fused
-top-K outputs.
+megastep — the one-slot ``ShardedIngestPipeline`` handle every served
+path runs (one-dispatch cheap-CNN → cluster megastep) — saves a
+*byte-identical index on disk*, and identical ``IngestStats`` counters,
+to the host-staged ``cheap_apply`` path over the same stream, across
+random chunk splits, eviction boundaries, and shard rollovers. Plus: the
+≤ 2 dispatches-per-batch budget, the ``(batch_bucket, input_res)``
+compile cache, and the contract errors of the pipeline handle.
 """
 import os
 import tempfile
@@ -21,11 +21,13 @@ from hypothesis import strategies as st
 from conftest import index_save_bytes as _save_bytes
 from conftest import make_chunks as _chunks
 from conftest import make_stream as _stream
+from repro.core import pipeline as P
 from repro.core.archive import ShardCatalog
 from repro.core.ingest import IngestConfig, ingest
-from repro.core.pipeline import (IngestPipeline, batch_bucket,
+from repro.core.pipeline import (ShardedIngestPipeline, batch_bucket,
                                  staged_cheap_apply)
-from repro.core.streaming import MultiStreamRunner, StreamingIngestor
+from repro.core.streaming import StreamingIngestor
+from repro.launch.mesh import make_ingest_mesh
 
 FEAT_DIM = 12
 N_CLASSES = 5
@@ -40,6 +42,17 @@ def _cheap_fn(crops):
     probs = jax.nn.softmax(flat[:, FEAT_DIM:FEAT_DIM + N_CLASSES] * 5.0,
                            axis=-1)
     return probs, feats
+
+
+def _pipeline(cfg=None, names=("s",), **kw):
+    """A sharded pipeline on a one-device mesh, one slot per name."""
+    return ShardedIngestPipeline(_cheap_fn, make_ingest_mesh(1), list(names),
+                                 cfg=cfg, **kw)
+
+
+def _handle(cfg=None):
+    """The one-slot pipeline handle the served paths run."""
+    return _pipeline(cfg).handle("s")
 
 
 def _counters(stats):
@@ -71,8 +84,7 @@ def test_pipeline_equals_staged_byte_identical(data):
                                   1e9, cfg)
 
     staged = StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9, cfg)
-    piped = StreamingIngestor(None, 1e9, cfg,
-                              pipeline=IngestPipeline(_cheap_fn, cfg))
+    piped = StreamingIngestor(None, 1e9, cfg, pipeline=_handle(cfg))
     for size in _chunks(data.draw, n):
         taken, crops = crops[:size], crops[size:]
         tf, frames = frames[:size], frames[size:]
@@ -105,7 +117,7 @@ def test_pipeline_rollover_shards_byte_identical(seed, shard_objects):
         cat_p = ShardCatalog.open(os.path.join(d, "piped"))
         ing_p = StreamingIngestor(None, 1e9, cfg, catalog=cat_p,
                                   shard_objects=shard_objects,
-                                  pipeline=IngestPipeline(_cheap_fn, cfg))
+                                  pipeline=_handle(cfg))
         for s in range(0, len(crops), 77):
             ing_s.feed(crops[s:s + 77], frames[s:s + 77])
             ing_p.feed(crops[s:s + 77], frames[s:s + 77])
@@ -120,7 +132,7 @@ def test_pipeline_rollover_shards_byte_identical(seed, shard_objects):
 
 
 # ---------------------------------------------------------------------------
-# dispatch budget, compile cache, fused top-K outputs
+# dispatch budget, compile cache, stacked steps
 # ---------------------------------------------------------------------------
 
 def test_pipeline_dispatch_budget_and_compile_cache():
@@ -130,8 +142,8 @@ def test_pipeline_dispatch_budget_and_compile_cache():
     crops, frames = _stream(7, 500)
     cfg = IngestConfig(K=2, threshold=1.5, max_clusters=64, batch_size=60,
                        pixel_diff=False)
-    pipe = IngestPipeline(_cheap_fn, cfg)
-    ing = StreamingIngestor(None, 1e9, cfg, pipeline=pipe)
+    pipe = _pipeline(cfg)
+    ing = StreamingIngestor(None, 1e9, cfg, pipeline=pipe.handle("s"))
     ing.feed(crops, frames)
     ing.finish()
     assert pipe.stats.n_batches == 9          # 8 full + 1 tail (20 rows)
@@ -151,33 +163,49 @@ def test_batch_bucket_shapes():
     assert batch_bucket(70, 100) == 100       # tail bucket capped at batch
 
 
-def test_pipeline_topk_sink_matches_probs():
-    """The megastep's fused Pallas top-K outputs agree with the batch's
-    probabilities: descending values that index into each row's probs."""
-    got = []
-    crops, frames = _stream(3, 200)
-    cfg = IngestConfig(K=3, threshold=1.5, max_clusters=64, batch_size=64,
+def test_heads_in_different_buckets_fold_in_separate_steps():
+    """A stacked step takes only the streams whose head batch shares the
+    leading stream's bucket: a full batch and a ragged tail queued at once
+    fold in two steps, and each stream still saves what the staged path
+    saves."""
+    cfg = IngestConfig(K=2, threshold=1.5, max_clusters=64, batch_size=64,
                        pixel_diff=False)
-    pipe = IngestPipeline(_cheap_fn, cfg,
-                          topk_sink=lambda o, v, i: got.append((o, v, i)))
-    ing = StreamingIngestor(None, 1e9, cfg, pipeline=pipe)
-    ing.feed(crops, frames)
-    index, _ = ing.finish()
-    probs = np.asarray(jax.jit(_cheap_fn)(crops)[0])
-    seen = 0
-    for objs, vals, idxs in got:
-        assert vals.shape == (len(objs), cfg.K)
-        assert (np.diff(vals, axis=1) <= 1e-6).all()
-        np.testing.assert_allclose(
-            np.take_along_axis(probs[objs], idxs, 1), vals, atol=1e-6)
-        seen += len(objs)
-    assert seen == 200
-    # the with-topk megastep graph (compiled only when a sink consumes
-    # it) must still fold byte-identically to the staged path
-    staged = StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9, cfg)
-    staged.feed(crops, frames)
-    staged_index, _ = staged.finish()
-    assert _save_bytes(index, "p") == _save_bytes(staged_index, "s")
+    streams = {"a": _stream(5, 64), "b": _stream(6, 20)}
+    pipe = _pipeline(cfg, names=("a", "b"), auto_pump=False)
+    ings = {nm: StreamingIngestor(None, 1e9, cfg, pipeline=pipe.handle(nm))
+            for nm in streams}
+    for nm, (crops, frames) in streams.items():
+        ings[nm].feed(crops, frames)
+    assert len(pipe.handle("a").queue) == 1   # one full batch queued
+    out = {"b": ings["b"].finish()[0]}        # queues b's 20-row tail
+    out["a"] = ings["a"].finish()[0]
+    assert batch_bucket(64, 64) != batch_bucket(20, 64)
+    assert pipe.stats.n_batches == 2 and pipe.stats.n_steps == 2
+    for nm, (crops, frames) in streams.items():
+        staged = StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9,
+                                   cfg)
+        staged.feed(crops, frames)
+        assert _save_bytes(out[nm], nm) == \
+            _save_bytes(staged.finish()[0], nm + "s"), nm
+
+
+def test_megastep_jit_shared_across_K():
+    """The megastep program does not depend on K (the index ranks on the
+    host): two pipelines over one cheap_fn whose configs differ only in K
+    share one compiled-jit cache entry."""
+    crops, frames = _stream(4, 80)
+    P._MEGASTEP_JITS.clear()
+    pipes = []
+    for K in (2, 4):
+        cfg = IngestConfig(K=K, threshold=1.5, batch_size=32,
+                           pixel_diff=False)
+        pipe = _pipeline(cfg)
+        ing = StreamingIngestor(None, 1e9, cfg, pipeline=pipe.handle("s"))
+        ing.feed(crops, frames)
+        ing.finish()
+        pipes.append(pipe)
+    assert len(P._MEGASTEP_JITS) == 1
+    assert pipes[0]._megastep_fn is pipes[1]._megastep_fn
 
 
 # ---------------------------------------------------------------------------
@@ -188,7 +216,7 @@ def test_ingestor_rejects_both_cheap_apply_and_pipeline():
     cfg = IngestConfig(batch_size=8)
     with pytest.raises(ValueError):
         StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9, cfg,
-                          pipeline=IngestPipeline(_cheap_fn, cfg))
+                          pipeline=_handle(cfg))
 
 
 def test_rejected_constructor_does_not_consume_pipeline():
@@ -196,7 +224,7 @@ def test_rejected_constructor_does_not_consume_pipeline():
     without a catalog) must not leave the pipeline bound — the caller
     retries with a corrected constructor and the same pipeline."""
     cfg = IngestConfig(batch_size=8)
-    pipe = IngestPipeline(_cheap_fn, cfg)
+    pipe = _handle(cfg)
     with pytest.raises(ValueError):
         StreamingIngestor(None, 1e9, cfg, shard_objects=100, pipeline=pipe)
     StreamingIngestor(None, 1e9, cfg, pipeline=pipe)     # retry works
@@ -204,38 +232,21 @@ def test_rejected_constructor_does_not_consume_pipeline():
 
 def test_pipeline_rejects_second_ingestor():
     cfg = IngestConfig(batch_size=8)
-    pipe = IngestPipeline(_cheap_fn, cfg)
+    pipe = _handle(cfg)
     StreamingIngestor(None, 1e9, cfg, pipeline=pipe)
     with pytest.raises(ValueError):
         StreamingIngestor(None, 1e9, cfg, pipeline=pipe)
 
 
-def test_runner_rejects_pipeline_driven_ingestors():
-    cfg = IngestConfig(batch_size=8)
-    ing = StreamingIngestor(None, 1e9, cfg,
-                            pipeline=IngestPipeline(_cheap_fn, cfg))
-    with pytest.raises(ValueError):
-        MultiStreamRunner({"a": ing}, _cheap_fn)
-
-
-def test_pipeline_explicit_topk_wider_than_classes_raises():
-    """cfg.K wider than the class width is clamped (TopKIndex semantics),
-    but an explicit topk_k beyond it is a config error, matching
-    ops.topk."""
+def test_pipeline_K_wider_than_classes_is_clamped():
+    """cfg.K wider than the class width is clamped (TopKIndex's min(K, C)
+    semantics): the pipeline ingests fine with K > C."""
     crops, frames = _stream(2, 50)
-    cfg = IngestConfig(K=2, threshold=1.5, batch_size=16, pixel_diff=False)
-    ing = StreamingIngestor(
-        None, 1e9, cfg,
-        pipeline=IngestPipeline(_cheap_fn, cfg, topk_k=N_CLASSES + 1))
-    with pytest.raises(ValueError):
-        ing.feed(crops, frames)
-    # the clamped default path ingests fine with K > C
     wide = IngestConfig(K=N_CLASSES + 3, threshold=1.5, batch_size=16,
                         pixel_diff=False)
-    ing2 = StreamingIngestor(None, 1e9, wide,
-                             pipeline=IngestPipeline(_cheap_fn, wide))
-    ing2.feed(crops, frames)
-    index, _ = ing2.finish()
+    ing = StreamingIngestor(None, 1e9, wide, pipeline=_handle(wide))
+    ing.feed(crops, frames)
+    index, _ = ing.finish()
     assert index.n_objects == 50
 
 
@@ -243,8 +254,7 @@ def test_pipeline_rejects_mismatched_cfg():
     """A pipeline built with its own cfg must match the ingestor's —
     otherwise the megastep would cluster with one threshold/table size
     while the host folds with another."""
-    pipe = IngestPipeline(_cheap_fn, IngestConfig(batch_size=8,
-                                                  threshold=0.5))
+    pipe = _handle(IngestConfig(batch_size=8, threshold=0.5))
     with pytest.raises(ValueError):
         StreamingIngestor(None, 1e9, IngestConfig(batch_size=8,
                                                   threshold=0.9),
@@ -252,19 +262,36 @@ def test_pipeline_rejects_mismatched_cfg():
 
 
 def test_pipeline_rejects_non_fused_clustering():
-    """The megastep hard-codes fused clustering semantics; a scan/batched
-    config must be rejected loudly, not silently diverge from staged."""
+    """Ingest clusters with the fused semantics only (the megastep
+    hard-codes them, and the staged path runs ``cluster_fused``): a
+    scan/batched config is rejected loudly where it is made."""
     for variant in ("scan", "batched"):
-        cfg = IngestConfig(batch_size=8, clustering=variant)
-        with pytest.raises(ValueError):
-            IngestPipeline(_cheap_fn, cfg)
-        with pytest.raises(ValueError):
-            StreamingIngestor(None, 1e9, cfg,
-                              pipeline=IngestPipeline(_cheap_fn))
+        with pytest.raises(ValueError, match="fused"):
+            IngestConfig(batch_size=8, clustering=variant)
 
 
 def test_unbound_pipeline_submit_raises():
-    pipe = IngestPipeline(_cheap_fn, IngestConfig(batch_size=8))
-    with pytest.raises(RuntimeError):
-        pipe.submit(np.zeros((4, 6, 6, 3), np.float32),
-                    np.arange(4), np.zeros(4, np.int64))
+    """A slot no ingestor has bound refuses a batch before queueing or
+    dispatching anything."""
+    pipe = _pipeline(IngestConfig(batch_size=8))
+    h = pipe.handle("s")
+    with pytest.raises(RuntimeError, match="not bound"):
+        h.submit(np.zeros((4, 6, 6, 3), np.float32), np.arange(4),
+                 np.zeros(4, np.int64))
+    assert not h.queue and pipe.stats.n_dispatches == 0
+
+
+def test_slot_reset_with_queued_batch_raises():
+    """Shard rollover must drain first: resetting a slot that still holds
+    a queued batch raises and leaves the queue as it was."""
+    cfg = IngestConfig(batch_size=8, pixel_diff=False)
+    pipe = _pipeline(cfg, auto_pump=False)
+    h = pipe.handle("s")
+    StreamingIngestor(None, 1e9, cfg, pipeline=h)
+    crops, frames = _stream(8, 8)
+    h.submit(crops, np.arange(8), frames)
+    with pytest.raises(RuntimeError, match="queued"):
+        h.reset()
+    assert len(h.queue) == 1 and len(h.queue[0][1]) == 8
+    assert pipe.pump() == 8 and not h.queue
+    h.reset()

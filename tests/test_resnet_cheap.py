@@ -19,8 +19,7 @@ from repro.common.config import CheapCNNConfig
 from repro.core.archive import ShardCatalog
 from repro.core.index import saved_file_bytes
 from repro.core.ingest import IngestConfig
-from repro.core.pipeline import (IngestPipeline, ShardedIngestPipeline,
-                                 _megastep_jit, _sharded_megastep_jit,
+from repro.core.pipeline import (ShardedIngestPipeline, _sharded_megastep_jit,
                                  batch_bucket, staged_cheap_apply)
 from repro.core.specialize import SpecializedModel
 from repro.core.streaming import StreamingIngestor, make_sharded_runner
@@ -215,8 +214,7 @@ def _plain_forward_before(params, images, cfg):
     return jax.nn.softmax(logits, axis=-1), feats
 
 
-@pytest.mark.parametrize("sharded", [False, True])
-def test_spec1_megastep_outputs_unchanged(sharded):
+def test_spec1_megastep_outputs_unchanged():
     """spec1's megastep, with the model passed as an argument (a
     ``Partial`` over the weights), gives the outputs the closure over the
     parent's plain forward gives, bit for bit."""
@@ -237,16 +235,11 @@ def test_spec1_megastep_outputs_unchanged(sharded):
         # fresh cluster state per step: the megastep donates it
         cen = jnp.zeros((256, 128)).at[:24].set(founders)
         cnt = jnp.ones((256,), jnp.int32)
-        if sharded:
-            mesh = make_ingest_mesh(1)
-            step = _sharded_megastep_jit(fn, 4, True, mesh, 1)
-            out = step(ShardedIngestPipeline(fn, mesh, ["s"])._model,
-                       cen[None], cnt[None], jnp.full((1,), 24, jnp.int32),
-                       thr, jnp.full((1,), 60, jnp.int32), crops[None])
-        else:
-            step = _megastep_jit(fn, 4, True)
-            out = step(IngestPipeline(fn)._model, cen, cnt, jnp.int32(24),
-                       thr, jnp.int32(60), crops)
+        mesh = make_ingest_mesh(1)
+        step = _sharded_megastep_jit(fn, mesh, 1)
+        out = step(ShardedIngestPipeline(fn, mesh, ["s"])._model,
+                   cen[None], cnt[None], jnp.full((1,), 24, jnp.int32),
+                   thr, jnp.full((1,), 60, jnp.int32), crops[None])
         outs.append([np.asarray(o) for o in out])
     assert outs[0][6].any() and not outs[0][6].all()     # some matched
     for a, b in zip(*outs):
@@ -258,7 +251,7 @@ def _stub(crops):
     return jax.nn.softmax(flat[:, 12:17] * 5.0, axis=-1), flat[:, :12] * 10.0
 
 
-@pytest.mark.parametrize("path", ["sharded", "single", "make_apply"])
+@pytest.mark.parametrize("path", ["sharded", "make_apply"])
 def test_cnn_rows_equal_a_hand_count(monkeypatch, path):
     """``cnn.rows`` counts every row the cheap CNN runs: each batch padded
     to its bucket (the megasteps) or to the staged forward's pad."""
@@ -277,12 +270,9 @@ def test_cnn_rows_equal_a_hand_count(monkeypatch, path):
         ing = StreamingIngestor(spy, 1e9, cfg)
         want = lambda: sum(n + (-n % 16) for n in sizes)       # noqa: E731
     else:
-        if path == "sharded":
-            shared = ShardedIngestPipeline(_stub, make_ingest_mesh(1),
-                                           ["cam"], cfg=cfg)
-            pipe = shared.handle("cam")
-        else:
-            pipe = IngestPipeline(_stub, cfg)
+        shared = ShardedIngestPipeline(_stub, make_ingest_mesh(1), ["cam"],
+                                       cfg=cfg)
+        pipe = shared.handle("cam")
         submit = type(pipe).submit
 
         def spy(self, c, objs, fr):

@@ -3,7 +3,7 @@
 Core property: every stream driven through a ``ShardedIngestPipeline``
 (one sharded megastep per stacked step, cluster tables device-resident
 per stream slot) saves a *byte-identical index* — and identical stats
-counters — to that stream's single-device ``StreamingIngestor`` run,
+counters — to that stream's host-staged ``StreamingIngestor`` run,
 across random chunk splits, eviction boundaries, and archive shard
 rollovers. Plus: deterministic stream → device placement stable across
 ``feed()`` chunkings, and ``make_ingest_mesh`` validation.
@@ -27,7 +27,7 @@ from conftest import make_chunks as _chunks
 from conftest import make_stream as _stream
 from repro.core.archive import ShardCatalog
 from repro.core.ingest import IngestConfig
-from repro.core.pipeline import IngestPipeline, ShardedIngestPipeline
+from repro.core.pipeline import ShardedIngestPipeline, staged_cheap_apply
 from repro.core.streaming import (MultiStreamRunner, StreamPlacement,
                                   StreamingIngestor, make_sharded_runner)
 from repro.launch.mesh import make_ingest_mesh
@@ -61,12 +61,11 @@ _CFG = dict(K=2, threshold=1.5, max_clusters=24, high_water=0.8,
 
 
 def _reference(name_streams, cfg, chunkings):
-    """Per-stream single-device fused-pipeline runs over the same chunk
-    splits — the byte-identity baseline."""
+    """Per-stream host-staged runs over the same chunk splits — the
+    byte-identity baseline."""
     out = {}
     for nm, (crops, frames) in name_streams.items():
-        ref = StreamingIngestor(None, 1e9, cfg,
-                                pipeline=IngestPipeline(_cheap_fn, cfg))
+        ref = StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9, cfg)
         o = 0
         for k in chunkings[nm]:
             ref.feed(crops[o:o + k], frames[o:o + k])
@@ -100,15 +99,15 @@ def _run_sharded(mesh, name_streams, cfg, chunkings, interleave=True):
 
 
 # ---------------------------------------------------------------------------
-# the equivalence property: sharded == per-stream single-device
+# the equivalence property: sharded == per-stream staged
 # ---------------------------------------------------------------------------
 
 @settings(max_examples=4, deadline=None)
 @given(st.data())
 def test_sharded_1device_mesh_equals_single_device(data):
     """1-device mesh (runs under plain tier-1): stacked sharded steps over
-    2 streams save byte-identically to each stream's own single-device
-    fused-pipeline run, over random chunk splits with evictions."""
+    2 streams save byte-identically to each stream's own host-staged run,
+    over random chunk splits with evictions."""
     cfg = IngestConfig(batch_size=data.draw(st.sampled_from([32, 64]),
                                             label="batch"), **_CFG)
     streams, chunkings = {}, {}
@@ -131,7 +130,7 @@ def test_sharded_1device_mesh_equals_single_device(data):
 @given(st.data())
 def test_sharded_multi_device_equals_single_device(data):
     """THE tentpole property (ISSUE 9): sharded(4 streams, 2 devices) ==
-    per-stream single-device, byte-identical per stream, including
+    per-stream host-staged, byte-identical per stream, including
     eviction boundaries, over random streams and chunk splits."""
     cfg = IngestConfig(batch_size=32, **_CFG)
     streams, chunkings = {}, {}
@@ -154,7 +153,7 @@ def test_sharded_multi_device_equals_single_device(data):
 @multi_device
 def test_sharded_rollover_shards_byte_identical():
     """Archive rollover mid-run on a 2-device mesh: every sealed shard
-    file (and its manifest entry) matches the single-device rollover run
+    file (and its manifest entry) matches the host-staged rollover run
     byte for byte — seals fire per stream while other streams keep
     ingesting through the same stacked pipeline."""
     cfg = IngestConfig(batch_size=32, **_CFG)
@@ -175,9 +174,8 @@ def test_sharded_rollover_shards_byte_identical():
         runner.finish()
         for nm in names:
             cat_r = ShardCatalog.open(os.path.join(d, "ref_" + nm))
-            ref = StreamingIngestor(None, 1e9, cfg, catalog=cat_r,
-                                    shard_objects=110,
-                                    pipeline=IngestPipeline(_cheap_fn, cfg))
+            ref = StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9,
+                                    cfg, catalog=cat_r, shard_objects=110)
             for s in range(0, 300, 77):
                 ref.feed(streams[nm][0][s:s + 77],
                          streams[nm][1][s:s + 77])
@@ -208,9 +206,8 @@ def test_sharded_1device_rollover_byte_identical():
             runner.feed({"cam0": (crops[s:s + 90], frames[s:s + 90])})
         runner.finish()
         cat_r = ShardCatalog.open(os.path.join(d, "ref"))
-        ref = StreamingIngestor(None, 1e9, cfg, catalog=cat_r,
-                                shard_objects=100,
-                                pipeline=IngestPipeline(_cheap_fn, cfg))
+        ref = StreamingIngestor(staged_cheap_apply(_cheap_fn, cfg), 1e9, cfg,
+                                catalog=cat_r, shard_objects=100)
         for s in range(0, 280, 90):
             ref.feed(crops[s:s + 90], frames[s:s + 90])
         ref.finish()
@@ -318,16 +315,29 @@ def test_sharded_pipeline_slot_layout_validation():
         ShardedIngestPipeline(_cheap_fn, None, ["a"])
 
 
-def test_runner_rejects_foreign_pipeline_binding():
+@pytest.mark.parametrize("case", ["foreign_pipeline", "staged_ingestor",
+                                  "unbound_ingestor", "empty_mapping"])
+def test_runner_rejects_foreign_pipeline_binding(case):
+    """The runner drives only ingestors bound to ITS pipeline's slots: an
+    ingestor on another pipeline, a host-staged one, one handed to a
+    runner with no pipeline at all, and an empty mapping are refused."""
     cfg = IngestConfig(batch_size=32, **_CFG)
     mesh = make_ingest_mesh(1)
     shared = ShardedIngestPipeline(_cheap_fn, mesh, ["a", "b"], cfg=cfg)
-    other = ShardedIngestPipeline(_cheap_fn, mesh, ["a"], cfg=cfg)
-    ing = StreamingIngestor(None, 1e9, cfg, pipeline=other.handle("a"))
-    with pytest.raises(ValueError, match="not bound to this"):
-        MultiStreamRunner({"a": ing}, pipeline=shared)
-    with pytest.raises(ValueError, match="exactly one"):
-        MultiStreamRunner({"a": ing})
+    ingestors = {
+        "foreign_pipeline": lambda: {"a": StreamingIngestor(
+            None, 1e9, cfg, pipeline=ShardedIngestPipeline(
+                _cheap_fn, mesh, ["a"], cfg=cfg).handle("a"))},
+        "staged_ingestor": lambda: {"a": StreamingIngestor(
+            staged_cheap_apply(_cheap_fn, cfg), 1e9, cfg)},
+        "unbound_ingestor": lambda: {"a": StreamingIngestor(
+            None, 1e9, cfg, pipeline=shared.handle("a"))},
+        "empty_mapping": lambda: {},
+    }[case]()
+    runner_pipeline = None if case == "unbound_ingestor" else shared
+    with pytest.raises(ValueError, match="not bound to this|at least one"):
+        MultiStreamRunner(ingestors, runner_pipeline)
+    assert shared.auto_pump            # a refused runner takes no control
 
 
 def test_sharded_one_dispatch_per_stacked_step():

@@ -8,6 +8,7 @@ one-shot ``ingest()`` over the concatenated stream, including across
 eviction boundaries. Plus: multi-stream runner equivalence, and
 query-while-ingest returning exactly what a fresh engine sees.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -19,7 +20,9 @@ from conftest import make_stream as _stream
 from repro.core.engine import QueryEngine
 from repro.core.index import TopKIndex
 from repro.core.ingest import IngestConfig, ingest
-from repro.core.streaming import MultiStreamRunner, StreamingIngestor
+from repro.core.pipeline import staged_cheap_apply
+from repro.core.streaming import StreamingIngestor, make_sharded_runner
+from repro.launch.mesh import make_ingest_mesh
 
 FEAT_DIM = 12
 N_CLASSES = 5
@@ -33,6 +36,13 @@ def _cheap(batch):
     feats = (flat[:, :FEAT_DIM] * 10.0).astype(np.float32)
     probs = np.abs(flat[:, FEAT_DIM:FEAT_DIM + N_CLASSES]) + 1e-3
     return (probs / probs.sum(1, keepdims=True)).astype(np.float32), feats
+
+
+def _cheap_fn(crops):
+    """Jax-traceable form of ``_cheap`` for the sharded runner."""
+    flat = crops.reshape(crops.shape[0], -1)
+    probs = jnp.abs(flat[:, FEAT_DIM:FEAT_DIM + N_CLASSES]) + 1e-3
+    return probs / probs.sum(1, keepdims=True), flat[:, :FEAT_DIM] * 10.0
 
 
 def _gt_apply(batch):
@@ -79,19 +89,21 @@ def test_streaming_equals_oneshot_byte_identical(data):
 @settings(max_examples=4, deadline=None)
 @given(st.integers(0, 10_000))
 def test_multi_stream_runner_matches_self_driven(seed):
-    """Two streams through one stacked shared-CNN runner == each stream
-    ingested on its own, byte for byte."""
+    """Two streams through one sharded runner, fed uneven interleaved
+    chunks == each stream ingested on its own (host-staged), byte for
+    byte."""
     cfg = IngestConfig(K=2, threshold=1.5, max_clusters=32, batch_size=48,
                        high_water=0.85, evict_frac=0.4)
     streams = {name: _stream(seed + i, 300 + 40 * i)
                for i, name in enumerate(["cam_a", "cam_b"])}
 
-    solo = {name: ingest(c, f, _cheap, 1e9, cfg)[0]
+    solo = {name: ingest(c, f, staged_cheap_apply(_cheap_fn, cfg), 1e9,
+                         cfg)[0]
             for name, (c, f) in streams.items()}
 
-    runner = MultiStreamRunner(
-        {name: StreamingIngestor(None, 1e9, cfg) for name in streams},
-        _cheap, batch_pad=32)
+    runner = make_sharded_runner(_cheap_fn, make_ingest_mesh(1),
+                                 list(streams), cfg=cfg,
+                                 cheap_flops_per_image=1e9)
     # interleave feeds chunk by chunk (uneven chunk sizes per stream)
     cursors = {name: 0 for name in streams}
     sizes = {"cam_a": 77, "cam_b": 130}
@@ -410,9 +422,9 @@ def test_default_obj_ids_are_arrival_positions_in_unsorted_chunk():
 def test_take_on_empty_buffer_returns_empty_arrays():
     """Regression: ``take_tail``/``take_ready_batch`` on an ingestor whose
     unique buffer is still empty crashed with ``None[:0]`` (TypeError) —
-    e.g. an external driver finishing a stream whose chunks were all
-    duplicates, before any unique object was buffered."""
-    ing = StreamingIngestor(None, 1e9, IngestConfig(batch_size=8))
+    e.g. a stream whose chunks were all duplicates, before any unique
+    object was buffered."""
+    ing = StreamingIngestor(_cheap, 1e9, IngestConfig(batch_size=8))
     for crops, objs, frames in (ing.take_tail(), ing.take_ready_batch()):
         assert len(crops) == len(objs) == len(frames) == 0
         assert objs.dtype == np.int64 and frames.dtype == np.int64
@@ -421,18 +433,17 @@ def test_take_on_empty_buffer_returns_empty_arrays():
 
 
 def test_take_tail_after_full_drain_keeps_crop_shape():
-    """After the buffer drains to empty, a further take returns empties
-    with the stream's crop shape (so a shape-polymorphic driver can still
-    batch them)."""
+    """After the buffer drains to empty (here: the feed folds both full
+    batches), a further take returns empties with the stream's crop shape
+    (so a shape-polymorphic caller can still batch them)."""
     cfg = IngestConfig(batch_size=4, pixel_diff=False)
-    ing = StreamingIngestor(None, 1e9, cfg)
+    ing = StreamingIngestor(_cheap, 1e9, cfg)
     crops = np.random.default_rng(0).random((8, 6, 6, 3)).astype(np.float32)
     ing.feed(crops, np.zeros(8, np.int64))
-    ing.take_ready_batch()
-    ing.take_ready_batch()
-    tail_crops, tail_objs, _ = ing.take_tail()
-    assert tail_crops.shape == (0, 6, 6, 3)
-    assert len(tail_objs) == 0
+    assert ing.n_pending_unique == 0 and ing.stats.n_cnn_invocations == 8
+    for crops_, objs, _ in (ing.take_ready_batch(), ing.take_tail()):
+        assert crops_.shape == (0, 6, 6, 3)
+        assert len(objs) == 0
 
 
 def test_feed_after_finish_raises():
@@ -444,42 +455,12 @@ def test_feed_after_finish_raises():
         ing.feed(crops, frames)
 
 
-def test_runner_rejects_self_driven_ingestors():
-    with pytest.raises(ValueError):
-        MultiStreamRunner({"a": StreamingIngestor(_cheap, 1e9,
-                                                  IngestConfig())}, _cheap)
-    with pytest.raises(ValueError):
-        MultiStreamRunner({}, _cheap)
-
-
-def test_runner_mesh_requires_divisible_batch_pad():
-    """Staged stacking over a mesh pads every stacked batch to batch_pad
-    rows; a batch_pad the data axis cannot divide is refused up front
-    (nothing is silently left unsharded)."""
-    from repro.launch.mesh import make_ingest_mesh
-    mesh = make_ingest_mesh(1)
-    cfg = IngestConfig(K=2, threshold=1.5, max_clusters=32, batch_size=48)
-    crops, frames = _stream(3, 120)
-    runner = MultiStreamRunner({"a": StreamingIngestor(None, 1e9, cfg)},
-                               _cheap, batch_pad=32, mesh=mesh)
-    runner.feed({"a": (crops, frames)})
-    index, _ = runner.finish()["a"]
-    want, _ = ingest(crops, frames, _cheap, 1e9, cfg)
-    assert _save_bytes(index) == _save_bytes(want)
-
-    class _Mesh3:
-        size = 3
-    with pytest.raises(ValueError, match="multiple of the mesh size"):
-        MultiStreamRunner({"a": StreamingIngestor(None, 1e9, cfg)},
-                          _cheap, batch_pad=32, mesh=_Mesh3())
-
-
 def test_runner_driven_finish_requires_runner():
-    ing = StreamingIngestor(None, 1e9, IngestConfig(batch_size=64))
-    crops, frames = _stream(2, 30)
-    ing.feed(crops, frames)              # buffered: no CNN to drain with
-    with pytest.raises(RuntimeError):
-        ing.finish()
+    """An ingestor needs exactly one CNN sink: with neither a
+    ``cheap_apply`` nor a pipeline handle (nothing could ever fold its
+    buffered objects) the constructor refuses it."""
+    with pytest.raises(ValueError, match="exactly one"):
+        StreamingIngestor(None, 1e9, IngestConfig(batch_size=64))
 
 
 def test_empty_feeds_and_empty_finish():

@@ -134,9 +134,10 @@ def test_pixel_match_block_compiles(one_chip):
     _assert_kernel(c)
 
 
-def test_spec1_megastep_compiles(one_chip, monkeypatch):
-    """The fused ingest megastep (cheap-CNN forward -> topk -> phase-1
-    centroid_assign -> matched fold) of ``spec1`` at batch 512."""
+def test_spec1_megastep_compiles(topo, monkeypatch):
+    """The fused ingest megastep (cheap-CNN forward -> phase-1
+    centroid_assign -> matched fold) of ``spec1`` at batch 512, as the
+    spec1 cell runs it: one stream slot on a one-chip mesh."""
     from repro.common.config import CheapCNNConfig
     from repro.core import pipeline
     from repro.kernels import ops
@@ -151,26 +152,31 @@ def test_spec1_megastep_compiles(one_chip, monkeypatch):
         logits, feats = cnn.forward(params, crops, cfg)
         return jax.nn.softmax(logits, axis=-1), feats
 
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    rep = NamedSharding(mesh, P())
+    row = lambda *shape: NamedSharding(                        # noqa: E731
+        mesh, P("data", *[None] * (len(shape) - 1)))
     # steer the kernels' platform choice in the test: the process backend
     # is the CPU, the compile target is the TPU. Traces cached either way
     # must not leak into other tests.
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     jax.clear_caches()
     try:
-        step = pipeline._megastep_jit(cheap_fn, 4, True)
+        step = pipeline._sharded_megastep_jit(cheap_fn, mesh, 1)
         c = step.lower(
             Partial(cheap_fn),
-            _spec((MAX_CLUSTERS, FEAT_DIM), jnp.float32, one_chip),
-            _spec((MAX_CLUSTERS,), jnp.int32, one_chip),
-            _spec((), jnp.int32, one_chip),
-            _spec((), jnp.float32, one_chip),
-            _spec((), jnp.int32, one_chip),
-            _spec((BATCH, 32, 32, 3), jnp.float32, one_chip)).compile()
+            _spec((1, MAX_CLUSTERS, FEAT_DIM), jnp.float32,
+                  row(1, MAX_CLUSTERS, FEAT_DIM)),
+            _spec((1, MAX_CLUSTERS), jnp.int32, row(1, MAX_CLUSTERS)),
+            _spec((1,), jnp.int32, row(1)),
+            _spec((), jnp.float32, rep),
+            _spec((1,), jnp.int32, row(1)),
+            _spec((1, BATCH, 32, 32, 3), jnp.float32,
+                  row(1, BATCH, 32, 32, 3))).compile()
     finally:
         pipeline._MEGASTEP_JITS.clear()
         jax.clear_caches()
-    # two kernels in the one program: topk and centroid_assign
-    assert c.as_text().count("tpu_custom_call") >= 2
+    assert c.as_text().count("tpu_custom_call") >= 1     # centroid_assign
 
 
 def test_resnet18_megastep_compiles(topo, monkeypatch):
@@ -197,7 +203,7 @@ def test_resnet18_megastep_compiles(topo, monkeypatch):
     monkeypatch.setattr(ops, "_interpret", lambda: False)
     jax.clear_caches()
     try:
-        step = pipeline._sharded_megastep_jit(model, 4, False, mesh, 1)
+        step = pipeline._sharded_megastep_jit(model, mesh, 1)
         c = step.lower(
             model,
             _spec((1, MAX_CLUSTERS, R18_FEAT_DIM), jnp.float32,
